@@ -91,7 +91,7 @@ def main() -> None:
     import tempfile
 
     metric = f"match_lines_per_sec_{N_PATTERNS}regex_library"
-    platform = bench_common.probe_backend(metric, "lines/s")
+    platform = bench_common.require_tpu(metric, "lines/s")
 
     # every device touch must yield the {"value": null} diagnostics exit
     # on a wedged backend, never an unbounded hang
@@ -110,7 +110,7 @@ def main() -> None:
         t0 = time.perf_counter()
         engine = bounded(
             lambda: PatternShardedEngine(sets, ScoringConfig()),
-            bench_common.PROBE_TIMEOUT_S,
+            bench_common.INIT_BUDGET_S,
             "cold compile",
         )
         cold_compile = time.perf_counter() - t0
@@ -129,7 +129,7 @@ def main() -> None:
         t0 = time.perf_counter()
         engine = bounded(
             lambda: PatternShardedEngine(sets, ScoringConfig()),
-            bench_common.PROBE_TIMEOUT_S,
+            bench_common.INIT_BUDGET_S,
             "warm compile",
         )
         warm_compile = time.perf_counter() - t0

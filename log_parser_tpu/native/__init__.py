@@ -1,7 +1,10 @@
 """ctypes bindings for the native runtime library (native/log_parser_native.cpp).
 
 The shared object is compiled on demand with ``g++ -O3`` and cached next to
-the source, keyed by source mtime. Every caller must tolerate
+the source, keyed by a sha256 of the source (a stamp file beside the
+``.so``): a fresh copy of the tree sets mtimes arbitrarily, so only the
+content says whether the binary was built from this source. The build
+product is never committed. Every caller must tolerate
 ``get_lib() is None`` (no toolchain, compile failure) and fall back to the
 pure-Python path — the native layer is an accelerator, never a requirement.
 """
@@ -9,6 +12,8 @@ pure-Python path — the native layer is an accelerator, never a requirement.
 from __future__ import annotations
 
 import ctypes
+import fcntl
+import hashlib
 import logging
 import os
 import re
@@ -20,6 +25,8 @@ log = logging.getLogger(__name__)
 
 _SRC = Path(__file__).resolve().parents[2] / "native" / "log_parser_native.cpp"
 _SO = _SRC.parent / "build" / "log_parser_native.so"
+# sha256 of the source the .so was built from
+_STAMP = _SO.with_name(_SO.name + ".sha256")
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
@@ -98,12 +105,27 @@ def glibcxx_triage(so_path=None) -> dict:
     }
 
 
-def _compile() -> bool:
+def _src_digest() -> str:
+    return hashlib.sha256(_SRC.read_bytes()).hexdigest()
+
+
+def _stamp() -> str | None:
+    try:
+        return _STAMP.read_text().strip()
+    except OSError:
+        return None
+
+
+def _compile(digest: str) -> bool:
+    """Build ``_SO`` from ``_SRC`` and stamp it with ``digest``. Written
+    to a private temp name and renamed into place, so a process that
+    loaded the old binary keeps its mapping and no reader sees half a
+    file."""
     global _load_error
-    _SO.parent.mkdir(parents=True, exist_ok=True)
+    tmp = _SO.with_name(f"{_SO.name}.{os.getpid()}.tmp")
     cmd = [
         "g++", "-O3", "-std=c++17", "-shared", "-fPIC",
-        str(_SRC), "-o", str(_SO),
+        str(_SRC), "-o", str(tmp),
     ]
     try:
         proc = subprocess.run(cmd, capture_output=True, text=True, timeout=300)
@@ -115,7 +137,26 @@ def _compile() -> bool:
         log.warning("native compile failed:\n%s", proc.stderr)
         _load_error = f"compile failed: {proc.stderr.strip()[:500]}"
         return False
+    os.replace(tmp, _SO)
+    stamp_tmp = _STAMP.with_name(f"{_STAMP.name}.{os.getpid()}.tmp")
+    stamp_tmp.write_text(digest + "\n")
+    os.replace(stamp_tmp, _STAMP)
     return True
+
+
+def _ensure_built() -> bool:
+    """Rebuild unless the stamp matches the source's digest. One
+    builder at a time (test workers start together): the others wait
+    on the lock and then find the stamp current."""
+    digest = _src_digest()
+    if _SO.exists() and _stamp() == digest:
+        return True
+    _SO.parent.mkdir(parents=True, exist_ok=True)
+    with open(_SO.parent / ".build.lock", "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if _SO.exists() and _stamp() == digest:
+            return True
+        return _compile(digest)
 
 
 def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
@@ -222,10 +263,7 @@ def get_lib() -> ctypes.CDLL | None:
             # stage, no toolchain) is loaded as-is; staleness only applies
             # when the source is present to rebuild from
             if _SRC.exists():
-                stale = (
-                    not _SO.exists() or _SO.stat().st_mtime < _SRC.stat().st_mtime
-                )
-                if stale and not _compile():
+                if not _ensure_built():
                     return None
             elif not _SO.exists():
                 _load_error = f"no prebuilt library at {_SO} and no source to build"

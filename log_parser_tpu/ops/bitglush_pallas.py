@@ -76,15 +76,20 @@ def _i32(a) -> np.ndarray:
 
 
 def pick_tile(B: int, limit: int | None = None) -> int | None:
-    """Largest batch tile ≤ TILE_B that divides ``B`` on a sublane
-    multiple (8); None when no usable tile exists. The encoder's
-    quarter-pow2 row rungs (640, 896, 1792, ...) are not all multiples
-    of 512, so the tile adapts per batch (640 → 320)."""
-    tile = min(limit or TILE_B, B)
-    while tile >= 8:
-        if B % tile == 0 and tile % 8 == 0:
+    """Largest batch tile ≤ ``limit`` (default TILE_B) that Mosaic can
+    block; None when no usable tile exists. The tile is the LANE axis of
+    the ``[T, tile]`` byte block, so it must be a multiple of 128 or the
+    whole batch (a multiple of 8: it is also the sublane axis of the
+    ``[tile, ·]`` blocks). The encoder's rows are powers of two up to
+    128 and multiples of 128 beyond, so every rung has a tile."""
+    limit = min(limit or TILE_B, B)
+    if B == limit and B % 8 == 0:
+        return B
+    tile = limit // 128 * 128
+    while tile >= 128:
+        if B % tile == 0:
             return tile
-        tile -= 8
+        tile -= 128
     return None
 
 
@@ -223,9 +228,9 @@ def bitglush_hits_pallas(
     T, B = lines_tb.shape
     W = bank.n_words
     if interpret is None:
-        # Mosaic needs real TPU hardware; everywhere else (CPU test
-        # meshes) the interpreter executes the same kernel semantics
-        interpret = jax.default_backend() != "tpu"
+        # the interpreter runs only on the CPU test backend; any other
+        # backend lowers through Mosaic, so a refusal raises
+        interpret = jax.default_backend() == "cpu"
     consts = jnp.asarray(
         np.stack(
             [

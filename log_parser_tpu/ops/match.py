@@ -1090,22 +1090,17 @@ class MatcherBanks:
             )
 
             if dfa_tile(self._dfa_pallas_plan, B, lines_tb.shape[0]) is not None:
-                # any failure on this path — injected kernel fault or a
-                # real lowering error — drops the WHOLE batch back onto
-                # the XLA scan tier below, parity preserved
-                try:
-                    from log_parser_tpu.runtime import faults
+                # a kernel failure (injected or a real lowering error)
+                # raises: the engine's golden fallback serves and counts it
+                from log_parser_tpu.runtime import faults
 
-                    faults.fire("kernel")
-                    rep_bg = multidfa_reported_pallas(
-                        self._dfa_pallas_plan, lines_tb
-                    )
-                    multi_pallas = [
-                        rep_bg[:, i] != 0
-                        for i in range(len(self.multi_groups))
-                    ]
-                except Exception:
-                    self.multidfa_pallas_reason = "fault"
+                faults.fire("kernel")
+                rep_bg = multidfa_reported_pallas(
+                    self._dfa_pallas_plan, lines_tb
+                )
+                multi_pallas = [
+                    rep_bg[:, i] != 0 for i in range(len(self.multi_groups))
+                ]
             else:
                 self.multidfa_pallas_reason = "no_tile"
         if multi_pallas is not None:

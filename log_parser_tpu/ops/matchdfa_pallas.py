@@ -60,7 +60,11 @@ batch tile before giving up — callers fall back to the XLA scan tier on
 ``None``. Mosaic-friendly dialect throughout: int32 only, logical
 shifts via ``jax.lax.shift_right_logical``, no bool vectors (compare
 results are cast immediately), 128-aligned lane slices (``S_pad``) and
-8-aligned sublane counts (``nc_pad``).
+8-aligned sublane counts (``nc_pad``). Per-group operands (class map,
+starts) and the output carry a squeezed leading group axis, so every
+block's last two dims meet Mosaic's rule: divisible by (8, 128) or
+equal to the array's (tests/test_tpu_compile.py compiles it for a
+described v5e).
 
 Semantics are IDENTICAL to the scan tier's reported-flag carry —
 verified bit-exactly by tests/test_matchdfa_pallas.py (interpreter
@@ -103,7 +107,6 @@ REASONS = {
     "no_union_groups": "bank packed no union multi-DFA groups",
     "table_too_large": "dense planes exceed the VMEM budget — XLA scan",
     "no_tile": "no usable batch tile for this batch size — XLA scan",
-    "fault": "kernel path raised; whole batch fell back to the XLA scan",
 }
 
 #: reason codes meaning "an admissible plan exists" (provenance split)
@@ -346,7 +349,7 @@ def dfa_tile(
             return None
         if _vmem_estimate(plan.s_pad, plan.nc_pad, tile, T) <= budget:
             return tile
-        limit = tile - 8
+        limit = tile - 1
 
 
 def _kernel(
@@ -417,9 +420,9 @@ def multidfa_reported_pallas(
     assert stride in (1, 2)
     T, B = lines_tb.shape
     if interpret is None:
-        # Mosaic needs real TPU hardware; everywhere else (CPU test
-        # meshes) the interpreter executes the same kernel semantics
-        interpret = jax.default_backend() != "tpu"
+        # the interpreter runs only on the CPU test backend; any other
+        # backend lowers through Mosaic, so a refusal raises
+        interpret = jax.default_backend() == "cpu"
     tile = dfa_tile(plan, B, T, budget=budget) if tile_b is None else tile_b
     assert tile is not None, f"no usable tile for batch rows {B}"
     G, s_pad, nc_pad = plan.n_groups, plan.s_pad, plan.nc_pad
@@ -432,7 +435,7 @@ def multidfa_reported_pallas(
                 (T, tile), lambda g, i: (0, i), memory_space=pltpu.VMEM
             ),
             pl.BlockSpec(
-                (1, 256), lambda g, i: (g, 0), memory_space=pltpu.VMEM
+                (None, 1, 256), lambda g, i: (g, 0, 0), memory_space=pltpu.VMEM
             ),
             pl.BlockSpec(
                 (nc_pad, s_pad), lambda g, i: (0, g), memory_space=pltpu.VMEM
@@ -440,17 +443,19 @@ def multidfa_reported_pallas(
             pl.BlockSpec(
                 (nc_pad, s_pad), lambda g, i: (0, g), memory_space=pltpu.VMEM
             ),
-            pl.BlockSpec((1, 2), lambda g, i: (g, 0), memory_space=pltpu.SMEM),
+            pl.BlockSpec(
+                (None, 1, 2), lambda g, i: (g, 0, 0), memory_space=pltpu.SMEM
+            ),
         ],
         out_specs=pl.BlockSpec(
-            (tile, 1), lambda g, i: (i, g), memory_space=pltpu.VMEM
+            (None, tile, 1), lambda g, i: (g, i, 0), memory_space=pltpu.VMEM
         ),
-        out_shape=jax.ShapeDtypeStruct((B, G), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((G, B, 1), jnp.int32),
         interpret=interpret,
     )(
         lines_tb.astype(jnp.int32),
-        jnp.asarray(plan.cmap),
+        jnp.asarray(plan.cmap).reshape(G, 1, 256),
         jnp.asarray(plan.p0),
         jnp.asarray(plan.p1),
-        jnp.asarray(plan.starts),
-    )
+        jnp.asarray(plan.starts).reshape(G, 1, 2),
+    )[:, :, 0].T

@@ -39,7 +39,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from log_parser_tpu import _clock as pclock
@@ -145,7 +144,7 @@ class ShardedFusedStep:
         return np.asarray(multihost_utils.process_allgather(x, tiled=True))
 
     def _sharded(self, k_local: int):
-        return shard_map(
+        return jax.shard_map(
             lambda lines, lens, om, ov, n: self._step(k_local, lines, lens, om, ov, n),
             mesh=self.mesh,
             in_specs=(
@@ -163,7 +162,7 @@ class ShardedFusedStep:
                 P(DATA_AXIS, None),  # rec seq flags [D*K_l, Q_max]
                 P(DATA_AXIS, None),  # rec ctx counts [D*K_l, 5]
             ),
-            check_rep=False,
+            check_vma=False,
         )
 
     # ------------------------------------------------------------- host API

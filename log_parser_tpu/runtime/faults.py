@@ -40,9 +40,10 @@ Each spec is ``<site>_<action>[:<arg>][@mod=value]*``:
   before the error frame goes out). Any string
   works; sites are just names the code fires, see :func:`fire` call
   sites;
-- action: ``raise`` (raise :class:`InjectedFault`; at the ``device`` site
-  :class:`InjectedDeviceFault`, which ``is_device_error`` classifies as a
-  device failure so the golden fallback serves it), ``hang`` (block for
+- action: ``raise`` (raise :class:`InjectedFault`; at the ``device`` and
+  ``kernel`` sites :class:`InjectedDeviceFault`, which ``is_device_error``
+  classifies as a device failure so the golden fallback serves it),
+  ``hang`` (block for
   ``arg`` seconds — ``inf`` blocks until :meth:`FaultRegistry.lift`),
   ``slow`` (add ``arg`` seconds of latency);
 - arg: probability in (0, 1] for ``raise`` (default 1), seconds for
@@ -250,7 +251,7 @@ class FaultRegistry:
         if chosen.action == "raise":
             if site == "quarantine":
                 exc_t = InjectedPoisonFault
-            elif site == "device":
+            elif site in ("device", "kernel"):
                 exc_t = InjectedDeviceFault
             else:
                 exc_t = InjectedFault

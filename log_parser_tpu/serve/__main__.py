@@ -413,14 +413,6 @@ def main(argv: list[str] | None = None) -> int:
         "a structured 409; default warn (LOG_PARSER_TPU_LINT_PATTERNS)",
     )
     parser.add_argument(
-        "--compile-cache-dir", default=None, metavar="DIR",
-        help="persistent XLA compilation cache directory: warm restarts "
-        "replay compiles from disk instead of re-running XLA "
-        "(utils/xlacache.py; default on at "
-        "~/.cache/log_parser_tpu/xla-cache, '0' disables; "
-        "LOG_PARSER_TPU_XLA_CACHE)",
-    )
-    parser.add_argument(
         "--pallas-dfa", default=None, choices=("on", "off"),
         help="route the union multi-DFA tier through the Pallas scan "
         "kernel (ops/matchdfa_pallas.py); bit-identical to the XLA scan, "
@@ -505,7 +497,6 @@ def main(argv: list[str] | None = None) -> int:
         (args.retry_budget, "LOG_PARSER_TPU_RETRY_BUDGET"),
         (args.watch_patterns, "LOG_PARSER_TPU_WATCH_PATTERNS"),
         (args.lint_patterns, "LOG_PARSER_TPU_LINT_PATTERNS"),
-        (args.compile_cache_dir, "LOG_PARSER_TPU_XLA_CACHE"),
         (args.tenant_root, "LOG_PARSER_TPU_TENANT_ROOT"),
         (args.tenant_budget_mb, "LOG_PARSER_TPU_TENANT_BUDGET_MB"),
         (args.tenant_max_inflight, "LOG_PARSER_TPU_TENANT_MAX_INFLIGHT"),

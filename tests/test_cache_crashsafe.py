@@ -240,3 +240,81 @@ class TestXlaCacheIntegrity:
         counts = verify_cache_integrity(d)  # must not raise into boot
         assert counts == {"checked": 0, "recorded": 0, "quarantined": 0}
         assert os.path.exists(os.path.join(d, "exec-a"))
+
+
+class TestXlaCachePlacement:
+    """Where the persistent compile cache lives: JAX's own env var when
+    set (and then the program sets no directory), else a fixed path in
+    the checkout; ``LOG_PARSER_TPU_XLA_CACHE=0`` turns it off."""
+
+    @pytest.fixture
+    def enable(self, monkeypatch, tmp_path):
+        import jax
+
+        from log_parser_tpu.utils import xlacache
+
+        updates: dict = {}
+        monkeypatch.setattr(
+            jax.config, "update", lambda k, v: updates.__setitem__(k, v)
+        )
+        monkeypatch.setattr(xlacache, "_configured", False)
+        monkeypatch.setattr(xlacache, "_cache_dir", None)
+        monkeypatch.setattr(xlacache, "_listener_registered", True)
+        monkeypatch.setattr(xlacache, "DEFAULT_DIR", str(tmp_path / "default"))
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        monkeypatch.delenv("LOG_PARSER_TPU_XLA_CACHE", raising=False)
+
+        def run() -> dict:
+            xlacache.enable_persistent_cache()
+            return updates
+
+        return run
+
+    def test_default_is_a_fixed_path_in_the_checkout(self, enable, tmp_path):
+        from log_parser_tpu.utils import xlacache
+
+        updates = enable()
+        assert updates["jax_compilation_cache_dir"] == str(tmp_path / "default")
+        assert updates["jax_persistent_cache_min_entry_size_bytes"] == 0
+        assert xlacache.stats()["dir"] == str(tmp_path / "default")
+        assert os.path.isdir(tmp_path / "default")
+
+    def test_default_dir_constant(self):
+        from log_parser_tpu.utils import xlacache
+
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert xlacache.DEFAULT_DIR == os.path.join(repo, ".cache", "xla")
+
+    def test_jax_env_var_wins_and_no_dir_is_set(
+        self, enable, monkeypatch, tmp_path
+    ):
+        from log_parser_tpu.utils import xlacache
+
+        target = tmp_path / "from-env"
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(target))
+        updates = enable()
+        assert "jax_compilation_cache_dir" not in updates
+        # thresholds and the integrity sweep still apply
+        assert updates["jax_persistent_cache_min_compile_time_secs"] == 0.0
+        assert os.path.isdir(target / ".integrity")
+        assert xlacache.stats() == {
+            "dir": str(target), "enabled": True,
+            "compileHits": xlacache._hits,
+            "compileMisses": max(0, xlacache._requests - xlacache._hits),
+        }
+
+    @pytest.mark.parametrize("value", ["0", " 0 "])
+    def test_zero_disables(self, enable, monkeypatch, value):
+        from log_parser_tpu.utils import xlacache
+
+        monkeypatch.setenv("LOG_PARSER_TPU_XLA_CACHE", value)
+        assert enable() == {}
+        assert xlacache.stats()["enabled"] is False
+
+    def test_directory_value_is_no_longer_an_override(
+        self, enable, monkeypatch, tmp_path
+    ):
+        monkeypatch.setenv("LOG_PARSER_TPU_XLA_CACHE", str(tmp_path / "old"))
+        updates = enable()
+        assert updates["jax_compilation_cache_dir"] == str(tmp_path / "default")
+        assert not os.path.exists(tmp_path / "old")

@@ -43,8 +43,8 @@ _WORKER = textwrap.dedent(
     port = sys.argv[2]
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     os.environ["LOG_PARSER_TPU_NO_FALLBACK"] = "1"
+    os.environ["JAX_PLATFORMS"] = "cpu"
     import jax
-    jax.config.update("jax_platforms", "cpu")
 
     from log_parser_tpu.parallel.distributed import (
         DistributedShardedEngine,
@@ -198,8 +198,8 @@ _CHAOS_WORKER = textwrap.dedent(
         os.environ["LOG_PARSER_TPU_BROADCAST_RETRIES"] = "1"
         os.environ["LOG_PARSER_TPU_BROADCAST_BACKOFF_S"] = "0.05"
         os.environ["LOG_PARSER_TPU_DEAD_AFTER"] = "2"
+    os.environ["JAX_PLATFORMS"] = "cpu"
     import jax
-    jax.config.update("jax_platforms", "cpu")
 
     from log_parser_tpu.parallel.distributed import (
         DistributedShardedEngine,

@@ -6,7 +6,8 @@ Mosaic lowers on TPU) against the scan tier's pair_stepper carry and an
 independent numpy byte-walk of the packed table, over the union fixture
 set plus adversarial shapes: pair-stride odd-length tails, padding-class
 rows, the dense re-scan ``lax.cond`` recovery path, zero-match batches,
-and the oversized-table / no-tile admission fallbacks — batched (the
+the oversized-table / no-tile admission fallbacks, and a kernel fault
+that raises instead of hiding — batched (the
 micro-batcher's vmapped program) and unbatched.
 """
 
@@ -361,10 +362,13 @@ def test_cube_oversized_table_falls_back(multi_engaged, monkeypatch):
     )
 
 
-def test_cube_kernel_fault_whole_batch_xla_fallback(multi_engaged, monkeypatch):
-    """An injected kernel fault drops the WHOLE batch onto the XLA scan
-    tier with identical results — the chaos_sweep --group kernel
-    scenario, at unit scope."""
+def test_cube_kernel_fault_raises(multi_engaged, monkeypatch):
+    """A kernel failure is never swallowed in cube(): an injected kernel
+    fault raises the device-classified error (the chaos_sweep --group
+    kernel scenario, at unit scope), and the next trace runs the kernel
+    with scan-tier parity."""
+    from log_parser_tpu.runtime.engine import is_device_error
+
     bank = _fixture_bank()
     monkeypatch.setenv("LOG_PARSER_TPU_PALLAS_DFA", "1")
     on = MatcherBanks(bank, **_KW)
@@ -374,11 +378,46 @@ def test_cube_kernel_fault_whole_batch_xla_fallback(multi_engaged, monkeypatch):
     lt, ln = jnp.asarray(enc.u8.T), jnp.asarray(enc.lengths)
     faults.install(FaultRegistry.parse("kernel_raise:1.0@times=1", seed=1))
     try:
-        got = np.asarray(on.cube(lt, ln))
+        with pytest.raises(faults.InjectedDeviceFault) as exc_info:
+            on.cube(lt, ln)
+        assert is_device_error(exc_info.value)
+        got = np.asarray(on.cube(lt, ln))  # the fault fired its one time
     finally:
         faults.install(None)
-    assert on.multidfa_pallas_reason == "fault"
+    assert on.multidfa_pallas_reason in mdp.ADMITTED
     np.testing.assert_array_equal(got, np.asarray(off.cube(lt, ln)))
+
+
+def test_engine_counts_kernel_fault_as_fallback(monkeypatch):
+    """Through the engine, the raised kernel fault is served by the
+    golden fallback and COUNTED (fallbackCount), and the next request
+    rides the kernel."""
+    from log_parser_tpu.config import ScoringConfig
+    from log_parser_tpu.golden import GoldenAnalyzer
+    from log_parser_tpu.models.pod import PodFailureData
+    from log_parser_tpu.runtime import AnalysisEngine
+    from tests.test_engine_parity import assert_results_match
+
+    sets = [make_pattern_set([
+        make_pattern(f"p{j}", regex=rx, confidence=0.5, severity="LOW")
+        for j, (rx, ci) in enumerate(REGEXES)
+        if not ci and rx != "x?"
+    ])]
+    monkeypatch.setenv("LOG_PARSER_TPU_PALLAS_DFA", "1")
+    engine = AnalysisEngine(sets, ScoringConfig())
+    engine.fallback_to_golden = True
+    golden = GoldenAnalyzer(sets, ScoringConfig())
+    data = PodFailureData(pod={"metadata": {"name": "p"}}, logs="\n".join(LINES))
+    faults.install(FaultRegistry.parse("kernel_raise:1.0@times=1", seed=1))
+    try:
+        assert_results_match(engine.analyze(data), golden.analyze(data))
+        assert engine.fallback_count == 1
+        assert_results_match(engine.analyze(data), golden.analyze(data))
+    finally:
+        faults.install(None)
+    assert engine.fallback_count == 1
+    k = engine.kernel_stats.stats()
+    assert k["reason"] in mdp.ADMITTED and k["kernelBatches"] >= 1, k
 
 
 def test_engine_kernel_stats_counters():
@@ -396,7 +435,7 @@ def test_engine_kernel_stats_counters():
     geom = {"nGroups": 2, "sPad": 128}
     ks.note(128, active=True, enabled=True, reason="byte_classed",
             geometry=geom)
-    ks.note(64, active=False, enabled=True, reason="fault", geometry=geom)
+    ks.note(64, active=False, enabled=True, reason="no_tile", geometry=geom)
     ks.note(32, active=False, enabled=False, reason="off")  # not counted
     s = ks.stats()
     assert s["kernelBatches"] == 1 and s["kernelRows"] == 128
@@ -415,7 +454,7 @@ def test_reason_codes_documented():
         "no_union_groups",
         "table_too_large",
         "no_tile",
-        "fault",
     }
+    assert "fault" not in mdp.REASONS  # kernel errors raise, never hide
     assert "ok" not in mdp.REASONS  # replaced by the admission provenance
     assert mdp.ADMITTED == {"byte_classed", "split"}

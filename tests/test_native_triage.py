@@ -8,6 +8,8 @@ from __future__ import annotations
 import os
 import sys
 
+import pytest
+
 from log_parser_tpu import native
 from log_parser_tpu.obs import native_load_reason
 
@@ -68,3 +70,81 @@ def test_check_native_tool_reports_without_booting():
     assert doc["loaded"] == native.available()
     if not doc["loaded"]:
         assert doc["load_error"]
+
+
+class TestBuildRule:
+    """The shared object is rebuilt unless its stamp holds the sha256 of
+    the committed source — content, not mtime, so a fresh copy of the
+    tree builds from what it carries."""
+
+    @pytest.fixture
+    def tree(self, monkeypatch, tmp_path):
+        src = tmp_path / "native" / "log_parser_native.cpp"
+        src.parent.mkdir()
+        src.write_text("// v1\n")
+        so = tmp_path / "native" / "build" / "log_parser_native.so"
+        monkeypatch.setattr(native, "_SRC", src)
+        monkeypatch.setattr(native, "_SO", so)
+        monkeypatch.setattr(native, "_STAMP", so.with_name(so.name + ".sha256"))
+        builds: list[str] = []
+
+        def fake_compile(digest):
+            so.write_bytes(b"ELF")
+            native._STAMP.write_text(digest + "\n")
+            builds.append(digest)
+            return True
+
+        monkeypatch.setattr(native, "_compile", fake_compile)
+        return src, so, builds
+
+    def test_builds_when_absent_then_reuses(self, tree):
+        src, so, builds = tree
+        assert native._ensure_built()
+        assert builds == [native._src_digest()]
+        assert native._ensure_built()
+        assert len(builds) == 1  # stamp current: no second build
+
+    def test_source_change_rebuilds_whatever_the_mtimes(self, tree):
+        src, so, builds = tree
+        native._ensure_built()
+        src.write_text("// v2\n")
+        # a copied tree can leave the binary NEWER than the source
+        os.utime(so, (4e9, 4e9))
+        assert native._ensure_built()
+        assert len(builds) == 2 and builds[1] == native._src_digest()
+
+    def test_binary_without_stamp_is_rebuilt(self, tree):
+        src, so, builds = tree
+        so.parent.mkdir(parents=True)
+        so.write_bytes(b"ELF from somewhere else")
+        assert native._ensure_built()
+        assert len(builds) == 1
+
+    def test_real_build_stamps_the_source_digest(self, monkeypatch, tmp_path):
+        """The real g++ path writes the .so and its stamp atomically."""
+        import shutil
+
+        if shutil.which("g++") is None:
+            pytest.skip("no g++ on this host")
+        so = tmp_path / "build" / "log_parser_native.so"
+        so.parent.mkdir()
+        monkeypatch.setattr(native, "_SO", so)
+        monkeypatch.setattr(native, "_STAMP", so.with_name(so.name + ".sha256"))
+        digest = native._src_digest()
+        assert native._compile(digest)
+        assert so.stat().st_size > 0
+        assert native._stamp() == digest
+        assert not list(so.parent.glob("*.tmp"))
+
+
+def test_build_product_is_not_committed():
+    import subprocess
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    r = subprocess.run(
+        ["git", "ls-files", "native/build"], capture_output=True, text=True,
+        cwd=repo,
+    )
+    if r.returncode != 0:
+        pytest.skip("not a git checkout")
+    assert r.stdout.strip() == ""

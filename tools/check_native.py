@@ -30,7 +30,6 @@ import argparse
 import json
 import os
 import shutil
-import subprocess
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
@@ -47,24 +46,15 @@ def triage(rebuild: bool = False) -> dict:
         "toolchain": shutil.which("g++"),
     }
     if rebuild:
-        try:
-            native._SO.unlink()
-        except OSError:
-            pass
-        cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC",
-               str(native._SRC), "-o", str(native._SO)]
-        doc["rebuild_cmd"] = " ".join(cmd)
-        try:
-            native._SO.parent.mkdir(parents=True, exist_ok=True)
-            proc = subprocess.run(
-                cmd, capture_output=True, text=True, timeout=300
-            )
-            doc["rebuild_rc"] = proc.returncode
-            if proc.returncode != 0:
-                doc["rebuild_stderr"] = proc.stderr.strip()[:2000]
-        except (OSError, subprocess.TimeoutExpired) as exc:
-            doc["rebuild_rc"] = -1
-            doc["rebuild_stderr"] = str(exc)
+        # the loader's own from-source build, as a fresh checkout runs it
+        for path in (native._SO, native._STAMP):
+            try:
+                path.unlink()
+            except OSError:
+                pass
+        doc["rebuild_rc"] = 0 if native._ensure_built() else 1
+        if doc["rebuild_rc"]:
+            doc["rebuild_stderr"] = native._load_error
     doc["glibcxx"] = native.glibcxx_triage()
     # the real load attempt, exactly as the server would do it at boot
     doc["loaded"] = native.available()

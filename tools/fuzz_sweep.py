@@ -709,10 +709,6 @@ def run_stream_sweep(start: int, end: int) -> int:
 
 
 def run_sweep(mode: str, start: int, end: int) -> int:
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-
     from test_engine_parity import (  # the suite's generators ARE the spec
         _force_bit_policy,
         assert_results_match,

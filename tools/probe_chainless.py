@@ -22,7 +22,7 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from bench_common import pin_platform, timeit  # noqa: E402
+from bench_common import timeit  # noqa: E402
 
 
 def main() -> None:
@@ -32,7 +32,6 @@ def main() -> None:
     ap.add_argument("--width", type=int, default=112)
     args = ap.parse_args()
 
-    pin_platform()
     import jax
     import jax.numpy as jnp
     import numpy as np
