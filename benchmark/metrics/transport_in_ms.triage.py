@@ -1,0 +1,9 @@
+"""transport_in_ms.triage: Δ milliseconds in the /parse handler's
+``transport.read`` and ``transport.decode`` stages (serve/http.py) per
+answered request."""
+
+from benchmark.stages import per_request_ms, stage_s
+
+
+def read(run):
+    return per_request_ms(run, stage_s(run, "transport.read", "transport.decode"))

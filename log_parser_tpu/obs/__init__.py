@@ -131,6 +131,11 @@ def _fault_samples():
     return [("logparser_faults_armed", {}, armed)]
 
 
+def _process_cpu_samples():
+    t = os.times()
+    return [("logparser_process_cpu_seconds_total", {}, t.user + t.system)]
+
+
 def _env_float(name: str, default: float) -> float:
     try:
         return float(os.environ.get(name, "") or default)
@@ -187,6 +192,18 @@ class Obs:
             "logparser_phase_seconds", ("tenant", "phase", "route"),
             buckets=PHASE_BUCKETS, max_series=256,
         )
+        self.phase_cpu = reg.counter(
+            "logparser_phase_cpu_seconds_total", ("tenant", "phase", "route"),
+            max_series=256,
+        )
+        self.stage_seconds = reg.histogram(
+            "logparser_stage_seconds", ("tenant", "stage"),
+            buckets=PHASE_BUCKETS, max_series=256,
+        )
+        self.request_cpu = reg.counter(
+            "logparser_request_cpu_seconds_total", ("tenant", "route"),
+            max_series=256,
+        )
         self.slow_requests = reg.counter(
             "logparser_slow_requests_total", ("route",)
         )
@@ -207,17 +224,12 @@ class Obs:
         self.device_waste = reg.gauge(
             "logparser_device_dummy_waste_ratio", ("tenant",)
         )
-        self.device_flops = reg.counter(
-            "logparser_device_flops_total", ("tenant",)
-        )
-        self.device_hbm_bytes = reg.counter(
-            "logparser_device_hbm_bytes_total", ("tenant",)
-        )
         reg.register_collector("slo", self.slo.samples)
         reg.register_collector("spans", self._span_samples)
         reg.register_collector("native", _native_samples)
         reg.register_collector("compilecache", _compile_cache_samples)
         reg.register_collector("faults", _fault_samples)
+        reg.register_collector("process", _process_cpu_samples)
 
     def _span_samples(self):
         st = self.spans.stats()
@@ -249,9 +261,9 @@ class Obs:
     def note_served(self, trace, start: float, tenant: str,
                     outcome: str = "ok", n_lines: int | None = None,
                     error: str | None = None) -> None:
-        """One engine-served request: phase histograms + ring entry.
-        Called from ``_finish`` (and the fallback path) with the
-        request's :class:`PhaseTrace`."""
+        """One engine-served request: phase and stage histograms, phase
+        CPU + ring entry. Called from ``_finish`` (and the fallback path)
+        with the request's :class:`PhaseTrace`."""
         route = getattr(trace, "route", "device") or "device"
         request_id = getattr(trace, "request_id", None) or self.new_request_id()
         total_ms = (self.clock() - start) * 1e3
@@ -259,6 +271,9 @@ class Obs:
         observe = self.phase_seconds.observe
         for phase, seconds in phases.items():
             observe(seconds, tenant=tenant, phase=phase, route=route)
+        for phase, seconds in trace.cpu_dict().items():
+            self.phase_cpu.inc(seconds, tenant=tenant, phase=phase, route=route)
+        self.note_stages(trace.stage_dict(), tenant)
         entry = {
             "requestId": request_id,
             "tenant": tenant,
@@ -329,14 +344,28 @@ class Obs:
                 attrs=attrs,
             )
 
+    def note_stages(self, stages: dict[str, float], tenant: str) -> None:
+        """One observation per stage of one request: the engine's
+        through :meth:`note_served`, the transport's from the handler
+        once the response is written."""
+        observe = self.stage_seconds.observe
+        for stage, seconds in stages.items():
+            observe(seconds, tenant=tenant, stage=stage)
+
+    def note_request_cpu(self, seconds: float, tenant: str,
+                         route: str) -> None:
+        """Thread CPU the server spent on requests: a ``/parse``
+        handler's, from its start to after its write, or a batch flush's
+        on the scheduler thread (``route`` ``batched``)."""
+        self.request_cpu.inc(seconds, tenant=tenant, route=route)
+
     def note_dispatch(self, tenant: str, tier: str, padded_rows: int = 0,
-                      dummy_rows: int = 0, waste: float | None = None,
-                      flops: float | None = None,
-                      hbm_bytes: float | None = None) -> None:
+                      dummy_rows: int = 0,
+                      waste: float | None = None) -> None:
         """Per-dispatch device-utilization accounting: every device
         step (direct, batched flush, line-cache residual) folds its
-        cost into the per-tenant ``logparser_device_*`` families so
-        roofline math is a scrape, not a bench run."""
+        padded and dummy rows into the per-tenant ``logparser_device_*``
+        families."""
         self.device_dispatches.inc(tenant=tenant, tier=tier)
         if padded_rows:
             self.device_padded_rows.inc(padded_rows, tenant=tenant)
@@ -344,10 +373,6 @@ class Obs:
             self.device_dummy_rows.inc(dummy_rows, tenant=tenant)
         if waste is not None:
             self.device_waste.set(waste, tenant=tenant)
-        if flops:
-            self.device_flops.inc(flops, tenant=tenant)
-        if hbm_bytes:
-            self.device_hbm_bytes.inc(hbm_bytes, tenant=tenant)
 
     def note_dropped(self, transport: str) -> None:
         """A computed response the transport could not write back —

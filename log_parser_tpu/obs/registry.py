@@ -39,6 +39,20 @@ METRICS = {
     "logparser_phase_seconds": (
         "histogram",
         "Per-phase engine latency fed by PhaseTrace, by tenant/phase/route."),
+    "logparser_phase_cpu_seconds_total": (
+        "counter",
+        "Thread CPU seconds inside each timed PhaseTrace phase, by "
+        "tenant/phase/route."),
+    "logparser_stage_seconds": (
+        "histogram",
+        "Stages inside or between the phases (transport, device copies, "
+        "frequency state), by tenant/stage."),
+    "logparser_request_cpu_seconds_total": (
+        "counter",
+        "Thread CPU seconds of serving requests: each /parse handler from "
+        "its start to after its write, plus batch flushes, by tenant/route."),
+    "logparser_process_cpu_seconds_total": (
+        "counter", "User plus system CPU seconds of the server process."),
     "logparser_slow_requests_total": (
         "counter",
         "Requests captured in the slow-trace ring (above --trace-slow-ms)."),
@@ -129,10 +143,6 @@ METRICS = {
         "counter", "Dummy pow2-padding request slots dispatched (waste)."),
     "logparser_device_dummy_waste_ratio": (
         "gauge", "Dummy-slot waste fraction of the last batched dispatch."),
-    "logparser_device_flops_total": (
-        "counter", "XLA cost-analysis FLOPs accumulated over dispatches."),
-    "logparser_device_hbm_bytes_total": (
-        "counter", "XLA cost-analysis bytes accessed over dispatches."),
     # --------------------------------------- plan geometry + load state
     "logparser_kernel_plan_vmem_bytes": (
         "gauge", "Admitted union-DFA plan VMEM bytes per grid step."),

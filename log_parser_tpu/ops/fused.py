@@ -45,6 +45,7 @@ from log_parser_tpu.patterns.bank import (
     CTX_WARN,
     PatternBank,
 )
+from log_parser_tpu.utils.trace import NO_TRACE
 
 # "no hit" distance sentinel: larger than any window yet far from int32
 # overflow when compared/subtracted
@@ -334,24 +335,28 @@ class FusedMatchScore:
         self.config = config
         self.matchers = matchers  # MatcherBanks: tiered Shift-Or + DFA cube
         self.t = FusedStaticTables(bank, config)
+
+        # named functions, so a profile's module line names each program
+        def logparser_step_ov(k, lines, lens, n, om, ov):
+            return self._step(k, lines, lens, n, (om, ov))
+
+        def logparser_step(k, lines, lens, n):
+            return self._step(k, lines, lens, n, None)
+
+        def logparser_cube_ov(lines, lens, n, om, ov):
+            return self._cube_step(lines, lens, n, (om, ov))
+
+        def logparser_cube(lines, lens, n):
+            return self._cube_step(lines, lens, n, None)
+
         # K is a static arg: each bucket size is its own cached executable
-        self._jit_ov = jax.jit(
-            lambda k, lines, lens, n, om, ov: self._step(k, lines, lens, n, (om, ov)),
-            static_argnums=(0,),
-        )
-        self._jit_plain = jax.jit(
-            lambda k, lines, lens, n: self._step(k, lines, lens, n, None),
-            static_argnums=(0,),
-        )
+        self._jit_ov = jax.jit(logparser_step_ov, static_argnums=(0,))
+        self._jit_plain = jax.jit(logparser_step, static_argnums=(0,))
         # cube-only programs (the line-cache residual path): no extraction,
         # just the post-override bit matrix — extraction happens on the host
         # from cached + fresh rows together (runtime/linecache.py)
-        self._jit_cube_ov = jax.jit(
-            lambda lines, lens, n, om, ov: self._cube_step(lines, lens, n, (om, ov))
-        )
-        self._jit_cube_plain = jax.jit(
-            lambda lines, lens, n: self._cube_step(lines, lens, n, None)
-        )
+        self._jit_cube_ov = jax.jit(logparser_cube_ov)
+        self._jit_cube_plain = jax.jit(logparser_cube)
 
     # ------------------------------------------------------------- host entry
 
@@ -363,25 +368,24 @@ class FusedMatchScore:
         n_lines: int,
         override_mask: np.ndarray | None = None,
         override_val: np.ndarray | None = None,
+        trace=NO_TRACE,
     ):
         """Launch the fused program asynchronously at record capacity ``k``
         and return the un-synchronized device outputs. Callers fan out
         several dispatches (e.g. one pattern block per device) before the
-        first blocking read.
+        first blocking read. ``trace`` (a PhaseTrace) times the
+        ``device.upload`` and ``device.launch`` stages.
 
         The batch uploads in its contiguous [B, T] layout and transposes
         ON DEVICE (a free layout op inside the compiled program): a
         host-side ``.T`` copy before upload measured 82 ms vs 9 ms for
         the contiguous config-2 batch — ~10% of a serial request."""
-        lines_bt = jnp.asarray(lines_u8)
-        lens = jnp.asarray(lengths)
-        n = jnp.asarray(n_lines, dtype=jnp.int32)
-        if override_mask is not None:
-            return self._jit_ov(
-                k, lines_bt, lens, n,
-                jnp.asarray(override_mask), jnp.asarray(override_val),
-            )
-        return self._jit_plain(k, lines_bt, lens, n)
+        with trace.stage("device.upload"):
+            args = _upload(lines_u8, lengths, n_lines, override_mask, override_val)
+        with trace.stage("device.launch"):
+            if override_mask is not None:
+                return self._jit_ov(k, *args)
+            return self._jit_plain(k, *args)
 
     def k_ladder(self, lines_u8: np.ndarray, k_hint: int = 0):
         """The record-capacity buckets to try, smallest viable first."""
@@ -391,13 +395,16 @@ class FusedMatchScore:
             start += 1
         return [min(k, cap) for k in (*K_LADDER[start:], cap)], cap
 
-    def resolve(self, out) -> MatchRecords | None:
+    def resolve(self, out, trace=NO_TRACE) -> MatchRecords | None:
         """Synchronize one dispatch — a single packed-array transfer —
         and unpack; None signals K overflow (re-dispatch at the next
         ladder rung)."""
-        return unpack_records(
-            np.asarray(out), max(1, self.t.s_max), max(1, self.t.q_max)
-        )
+        return self.unpack(_read_back(out, trace))
+
+    def unpack(self, arr: np.ndarray) -> MatchRecords | None:
+        """:func:`unpack_records` of one host copy at this program's
+        record widths."""
+        return unpack_records(arr, max(1, self.t.s_max), max(1, self.t.q_max))
 
     def run(
         self,
@@ -407,14 +414,18 @@ class FusedMatchScore:
         override_mask: np.ndarray | None = None,
         override_val: np.ndarray | None = None,
         k_hint: int = 0,
+        trace=NO_TRACE,
     ) -> MatchRecords:
         """Executes the fused program, growing the record buffer until the
         batch's matches fit. ``k_hint``: expected match count (e.g. the
         previous request's), used to pick the starting bucket."""
         ladder, cap = self.k_ladder(lines_u8, k_hint)
         for k in ladder:
-            out = self.dispatch(k, lines_u8, lengths, n_lines, override_mask, override_val)
-            recs = self.resolve(out)
+            out = self.dispatch(
+                k, lines_u8, lengths, n_lines, override_mask, override_val,
+                trace=trace,
+            )
+            recs = self.resolve(out, trace)
             if recs is not None or k >= cap:
                 if recs is None:  # cap rung can never truly overflow
                     raise AssertionError("unreachable: K ladder capped at B*P")
@@ -439,45 +450,42 @@ class FusedMatchScore:
         n_lines: int,
         override_mask: np.ndarray | None = None,
         override_val: np.ndarray | None = None,
+        trace=NO_TRACE,
     ) -> np.ndarray:
         """Post-override match-bit matrix [B, n_columns] for a (residual)
         batch — the cacheable unit of the routing tier. Everything the
         fused extraction derives is a pure function of these bits plus the
         request's line count, so the line cache memoizes rows of THIS
         matrix and replays extraction on the host."""
-        lines_bt = jnp.asarray(lines_u8)
-        lens = jnp.asarray(lengths)
-        n = jnp.asarray(n_lines, dtype=jnp.int32)
-        if override_mask is not None:
-            out = self._jit_cube_ov(
-                lines_bt, lens, n,
-                jnp.asarray(override_mask), jnp.asarray(override_val),
-            )
-        else:
-            out = self._jit_cube_plain(lines_bt, lens, n)
-        return np.asarray(out)
+        with trace.stage("device.upload"):
+            args = _upload(lines_u8, lengths, n_lines, override_mask, override_val)
+        with trace.stage("device.launch"):
+            if override_mask is not None:
+                out = self._jit_cube_ov(*args)
+            else:
+                out = self._jit_cube_plain(*args)
+        return _read_back(out, trace)
 
     # ---------------------------------------------------------- device program
 
     def _cube_step(self, lines_bt, lengths, n_lines, overrides):
         """The shared front half of :meth:`_step`: tiered match cube,
         override splice, padding-row mask. Returns bool [B, n_columns]."""
-        lines_tb = lines_bt.T  # device-side layout change (see dispatch)
-        B = lengths.shape[0]
-        row_idx = jnp.arange(B, dtype=jnp.int32)
-        valid = row_idx < n_lines
-        cube = jax.lax.optimization_barrier(
-            self.matchers.cube(lines_tb, lengths)
-        )
-        if overrides is not None:
-            om, ov = overrides
-            cube = jnp.where(om, ov, cube)
-        return cube & valid[:, None]
+        with jax.named_scope("logparser.cube"):
+            lines_tb = lines_bt.T  # device-side layout change (see dispatch)
+            B = lengths.shape[0]
+            row_idx = jnp.arange(B, dtype=jnp.int32)
+            valid = row_idx < n_lines
+            cube = jax.lax.optimization_barrier(
+                self.matchers.cube(lines_tb, lengths)
+            )
+            if overrides is not None:
+                om, ov = overrides
+                cube = jnp.where(om, ov, cube)
+            return cube & valid[:, None]
 
     def _step(self, K, lines_bt, lengths, n_lines, overrides):
-        bank, t = self.bank, self.t
         B = lengths.shape[0]
-        P = bank.n_patterns
         row_idx = jnp.arange(B, dtype=jnp.int32)
 
         # ---- match cube (tiered: Shift-Or + DFA banks) --------------------
@@ -488,7 +496,14 @@ class FusedMatchScore:
         # nothing: empty-matching regexes (^$, \s*) would otherwise
         # produce phantom hits on zero-length padding.
         cube = self._cube_step(lines_bt, lengths, n_lines, overrides)
+        with jax.named_scope("logparser.extract"):
+            return self._extract(K, cube, row_idx, B, n_lines)
 
+    def _extract(self, K, cube, row_idx, B, n_lines):
+        """The back half of :meth:`_step`: integer factor components and
+        the K-capped record compaction, packed into one array."""
+        bank, t = self.bank, self.t
+        P = bank.n_patterns
         if P == 0:
             z32 = jnp.zeros((K,), jnp.int32)
             return pack_records(
@@ -564,6 +579,31 @@ class FusedMatchScore:
         return jnp.stack(per_shape, axis=1)  # [B, U, 5]
 
 
+def _upload(lines_u8, lengths, n_lines, override_mask, override_val) -> tuple:
+    """The host → device copies of one dispatch's inputs, in the order
+    the programs take them (the overrides only where there are some)."""
+    args = (
+        jnp.asarray(lines_u8),
+        jnp.asarray(lengths),
+        jnp.asarray(n_lines, dtype=jnp.int32),
+    )
+    if override_mask is None:
+        return args
+    return args + (jnp.asarray(override_mask), jnp.asarray(override_val))
+
+
+def _read_back(out, trace) -> np.ndarray:
+    """Wait for ``out`` (``device.wait``), then copy it to the host
+    (``device.readback``). The copy is queued before the wait, so it
+    starts when the program ends, as a bare ``np.asarray`` would start it,
+    and not after the host has woken from the wait."""
+    out.copy_to_host_async()
+    with trace.stage("device.wait"):
+        out.block_until_ready()
+    with trace.stage("device.readback"):
+        return np.asarray(out)
+
+
 class FusedBatchMatchScore:
     """Cross-request batched fused program: ``vmap`` of
     :meth:`FusedMatchScore._step` over a leading request axis R.
@@ -586,18 +626,19 @@ class FusedBatchMatchScore:
 
     def __init__(self, fused: FusedMatchScore):
         self.fused = fused
-        self._jit_plain = jax.jit(
-            lambda k, lines, lens, n: jax.vmap(
+
+        def logparser_batch_step(k, lines, lens, n):
+            return jax.vmap(
                 lambda L, le, nn: fused._step(k, L, le, nn, None)
-            )(lines, lens, n),
-            static_argnums=(0,),
-        )
-        self._jit_ov = jax.jit(
-            lambda k, lines, lens, n, om, ov: jax.vmap(
+            )(lines, lens, n)
+
+        def logparser_batch_step_ov(k, lines, lens, n, om, ov):
+            return jax.vmap(
                 lambda L, le, nn, m, v: fused._step(k, L, le, nn, (m, v))
-            )(lines, lens, n, om, ov),
-            static_argnums=(0,),
-        )
+            )(lines, lens, n, om, ov)
+
+        self._jit_plain = jax.jit(logparser_batch_step, static_argnums=(0,))
+        self._jit_ov = jax.jit(logparser_batch_step_ov, static_argnums=(0,))
 
     def run(
         self,
@@ -607,24 +648,23 @@ class FusedBatchMatchScore:
         override_mask: np.ndarray | None = None,  # [R, B, C] bool
         override_val: np.ndarray | None = None,
         k_hint: int = 0,
+        trace=NO_TRACE,
     ) -> list[MatchRecords]:
         """One batched dispatch per K rung; returns per-request records in
-        request order. Overflow of any slot climbs the shared ladder."""
+        request order. Overflow of any slot climbs the shared ladder.
+        ``trace`` times the same device stages as the unbatched path."""
         R = lines_u8.shape[0]
         ladder, cap = self.fused.k_ladder(lines_u8[0], k_hint)
-        lines = jnp.asarray(lines_u8)
-        lens = jnp.asarray(lengths)
-        n = jnp.asarray(n_lines, dtype=jnp.int32)
+        with trace.stage("device.upload"):
+            args = _upload(lines_u8, lengths, n_lines, override_mask, override_val)
         for k in ladder:
-            if override_mask is not None:
-                out = self._jit_ov(
-                    k, lines, lens, n,
-                    jnp.asarray(override_mask), jnp.asarray(override_val),
-                )
-            else:
-                out = self._jit_plain(k, lines, lens, n)
-            arr = np.asarray(out)  # [R, packed] — ONE device→host transfer
-            recs = [self.fused.resolve(arr[i]) for i in range(R)]
+            with trace.stage("device.launch"):
+                if override_mask is not None:
+                    out = self._jit_ov(k, *args)
+                else:
+                    out = self._jit_plain(k, *args)
+            arr = _read_back(out, trace)  # [R, packed] — ONE device→host transfer
+            recs = [self.fused.unpack(arr[i]) for i in range(R)]
             if all(r is not None for r in recs):
                 return recs
             if k >= cap:

@@ -469,6 +469,16 @@ class AcRunner:
         return np.asarray(out)
 
 
+# the ``jax.named_scope`` of each device tier inside the cube program
+TIER_SCOPES = {
+    "dense": "tier.dense",
+    "shiftor": "tier.shiftor",
+    "bitglush": "tier.bitglush",
+    "union": "tier.union",
+    "prefilter": "tier.prefilter",
+}
+
+
 class MatcherBanks:
     """Tiered device matchers for one PatternBank's columns.
 
@@ -1045,19 +1055,26 @@ class MatcherBanks:
 
         Both banks advance in ONE fused scan over byte pairs — the scan is
         the serial axis, so composing steppers instead of running two scans
-        halves the sequential latency when both tiers are populated."""
+        halves the sequential latency when both tiers are populated. Each
+        tier's work, inside the scan and out, runs under its own
+        ``jax.named_scope`` (:data:`TIER_SCOPES`), so a profile's device
+        ops name their tier."""
         jnp = self._jnp
         B = lengths.shape[0]
         cube = jnp.zeros((B, self.bank.n_columns), dtype=bool)
         steppers = []
         if self.dfa_cols:
-            steppers.append(
-                (self.dfa_bank.pair_stepper(B, lengths), self.dfa_cols, True)
-            )
+            with jax.named_scope(TIER_SCOPES["dense"]):
+                steppers.append((
+                    self.dfa_bank.pair_stepper(B, lengths), self.dfa_cols, True,
+                    TIER_SCOPES["dense"],
+                ))
         if self.shiftor is not None:
-            steppers.append(
-                (self.shiftor.pair_stepper(B, lengths), self.shiftor_cols, False)
-            )
+            with jax.named_scope(TIER_SCOPES["shiftor"]):
+                steppers.append((
+                    self.shiftor.pair_stepper(B, lengths), self.shiftor_cols,
+                    False, TIER_SCOPES["shiftor"],
+                ))
         if self.bitglush is not None:
             use_pallas = False
             if self.bitglush_use_pallas:
@@ -1069,19 +1086,21 @@ class MatcherBanks:
                 )
 
                 use_pallas = pick_tile(B) is not None
-            if use_pallas:
-                hits = bitglush_hits_pallas(self.bitglush, lines_tb, lengths)
-                cube = cube.at[
-                    :, jnp.asarray(np.asarray(self.bitglush_cols))
-                ].set(self.bitglush.columns_from_hits(hits))
-            else:
-                steppers.append(
-                    (
-                        self.bitglush.pair_stepper(B, lengths),
-                        self.bitglush_cols,
-                        False,
+            with jax.named_scope(TIER_SCOPES["bitglush"]):
+                if use_pallas:
+                    hits = bitglush_hits_pallas(self.bitglush, lines_tb, lengths)
+                    cube = cube.at[
+                        :, jnp.asarray(np.asarray(self.bitglush_cols))
+                    ].set(self.bitglush.columns_from_hits(hits))
+                else:
+                    steppers.append(
+                        (
+                            self.bitglush.pair_stepper(B, lengths),
+                            self.bitglush_cols,
+                            False,
+                            TIER_SCOPES["bitglush"],
+                        )
                     )
-                )
         multi_pallas: list | None = None
         if self.multi_groups and self.multidfa_use_pallas:
             from log_parser_tpu.ops.matchdfa_pallas import (
@@ -1095,33 +1114,41 @@ class MatcherBanks:
                 from log_parser_tpu.runtime import faults
 
                 faults.fire("kernel")
-                rep_bg = multidfa_reported_pallas(
-                    self._dfa_pallas_plan, lines_tb
-                )
-                multi_pallas = [
-                    rep_bg[:, i] != 0 for i in range(len(self.multi_groups))
-                ]
+                with jax.named_scope(TIER_SCOPES["union"]):
+                    rep_bg = multidfa_reported_pallas(
+                        self._dfa_pallas_plan, lines_tb
+                    )
+                    multi_pallas = [
+                        rep_bg[:, i] != 0
+                        for i in range(len(self.multi_groups))
+                    ]
             else:
                 self.multidfa_pallas_reason = "no_tile"
         if multi_pallas is not None:
             pass  # reported flags join multi_reps after the fused scan
         elif self.multi_cluster is not None:
             cluster = self.multi_cluster
-            steppers.append(
-                (cluster.pair_stepper(B, lengths), cluster, False)
-            )
+            with jax.named_scope(TIER_SCOPES["union"]):
+                steppers.append((
+                    cluster.pair_stepper(B, lengths), cluster, False,
+                    TIER_SCOPES["union"],
+                ))
         elif self.multi_groups:
             # CPU: per-group steppers in the same fused scan (see the
             # cluster construction note); group order must match
             # self.multi_groups — _multi_contribution zips against it
-            for g in self.multi_groups:
-                steppers.append(
-                    (g.pair_stepper(B, lengths), "multi_group", False)
-                )
+            with jax.named_scope(TIER_SCOPES["union"]):
+                for g in self.multi_groups:
+                    steppers.append((
+                        g.pair_stepper(B, lengths), "multi_group", False,
+                        TIER_SCOPES["union"],
+                    ))
         if self.prefilter is not None:
-            steppers.append(
-                (self.prefilter.anyhit_stepper(B, lengths), None, False)
-            )
+            with jax.named_scope(TIER_SCOPES["prefilter"]):
+                steppers.append((
+                    self.prefilter.anyhit_stepper(B, lengths), None, False,
+                    TIER_SCOPES["prefilter"],
+                ))
         if not steppers:
             if multi_pallas is not None:
                 cube = self._multi_contribution(
@@ -1134,39 +1161,43 @@ class MatcherBanks:
 
         def fused_step(carries, xs):
             pair_t, t = xs
-            new = tuple(
-                s[0][1](c, pair_t[0], pair_t[1], t)
-                for s, c in zip(steppers, carries)
-            )
-            return new, None
+            new = []
+            for s, c in zip(steppers, carries):
+                with jax.named_scope(s[3]):
+                    new.append(s[0][1](c, pair_t[0], pair_t[1], t))
+            return tuple(new), None
 
         finals, _ = jax.lax.scan(fused_step, inits, (pairs, ts))
         multi_reps: list[jax.Array] = []
-        for (stepper, cols, is_dfa), carry in zip(steppers, finals):
-            out = stepper[2](carry)
-            if cols is None:  # prefilter: hit words -> verify stage
-                contrib = self.prefilter.contribution(lines_tb, lengths, out)
-                cube = cube.at[
-                    :, jnp.asarray(np.asarray(self.prefilter_cols))
-                ].set(contrib)
-                continue
-            if isinstance(cols, MultiDfaCluster):  # per-group reported cols
-                multi_reps.extend(out)
-                continue
-            if isinstance(cols, str):  # "multi_group": one group's carry
-                multi_reps.append(out[1])
-                continue
-            if is_dfa:
-                out = out[:, : len(cols)]
-            # tier column sets are disjoint today, so .max equals .set;
-            # .max keeps the scatter an OR if a column ever lands in two
-            # tiers (a round-4 alternative-split experiment did exactly
-            # that and was silently masked by .set — PERF.md §9b)
-            cube = cube.at[:, jnp.asarray(np.asarray(cols))].max(out)
+        for (stepper, cols, is_dfa, scope), carry in zip(steppers, finals):
+            with jax.named_scope(scope):
+                out = stepper[2](carry)
+                if cols is None:  # prefilter: hit words -> verify stage
+                    contrib = self.prefilter.contribution(lines_tb, lengths, out)
+                    cube = cube.at[
+                        :, jnp.asarray(np.asarray(self.prefilter_cols))
+                    ].set(contrib)
+                    continue
+                if isinstance(cols, MultiDfaCluster):  # per-group reported cols
+                    multi_reps.extend(out)
+                    continue
+                if isinstance(cols, str):  # "multi_group": one group's carry
+                    multi_reps.append(out[1])
+                    continue
+                if is_dfa:
+                    out = out[:, : len(cols)]
+                # tier column sets are disjoint today, so .max equals .set;
+                # .max keeps the scatter an OR if a column ever lands in two
+                # tiers (a round-4 alternative-split experiment did exactly
+                # that and was silently masked by .set — PERF.md §9b)
+                cube = cube.at[:, jnp.asarray(np.asarray(cols))].max(out)
         if multi_pallas is not None:
             multi_reps.extend(multi_pallas)
         if multi_reps:
-            cube = self._multi_contribution(cube, lines_tb, lengths, multi_reps)
+            with jax.named_scope(TIER_SCOPES["union"]):
+                cube = self._multi_contribution(
+                    cube, lines_tb, lengths, multi_reps
+                )
         return cube
 
     def _multi_word_pass(self, lines_tb, lengths, N: int):

@@ -61,6 +61,7 @@ from log_parser_tpu.parallel.resilience import (
 )
 from log_parser_tpu.parallel.sharded import ShardedEngine, ShardedFusedStep
 from log_parser_tpu.runtime import faults
+from log_parser_tpu.utils.trace import NO_TRACE
 
 log = logging.getLogger(__name__)
 
@@ -309,7 +310,7 @@ class DistributedShardedEngine(ShardedEngine):
                 )
         return self._local_step_cache
 
-    def _run_device(self, enc, n_lines: int, om, ov, trace=None):
+    def _run_device(self, enc, n_lines: int, om, ov, trace=NO_TRACE):
         # batch rows are padded to a multiple of the GLOBAL mesh size
         # (_corpus_min_rows), which the local device count divides — the
         # local shard_map sees the same shapes, just fewer shards

@@ -43,6 +43,7 @@ from log_parser_tpu.ops.fused import FusedMatchScore, MatchRecords
 from log_parser_tpu.ops.match import MatcherBanks
 from log_parser_tpu.patterns.bank import PatternBank
 from log_parser_tpu.runtime.engine import AnalysisEngine
+from log_parser_tpu.utils.trace import NO_TRACE
 
 
 def partition_pattern_sets(
@@ -158,7 +159,7 @@ class PatternShardedEngine(AnalysisEngine):
         take = np.asarray(cols)
         return np.ascontiguousarray(om[:, take]), np.ascontiguousarray(ov[:, take])
 
-    def _run_device(self, enc, n_lines: int, om, ov, trace=None):
+    def _run_device(self, enc, n_lines: int, om, ov, trace=NO_TRACE):
         """Fan every block out asynchronously — one fused program per
         device — and only then start the blocking reads, so device work
         overlaps (wall-clock ≈ slowest block, not the sum). Blocks whose

@@ -62,6 +62,7 @@ from log_parser_tpu.patterns.bank import (
     PatternBank,
 )
 from log_parser_tpu.runtime.engine import AnalysisEngine
+from log_parser_tpu.utils.trace import NO_TRACE
 
 
 def _ring_halo(x: jax.Array, h: int, d: int) -> jax.Array:
@@ -366,7 +367,7 @@ class ShardedEngine(AnalysisEngine):
         # row padding must be divisible by the mesh size for shard_map
         return max(8, self.mesh.devices.size)
 
-    def _run_device(self, enc, n_lines: int, om, ov, trace=None):
+    def _run_device(self, enc, n_lines: int, om, ov, trace=NO_TRACE):
         B = enc.u8.shape[0]
         C = self.bank.n_columns
         if om is None:  # the SPMD program's in_specs always take overrides

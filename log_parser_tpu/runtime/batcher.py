@@ -81,7 +81,7 @@ from log_parser_tpu.runtime.linecache import (
     line_key,
     records_from_bits,
 )
-from log_parser_tpu.utils.trace import PhaseTrace
+from log_parser_tpu.utils.trace import PhaseTrace, annotation
 
 if TYPE_CHECKING:  # import cycle: engine imports nothing from here at boot
     from log_parser_tpu.models.analysis import AnalysisResult
@@ -331,6 +331,7 @@ class MicroBatcher:
                     self.flush_deadline += 1
                 else:
                     self.flush_wait += 1
+            cpu_started = time.thread_time()
             try:
                 self._flush(items, reason)
             except BaseException:  # pragma: no cover - must never kill the loop
@@ -340,6 +341,10 @@ class MicroBatcher:
                     "micro-batcher flush failed after demux; "
                     "requests were already resolved"
                 )
+            self.engine.obs.note_request_cpu(
+                time.thread_time() - cpu_started, self.engine.obs_tenant,
+                "batched",
+            )
 
     # --------------------------------------------------------------- flush
 
@@ -362,13 +367,17 @@ class MicroBatcher:
                 item.trace.request_id, "enqueue", wait_s,
                 attrs={"flush": flush_id, "reason": reason},
             )
+        # the flush's device stages, attributed to every member request
+        # as the shared device phase is
+        stages = PhaseTrace()
         t0 = time.perf_counter()
         self._active_flush = flush_id
         try:
             # chaos at the flush boundary: batcher_slow delays the whole
             # batch; batcher_raise fails it into per-request fallback
             faults.fire("batcher")
-            resolved = self._resolve_records(items)
+            with annotation("engine.device"):
+                resolved = self._resolve_records(items, stages)
         except Exception as exc:
             # pre-device failure (injected batcher fault, stacking bug):
             # every request takes the per-request fallback decision
@@ -376,8 +385,10 @@ class MicroBatcher:
         finally:
             self._active_flush = None
         dt = time.perf_counter() - t0
+        flush_stages = stages.stage_dict()
         for item in items:
             item.trace.add("device", dt)
+            item.trace.add_stages(flush_stages)
         demux_t0 = time.perf_counter()
         demux_errs = 0
         # demux in enqueue order: the frequency evolution equals a serial
@@ -459,7 +470,8 @@ class MicroBatcher:
 
     # ----------------------------------------------------------- bisection
 
-    def _resolve_records(self, items: list[_Pending], depth: int = 0):
+    def _resolve_records(self, items: list[_Pending], stages: PhaseTrace,
+                         depth: int = 0):
         """Per-item outcomes for one flush: device records on success, or
         the exception each row is charged with. On a device-classified
         fault the batch splits in half and each half retries as its own
@@ -470,14 +482,16 @@ class MicroBatcher:
         from log_parser_tpu.runtime.engine import is_device_error
 
         if depth == 0 and self.engine.line_cache is not None:
-            resolved = self._cached_batch(items, self.engine.line_cache)
+            resolved = self._cached_batch(
+                items, self.engine.line_cache, stages
+            )
             if resolved is not None:
                 return resolved
             # residual device step failed: retry the WHOLE flush on the
             # uncached vmapped path below, so bisection, per-row poison
             # isolation, and quarantine striking behave exactly cache-off
         try:
-            return self._device_batch(items)
+            return self._device_batch(items, stages)
         except Exception as exc:
             if len(items) == 1:
                 if depth > 0:
@@ -501,10 +515,10 @@ class MicroBatcher:
                 self.bisects += 1
             mid = len(items) // 2
             return self._resolve_records(
-                items[:mid], depth + 1
-            ) + self._resolve_records(items[mid:], depth + 1)
+                items[:mid], stages, depth + 1
+            ) + self._resolve_records(items[mid:], stages, depth + 1)
 
-    def _cached_batch(self, items: list[_Pending], cache):
+    def _cached_batch(self, items: list[_Pending], cache, stages: PhaseTrace):
         """Resolve one flush through the line cache: per-item lookups,
         ONE compacted residual cube dispatch for the unique misses across
         the WHOLE flush (the cross-request half of the dedup), host-side
@@ -606,7 +620,7 @@ class MicroBatcher:
                 for r in contributed:
                     faults.fire("quarantine", key=items[r].data.logs or "")  # conlint: contained-by-caller (watchdog.run)
                 faults.fire("device")  # conlint: contained-by-caller (watchdog.run)
-                return engine._run_cube(res_u8, res_len, u)
+                return engine._run_cube(res_u8, res_len, u, trace=stages)
 
             t0 = time.perf_counter()
             try:
@@ -652,9 +666,10 @@ class MicroBatcher:
         engine._k_hint = max(r.n_matches for r in out)
         return out
 
-    def _device_batch(self, items: list[_Pending]):
+    def _device_batch(self, items: list[_Pending], stages: PhaseTrace):
         """Stack the bucket into one padded [R, B, T] batch, run the
-        vmapped program through the watchdog, return per-item records."""
+        vmapped program through the watchdog, return per-item records.
+        ``stages`` (a PhaseTrace) times the device stages."""
         engine = self.engine
         B = items[0].corpus.encoded.u8.shape[0]
         T = max(i.corpus.encoded.u8.shape[1] for i in items)
@@ -687,7 +702,8 @@ class MicroBatcher:
                 faults.fire("quarantine", key=item.data.logs or "")  # conlint: contained-by-caller (watchdog.run)
             faults.fire("device")  # conlint: contained-by-caller (watchdog.run)
             return self.program.run(
-                lines, lens, nlin, om, ov, k_hint=engine._k_hint
+                lines, lens, nlin, om, ov, k_hint=engine._k_hint,
+                trace=stages,
             )
 
         t0 = time.perf_counter()

@@ -74,7 +74,7 @@ from log_parser_tpu.runtime.quarantine import (
     QuarantineTable,
     fingerprint as quarantine_fingerprint,
 )
-from log_parser_tpu.utils.trace import PhaseTrace
+from log_parser_tpu.utils.trace import NO_TRACE, PhaseTrace
 
 # Substrings identifying plain RuntimeErrors raised by the device layer
 # *before* jit execution starts (jax raises these from xla_bridge /
@@ -494,11 +494,6 @@ class AnalysisEngine:
         self.fallback_count = 0
         # Pallas union-DFA kernel tier accounting (GET /trace/last)
         self.kernel_stats = KernelTierStats()
-        # XLA cost-analysis cache for device-utilization accounting:
-        # (rows, width) -> {"flops","bytes"} | None, filled by a
-        # background lowering so the serving path never stalls on it
-        self._cost_cache: dict[tuple, dict | None] = {}
-        self._cost_lock = threading.Lock()
         # ... and how many were ROUTED there deliberately by admission
         # pressure (serve/admission.py ladder rung 2) — a separate counter,
         # because pressure routing is policy, not failure
@@ -886,9 +881,8 @@ class AnalysisEngine:
         """Kernel-tier + device-utilization accounting for one device
         dispatch: did the union groups ride the Pallas kernel for this
         cube batch size, and what did the dispatch cost (padded rows,
-        dummy-slot waste, transition-plane bytes, cost-analysis FLOPs) —
-        folded into the per-tenant ``logparser_device_*`` families so
-        roofline math is a scrape, not a bench run. Returns the dispatch
+        dummy-slot waste, transition-plane bytes) — folded into the
+        per-tenant ``logparser_device_*`` families. Returns the dispatch
         attributes the span store records (``dispatch`` span vocabulary,
         obs/spans.py), or None pre-boot."""
         m = self._matchers
@@ -935,83 +929,36 @@ class AnalysisEngine:
                 attrs["planeBytes"] = geometry["planeBytes"]
             if geometry.get("vmemPerStep") is not None:
                 attrs["vmemPerStep"] = geometry["vmemPerStep"]
-        cost = self._dispatch_cost(batch_rows, width) if width else None
-        flops = hbm = None
-        if cost:
-            flops = cost.get("flops")
-            hbm = cost.get("bytes")
-            if flops:
-                attrs["flops"] = flops
-            if hbm:
-                attrs["hbmBytes"] = hbm
         self.obs.note_dispatch(
             self.obs_tenant, tier, padded_rows=padded_rows,
-            dummy_rows=dummy_rows, waste=waste, flops=flops, hbm_bytes=hbm,
+            dummy_rows=dummy_rows, waste=waste,
         )
         return attrs
 
-    def _dispatch_cost(self, rows: int, width: int) -> dict | None:
-        """``jax.jit(...).lower().cost_analysis()`` FLOPs/bytes for the
-        cube step at one (rows, width) shape — computed ONCE per shape
-        on a background thread (lowering costs hundreds of ms; the
-        serving path must never pay it), then folded into every later
-        dispatch of that shape. None while pending or when the backend
-        exposes no cost model."""
-        key = (int(rows), int(width))
-        with self._cost_lock:
-            if key in self._cost_cache:
-                return self._cost_cache[key]
-            self._cost_cache[key] = None  # pending marker
-
-        def _lower():
-            cost = None
-            try:
-                import jax.numpy as jnp
-
-                lines = jnp.zeros(key, dtype=jnp.uint8)
-                lens = jnp.zeros((key[0],), dtype=jnp.int32)
-                n = jnp.asarray(key[0], dtype=jnp.int32)
-                ca = self.fused._jit_cube_plain.lower(
-                    lines, lens, n
-                ).cost_analysis()
-                if isinstance(ca, (list, tuple)):
-                    ca = ca[0] if ca else {}
-                cost = {
-                    "flops": float(ca.get("flops", 0.0) or 0.0),
-                    "bytes": float(ca.get("bytes accessed", 0.0) or 0.0),
-                }
-            except Exception:
-                cost = None
-            with self._cost_lock:
-                self._cost_cache[key] = cost
-
-        threading.Thread(
-            target=_lower, name="dispatch-cost", daemon=True
-        ).start()
-        return None
-
-    def _run_device(self, enc, n_lines: int, om, ov, trace=None):
+    def _run_device(self, enc, n_lines: int, om, ov, trace=NO_TRACE):
         out = self.fused.run(
-            enc.u8, enc.lengths, n_lines, om, ov, k_hint=self._k_hint
+            enc.u8, enc.lengths, n_lines, om, ov, k_hint=self._k_hint,
+            trace=trace,
         )
         attrs = self._note_kernel_dispatch(
             enc.u8.shape[0], width=enc.u8.shape[1], n_rows=n_lines
         )
-        if trace is not None and attrs:
+        if trace is not NO_TRACE and attrs:
             trace.span_attrs.update(attrs)
         return out
 
     def _run_cube(self, lines_u8, lengths, n_rows: int,
-                  trace=None) -> np.ndarray:
+                  trace=NO_TRACE) -> np.ndarray:
         """Cube-only device program for the line-cache residual batch:
         pre-override match bits for ``n_rows`` independent lines (no
         extraction — that replays on the host from cached + fresh rows
-        together, runtime/linecache.py)."""
-        out = self.fused.cube_rows(lines_u8, lengths, n_rows)
+        together, runtime/linecache.py). ``trace`` (a PhaseTrace) times
+        the device stages and carries the dispatch span attributes."""
+        out = self.fused.cube_rows(lines_u8, lengths, n_rows, trace=trace)
         attrs = self._note_kernel_dispatch(
             lines_u8.shape[0], width=lines_u8.shape[1], n_rows=n_rows
         )
-        if trace is not None and attrs:
+        if trace is not NO_TRACE and attrs:
             attrs = {**attrs, "residual": True}
             trace.span_attrs.update(attrs)
         return out
@@ -1695,9 +1642,10 @@ class AnalysisEngine:
         # an entry and takes the formula path, not the null early-return
         freq_base = np.zeros(max(1, self.bank.n_freq_slots), dtype=np.float64)
         freq_exists = np.zeros(max(1, self.bank.n_freq_slots), dtype=bool)
-        for slot, pid in enumerate(self.bank.freq_ids):
-            freq_base[slot] = self.frequency.get_windowed_count(pid)
-            freq_exists[slot] = self.frequency.has_entry(pid)
+        with trace.stage("engine.frequency"):
+            for slot, pid in enumerate(self.bank.freq_ids):
+                freq_base[slot] = self.frequency.get_windowed_count(pid)
+                freq_exists[slot] = self.frequency.has_entry(pid)
 
         with trace.phase("finalize"):
             faults.fire("finalize")  # conlint: contained-by-caller (serve handler / batcher bisection)
@@ -1712,11 +1660,12 @@ class AnalysisEngine:
         # slots are skipped wholesale: record_pattern_matches(pid, 0)
         # early-returns without creating an entry, so on hit-heavy traffic
         # (few matched patterns per batch) this touches matched slots only
-        sbc = np.asarray(fin.slot_batch_counts[: self.bank.n_freq_slots])
-        for slot in np.flatnonzero(sbc).tolist():
-            self.frequency.record_pattern_matches(
-                self.bank.freq_ids[slot], int(sbc[slot])
-            )
+        with trace.stage("engine.frequency"):
+            sbc = np.asarray(fin.slot_batch_counts[: self.bank.n_freq_slots])
+            for slot in np.flatnonzero(sbc).tolist():
+                self.frequency.record_pattern_matches(
+                    self.bank.freq_ids[slot], int(sbc[slot])
+                )
 
         # records are already in discovery order (line-major, then pattern)
         with trace.phase("assemble"):

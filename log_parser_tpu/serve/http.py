@@ -49,6 +49,7 @@ from log_parser_tpu.obs import SPANS
 from log_parser_tpu.obs.profiler import ProfilerBusy, ProfilerUnavailable
 from log_parser_tpu.runtime import faults, pressure
 from log_parser_tpu.utils import xlacache
+from log_parser_tpu.utils.trace import PhaseTrace, annotation
 from log_parser_tpu.runtime.engine import AnalysisEngine
 from log_parser_tpu.runtime.quarantine import QuarantineRejected
 from log_parser_tpu.runtime.tenancy import (
@@ -1099,8 +1100,12 @@ class _Handler(BaseHTTPRequestHandler):
         if rid is None:
             rid = obs.new_request_id()
         started = pclock.mono()
+        cpu_started = time.thread_time()
         tenant = "default"
         route = "device"
+        # the transport's own stages; observed here once the response is
+        # written, since the engine's note_served runs before the encode
+        stages = PhaseTrace()
 
         def reply(status, body, *, detail=None, headers=None):
             hdrs = dict(headers) if headers else {}
@@ -1114,7 +1119,12 @@ class _Handler(BaseHTTPRequestHandler):
                 request_id=rid,
                 detail=detail,
             )
-            return self._send_json(status, body, headers=hdrs)
+            with stages.stage("transport.write"):
+                self._send_json(status, body, headers=hdrs)
+            obs.note_stages(stages.stage_dict(), tenant)
+            obs.note_request_cpu(
+                time.thread_time() - cpu_started, tenant, route
+            )
 
         try:
             faults.fire("http")
@@ -1125,12 +1135,22 @@ class _Handler(BaseHTTPRequestHandler):
             )
         try:
             length = int(self.headers.get("Content-Length", 0))
-            body = self.rfile.read(length) if length else b""
-            payload = json.loads(body) if body else None
+            with stages.stage("transport.read"):
+                body = self.rfile.read(length) if length else b""
+            with stages.stage("transport.decode"):
+                payload = json.loads(body) if body else None
         except (ValueError, json.JSONDecodeError):
             return reply(400, _INVALID, detail="invalid body")
 
-        data = PodFailureData.from_dict(payload) if isinstance(payload, dict) else None
+        with stages.stage("transport.decode"):
+            data = (
+                PodFailureData.from_dict(payload)
+                if isinstance(payload, dict) else None
+            )
+            n_lines = (
+                data.logs.count("\n") + 1
+                if data is not None and data.logs else 0
+            )
         # Parse.java:45-49 — null data or null pod is a 400
         if data is None or data.pod is None:
             return reply(400, _INVALID, detail="invalid body")
@@ -1153,16 +1173,18 @@ class _Handler(BaseHTTPRequestHandler):
         tenant = ctx.tenant_id
         engine = ctx.engine
         batcher = getattr(engine, "batcher", None)
-        n_lines = (data.logs.count("\n") + 1) if data.logs else 0
         arrival = pclock.mono()
         try:
-            route = self.server.admission.acquire(
-                deadline_ms,
-                batchable=batcher is not None,
-                tenant=ctx.quota,
-                lines=n_lines,
-            )
+            with annotation("transport.admission"):
+                route = self.server.admission.acquire(
+                    deadline_ms,
+                    batchable=batcher is not None,
+                    tenant=ctx.quota,
+                    lines=n_lines,
+                )
         except AdmissionRejected as exc:
+            admission_s = pclock.mono() - arrival
+            stages.add_stage("transport.admission", admission_s)
             # shed (429) or draining (503): tell the client when it is
             # worth coming back. A futile shed (413 `tenant burst` — the
             # request exceeds the bucket's whole capacity) carries NO
@@ -1170,7 +1192,7 @@ class _Handler(BaseHTTPRequestHandler):
             # the staged admission child attaches when reply()'s
             # note_request commits this shed request's trace
             obs.spans.annotate(
-                rid, "admission", pclock.mono() - arrival,
+                rid, "admission", admission_s,
                 attrs={"verdict": exc.reason, "tenant": tenant},
             )
             route = "admission"
@@ -1184,8 +1206,10 @@ class _Handler(BaseHTTPRequestHandler):
                     else None
                 ),
             )
+        admission_s = pclock.mono() - arrival
+        stages.add_stage("transport.admission", admission_s)
         obs.spans.annotate(
-            rid, "admission", pclock.mono() - arrival,
+            rid, "admission", admission_s,
             attrs={"verdict": route, "tenant": tenant},
         )
         try:
@@ -1251,9 +1275,11 @@ class _Handler(BaseHTTPRequestHandler):
         # pressure.stamp marks the envelope ``durability: degraded``
         # while the disk ladder is hard — its absence is a promise that
         # this response's frequency updates ride an fsync'd journal
-        reply(200, json.dumps(
-            pressure.stamp(result.to_dict(drop_none=True))
-        ).encode())
+        with stages.stage("transport.encode"):
+            answer = json.dumps(
+                pressure.stamp(result.to_dict(drop_none=True))
+            ).encode()
+        reply(200, answer)
 
 
 def make_server(

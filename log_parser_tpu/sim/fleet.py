@@ -139,15 +139,6 @@ def _data(blob: str) -> PodFailureData:
     return PodFailureData(pod={"metadata": {"name": "sim"}}, logs=blob)
 
 
-def _quiet(eng):
-    """Disable the background dispatch-cost lowering thread on *eng*.
-    It only enriches obs span attrs, spawns real (non-virtual) work, and
-    an interpreter exiting mid-lowering aborts inside XLA — three reasons
-    the simulator wants none of it."""
-    eng._dispatch_cost = lambda rows, width: None
-    return eng
-
-
 # One fully-compiled template engine per (fixed) library, shared across
 # every fleet/run in the process via the ``_install_library`` transplant
 # seam the fleet router's shared-pack path uses. Without it each of the
@@ -159,7 +150,7 @@ _TEMPLATES: dict[str, object] = {}
 def _share_compiled(eng, key: str, sets_factory):
     tmpl = _TEMPLATES.get(key)
     if tmpl is None:
-        tmpl = _quiet(AnalysisEngine(sets_factory(), ScoringConfig()))
+        tmpl = AnalysisEngine(sets_factory(), ScoringConfig())
         for blob in TRAFFIC:  # trace every shape the corpus dispatches
             tmpl.analyze(_data(blob))
         _TEMPLATES[key] = tmpl
@@ -192,7 +183,6 @@ class SimNode:
         state = self.state_dir
 
         def setup(eng, tid):
-            _quiet(eng)
             _share_compiled(
                 eng, tid,
                 lambda: load_pattern_directory(
@@ -202,9 +192,7 @@ class SimNode:
             eng.attach_journal(os.path.join(state, "wal", tid), wall=clk)
 
         default_engine = _share_compiled(
-            _quiet(AnalysisEngine(
-                [_base_pattern_set()], ScoringConfig(), clock=clk
-            )),
+            AnalysisEngine([_base_pattern_set()], ScoringConfig(), clock=clk),
             "__base__", lambda: [_base_pattern_set()],
         )
         self.registry = TenantRegistry(
@@ -393,12 +381,12 @@ class SimFleet:
         eng = self.controls.get(tenant)
         if eng is None:
             eng = _share_compiled(
-                _quiet(AnalysisEngine(
+                AnalysisEngine(
                     load_pattern_directory(
                         os.path.join(self.tenant_root, tenant)
                     ),
                     ScoringConfig(), clock=self.wall_clock,
-                )),
+                ),
                 tenant,
                 lambda: load_pattern_directory(
                     os.path.join(self.tenant_root, tenant)
