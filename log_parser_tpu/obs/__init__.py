@@ -224,6 +224,9 @@ class Obs:
         self.device_waste = reg.gauge(
             "logparser_device_dummy_waste_ratio", ("tenant",)
         )
+        self.extract_hit_coords = reg.counter(
+            "logparser_extract_hit_coords_total", ("tenant",)
+        )
         reg.register_collector("slo", self.slo.samples)
         reg.register_collector("spans", self._span_samples)
         reg.register_collector("native", _native_samples)
@@ -373,6 +376,11 @@ class Obs:
             self.device_dummy_rows.inc(dummy_rows, tenant=tenant)
         if waste is not None:
             self.device_waste.set(waste, tenant=tenant)
+
+    def note_extract_hits(self, coords: int, tenant: str) -> None:
+        """One line-cache extract: the ``(line, col)`` hit coordinates it
+        carried. Over lines × columns, the hit density its cost follows."""
+        self.extract_hit_coords.inc(coords, tenant=tenant)
 
     def note_dropped(self, transport: str) -> None:
         """A computed response the transport could not write back —

@@ -105,6 +105,9 @@ METRICS = {
         "counter", "KeyInterner 64-bit probe hits (blake2b skipped)."),
     "logparser_interner_inserts_total": (
         "counter", "KeyInterner first-touch inserts (blake2b paid)."),
+    "logparser_extract_hit_coords_total": (
+        "counter", "(line, column) match-bit coordinates the line-cache "
+        "extract carried, by tenant."),
     # ------------------------------------------------------ batcher
     "logparser_batch_queue_depth": (
         "gauge", "Requests parked in micro-batcher queues."),

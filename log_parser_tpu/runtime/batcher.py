@@ -79,7 +79,9 @@ from log_parser_tpu.runtime import faults
 from log_parser_tpu.runtime.linecache import (
     dedup_slots,
     line_key,
-    records_from_bits,
+    records_from_hits,
+    request_hits,
+    slot_hits,
 )
 from log_parser_tpu.utils.trace import PhaseTrace, annotation
 
@@ -647,22 +649,15 @@ class MicroBatcher:
                 [keys[miss_slots[j]] for j in keep], fresh[keep]
             )
 
-        bits_u = np.zeros((U, cache.n_columns), dtype=bool)
-        hit_slots = [s for s in range(U) if packed[s] is not None]
-        if hit_slots:
-            bits_u[hit_slots] = cache.unpack([packed[s] for s in hit_slots])
-        if fresh is not None:
-            bits_u[miss_slots] = fresh
+        hits = slot_hits(cache, packed, miss_slots, fresh)
         out = []
         for r, item in enumerate(items):
             n = item.corpus.n_lines
-            if n:
-                bits = bits_u[per_item[r]]  # fan unique rows back out
-            else:
-                bits = np.zeros((0, cache.n_columns), dtype=bool)
-            if item.om is not None:
-                bits = np.where(item.om[:n], item.ov[:n], bits)
-            out.append(records_from_bits(bits, n, engine.bank, engine.tables))
+            line, col = request_hits(hits, per_item[r], n, item.om, item.ov)
+            out.append(
+                records_from_hits(line, col, n, engine.bank, engine.tables)
+            )
+            engine.obs.note_extract_hits(line.size, engine.obs_tenant)
         engine._k_hint = max(r.n_matches for r in out)
         return out
 

@@ -60,7 +60,9 @@ from log_parser_tpu.runtime.linecache import (
     LineCache,
     dedup_slots,
     line_key,
-    records_from_bits,
+    records_from_hits,
+    request_hits,
+    slot_hits,
 )
 from log_parser_tpu.ops.match import DfaBank, MatcherBanks
 from log_parser_tpu.patterns.bank import PatternBank
@@ -1500,15 +1502,20 @@ class AnalysisEngine:
         self, data, start, trace, corpus, om, ov, cache: LineCache
     ) -> "_Prepared":
         """The routing-tier prepare path: per-line cache lookup, one
-        compacted residual cube dispatch for the unique misses, host-side
-        override splice + record extraction. A request whose lines are
+        compacted residual cube dispatch for the unique misses, then a
+        sparse host replay of the extraction. A request whose lines are
         ALL cache hits never reaches the device step at all — it cannot
         trip the watchdog, cannot strike quarantine, and costs no device
-        dispatch. Parity with :meth:`_prepare` is exact: the cache holds
-        PRE-override bit rows (width-independent — zero padding is
-        automaton-neutral and ``needs_host`` lines are never populated),
-        the request's override cube is re-applied here, and
-        ``records_from_bits`` mirrors the device extraction bit-for-bit."""
+        dispatch. The replay reads each unique line's set columns from its
+        packed cache row or readback row, fans them out to the request's
+        lines as ``(line, col)`` coordinates, splices the override cube in
+        and builds the records from those coordinates
+        (``linecache.records_from_hits``): no ``[n, C]`` matrix, and a
+        cost that follows the hits. Parity with :meth:`_prepare` is exact:
+        the cache holds PRE-override bit rows (width-independent — zero
+        padding is automaton-neutral and ``needs_host`` lines are never
+        populated), the request's override cube is re-applied here, and
+        the replay mirrors the device extraction bit-for-bit."""
         enc = corpus.encoded
         n = corpus.n_lines
         with trace.phase("cache"):
@@ -1594,25 +1601,16 @@ class AnalysisEngine:
             )
 
         with trace.phase("extract"):
-            if n:
-                bits_u = np.zeros((U, cache.n_columns), dtype=bool)
-                hit_slots = [s for s in range(U) if packed[s] is not None]
-                if hit_slots:
-                    bits_u[hit_slots] = cache.unpack(
-                        [packed[s] for s in hit_slots]
-                    )
-                if fresh is not None:
-                    bits_u[miss_slots] = fresh
-                bits = bits_u[line_slot]  # fan unique rows back out
-            else:
-                bits = np.zeros((0, cache.n_columns), dtype=bool)
-            if om is not None:
-                # the per-request override splice: host-only columns,
-                # needs_host lines, and OPEN-breaker patterns — applied on
-                # the host over cached and fresh rows alike, which is what
-                # makes a breaker trip an exact per-pattern invalidation
-                bits = np.where(om[:n], ov[:n], bits)
-            recs = records_from_bits(bits, n, self.bank, self.tables)
+            # the override splice (host-only columns, needs_host lines,
+            # OPEN-breaker patterns) lands on cached and fresh rows alike,
+            # which is what makes a breaker trip an exact per-pattern
+            # invalidation
+            line, col = request_hits(
+                slot_hits(cache, packed, miss_slots, fresh),
+                line_slot, n, om, ov,
+            )
+            recs = records_from_hits(line, col, n, self.bank, self.tables)
+            self.obs.note_extract_hits(line.size, self.obs_tenant)
         self._k_hint = recs.n_matches
         with trace.phase("verify"):
             recs = self._verify_approx(corpus, recs)

@@ -26,10 +26,17 @@ every cached entry is invalidated the instant the breaker opens, without
 dropping the other patterns' bits.
 
 Cross-line factors (proximity distances, sequence chains, context
-windows) are NOT per-line — they are recomputed per request from the
-assembled bit matrix by :func:`records_from_bits`, a numpy mirror of the
-device extraction (same discovery order, same integer semantics), so
-cached requests produce bit-identical ``MatchRecords`` and the
+windows) are NOT per-line — they are recomputed per request by
+:func:`records_from_hits`, a sparse numpy replay of the device
+extraction (same discovery order, same integer semantics). The bits
+never form a dense per-line matrix: each unique line's set columns are
+read from its packed cache row or its readback row
+(:func:`slot_hits`), fanned out to the request's lines as sorted
+``(line, col)`` coordinates with the override splice applied
+(:func:`request_hits`), and the distances, sequence chains and
+windows are ``searchsorted`` queries over each column's hit lines at
+the record lines only. So the host's cost follows the request's hits,
+cached requests produce bit-identical ``MatchRecords``, and the
 frequency-coupled factors replay on the host under ``state_lock``
 exactly as before.
 
@@ -51,6 +58,7 @@ from __future__ import annotations
 import hashlib
 import threading
 from collections import OrderedDict, deque
+from typing import NamedTuple
 
 import numpy as np
 
@@ -494,6 +502,19 @@ class LineCache:
             count=self.n_columns,
         ).astype(bool)
 
+    def row_hits(self, packed: list[bytes]) -> tuple[np.ndarray, np.ndarray]:
+        """``(row, col)`` of the set bits of packed rows, sorted by row
+        then column — the extract path's view: only the nonzero bytes
+        are unpacked, never the whole rows."""
+        if not packed:
+            z = np.zeros(0, dtype=np.int64)
+            return z, z
+        buf = np.frombuffer(b"".join(packed), dtype=np.uint8)
+        flat = _nonzero_bytes(buf)
+        k, bit = np.nonzero(np.unpackbits(buf[flat][:, None], axis=1))
+        row, byte = np.divmod(flat[k], self._row_bytes)
+        return row, byte * 8 + bit
+
     def lookup(self, keys: list[bytes]) -> list[np.ndarray | None]:
         """Per-key bit rows (bool [n_columns]) or None for misses —
         convenience wrapper over :meth:`lookup_packed` for tests and
@@ -700,87 +721,192 @@ class MissTap:
 
 
 # --------------------------------------------------------- host extraction
+#
+# The match bits travel through extraction as sparse (line, column) hit
+# coordinates: a request's cost follows its hits, not lines × columns.
 
 
-def _host_prev_next_dist(hits: np.ndarray) -> np.ndarray:
-    """numpy mirror of ops/fused.py ``_prev_next_dist``: [B, S] bool hit
-    columns -> [B, S] int32 distance to the nearest hit on either side,
-    own row excluded, NO_HIT where none."""
-    B, S = hits.shape
-    col = np.arange(B, dtype=np.int64)[:, None]
-    prev_incl = np.maximum.accumulate(np.where(hits, col, -1), axis=0)
-    prev = np.concatenate(
-        [np.full((1, S), -1, dtype=np.int64), prev_incl[:-1]], axis=0
+def _nonzero_bytes(buf: np.ndarray) -> np.ndarray:
+    """Flat indices, ascending, of the nonzero bytes of a contiguous
+    uint8 buffer: one pass reads it as uint64 words, and only the nonzero
+    words are resolved to their bytes."""
+    nw = buf.size // 8
+    words = np.flatnonzero(buf[: nw * 8].view(np.uint64))
+    idx = (words[:, None] * 8 + np.arange(8)).ravel()
+    idx = idx[buf[idx] != 0]
+    tail = np.flatnonzero(buf[nw * 8 :])
+    return np.concatenate([idx, tail + nw * 8]) if tail.size else idx
+
+
+def bool_hits(rows: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    """``(row, col)`` of the set entries of a bool ``[r, C]`` matrix,
+    sorted by row then column."""
+    if rows.strides[0] < rows.strides[1]:
+        # column-major memory (the v5e's cube readback comes so): scan the
+        # transpose in its own order and sort the few hits, since a
+        # transposing copy of the whole matrix costs far more than the scan
+        col, row = bool_hits(rows.T)
+        order = np.lexsort((col, row))
+        return row[order], col[order]
+    r, c = rows.shape
+    if r == 0 or c == 0:
+        z = np.zeros(0, dtype=np.int64)
+        return z, z
+    if rows.strides[1] != 1 or rows.strides[0] < c:
+        rows = np.ascontiguousarray(rows)
+    # the rows are contiguous but may sit apart (rows sliced off a
+    # column-major matrix, transposed): scan the one span that holds
+    # them, gaps included, and drop the gaps' hits
+    s = rows.strides[0]
+    span = np.lib.stride_tricks.as_strided(
+        rows.view(np.uint8), shape=((r - 1) * s + c,), strides=(1,)
     )
-    nxt_incl = np.flip(
-        np.minimum.accumulate(
-            np.flip(np.where(hits, col, int(NO_HIT)), axis=0), axis=0
-        ),
-        axis=0,
+    row, col = np.divmod(_nonzero_bytes(span), s)
+    keep = col < c
+    return row[keep], col[keep]
+
+
+class SlotHits(NamedTuple):
+    """The hit columns of a request's (or flush's) unique lines, by slot:
+    slot ``s`` holds ``cols[start[s] : start[s] + count[s]]``, ascending."""
+
+    start: np.ndarray  # int64 [U]
+    count: np.ndarray  # int64 [U]
+    cols: np.ndarray  # int64 [H]
+
+
+def slot_hits(
+    cache: LineCache,
+    packed: list[bytes | None],
+    miss_slots: list[int],
+    fresh: np.ndarray | None,
+) -> SlotHits:
+    """Hit columns per unique slot from the cached rows (``packed[s]``
+    where not None) and the readback rows (``fresh[j]`` for slot
+    ``miss_slots[j]``), never unpacked to a dense matrix."""
+    U = len(packed)
+    start = np.zeros(U, dtype=np.int64)
+    count = np.zeros(U, dtype=np.int64)
+    hit_slots = [s for s, p in enumerate(packed) if p is not None]
+    groups = []
+    if hit_slots:
+        groups.append(
+            (hit_slots, cache.row_hits([packed[s] for s in hit_slots]))
+        )
+    if fresh is not None and miss_slots:
+        groups.append((miss_slots, bool_hits(fresh)))
+    parts: list[np.ndarray] = []
+    base = 0
+    for slots, (row, col) in groups:
+        c = np.bincount(row, minlength=len(slots))
+        idx = np.asarray(slots, dtype=np.int64)
+        count[idx] = c
+        start[idx] = base + np.cumsum(c) - c
+        parts.append(col)
+        base += col.size
+    cols = np.concatenate(parts) if parts else np.zeros(0, dtype=np.int64)
+    return SlotHits(start, count, cols)
+
+
+def request_hits(
+    hits: SlotHits,
+    line_slot: np.ndarray,
+    n_lines: int,
+    om: np.ndarray | None = None,
+    ov: np.ndarray | None = None,
+) -> tuple[np.ndarray, np.ndarray]:
+    """One request's ``(line, col)`` hits: each line takes its slot's
+    columns, then the request's override cube (``om``/``ov``, host-only
+    columns, needs_host lines and OPEN breakers) replaces the masked
+    coordinates."""
+    cnt = hits.count[line_slot]
+    first = np.cumsum(cnt) - cnt
+    total = int(cnt.sum())
+    line = np.repeat(np.arange(n_lines, dtype=np.int64), cnt)
+    col = hits.cols[
+        np.repeat(hits.start[line_slot] - first, cnt)
+        + np.arange(total, dtype=np.int64)
+    ]
+    if om is None:
+        return line, col
+    keep = ~om[line, col]
+    o_line, o_col = bool_hits(ov[:n_lines])
+    on = om[o_line, o_col]
+    return (
+        np.concatenate([line[keep], o_line[on]]),
+        np.concatenate([col[keep], o_col[on]]),
     )
-    nxt = np.concatenate(
-        [nxt_incl[1:], np.full((1, S), int(NO_HIT), dtype=np.int64)], axis=0
-    )
-    d_prev = np.where(prev >= 0, col - prev, int(NO_HIT))
-    d_next = np.where(nxt < int(NO_HIT), nxt - col, int(NO_HIT))
-    return np.minimum(d_prev, d_next).astype(np.int32)
 
 
-def _host_sequence_flags(
-    sequences, t: FusedStaticTables, em: np.ndarray, idx: np.ndarray, n_lines: int
-) -> np.ndarray:
-    """numpy mirror of ops/fused.py ``sequence_flags_from_events`` at the
-    record rows ``idx`` only: last event within ±SEQUENCE_NEAR_WINDOW of
-    the primary via a prefix-count range-any, earlier events chained
-    strictly backwards via inclusive prefix-cummax of last-hit line."""
-    B = em.shape[0]
-    eidx = np.arange(B, dtype=np.int64)[:, None]
-    prev_incl = np.maximum.accumulate(np.where(em, eidx, -1), axis=0)
-    prefix = np.concatenate(
-        [np.zeros((1, em.shape[1]), dtype=np.int64), np.cumsum(em, axis=0)]
-    )
-    w = SEQUENCE_NEAR_WINDOW
-    outs = []
-    for seq in sequences:
-        if not seq.event_columns:
-            outs.append(np.zeros(idx.shape, dtype=bool))
-            continue
-        last_e = t.seq_col_pos[seq.event_columns[-1]]
-        lo = np.clip(idx - w, 0, B)
-        hi = np.clip(np.minimum(idx + w + 1, n_lines), 0, B)
-        ok = (prefix[hi, last_e] - prefix[lo, last_e]) > 0
-        cur = idx
-        for col in reversed(seq.event_columns[:-1]):
-            e = t.seq_col_pos[col]
-            g = np.where(cur >= 1, prev_incl[np.clip(cur - 1, 0, B - 1), e], -1)
-            ok = ok & (g >= 0)
-            cur = np.clip(g, 0, B - 1)
-        outs.append(ok)
-    return np.stack(outs, axis=1)
+class _ColumnLines:
+    """The sorted hit lines of a few columns, one segment per column,
+    kept as keys ``u * (n_lines + 1) + line`` in one sorted array so a
+    single ``searchsorted`` answers a query for any column ``u``."""
+
+    def __init__(self, line, col, cols, n_lines: int, n_columns: int):
+        lut = np.full(max(1, n_columns), -1, dtype=np.int64)
+        lut[np.asarray(cols, dtype=np.int64)] = np.arange(len(cols))
+        u = lut[col]
+        sel = u >= 0
+        self.stride = int(n_lines) + 1
+        # a sentinel past every segment keeps the array non-empty
+        self.keys = np.append(
+            np.sort(u[sel] * self.stride + line[sel]), len(cols) * self.stride
+        )
+        bounds = np.searchsorted(
+            self.keys, np.arange(len(cols) + 1) * self.stride
+        )
+        self.lo, self.hi = bounds[:-1], bounds[1:]
+
+    def before(self, u, at):
+        """Last hit line of column ``u`` strictly before ``at``, -1 if none."""
+        base = u * self.stride
+        p = np.searchsorted(self.keys, base + at, "left") - 1
+        return np.where(p >= self.lo[u], self.keys[np.maximum(p, 0)] - base, -1)
+
+    def after(self, u, at):
+        """First hit line of column ``u`` strictly after ``at``, -1 if none."""
+        base = u * self.stride
+        q = np.searchsorted(self.keys, base + at, "right")
+        return np.where(q < self.hi[u], self.keys[q] - base, -1)
+
+    def count(self, u, lo, hi):
+        """Hits of column ``u`` on lines ``[lo, hi)``."""
+        base = u * self.stride
+        return np.searchsorted(self.keys, base + hi) - np.searchsorted(
+            self.keys, base + lo
+        )
 
 
-def records_from_bits(
-    bits: np.ndarray,
+def records_from_hits(
+    line: np.ndarray,
+    col: np.ndarray,
     n_lines: int,
     bank: PatternBank,
     tables: FusedStaticTables,
 ) -> MatchRecords:
-    """The device extraction, replayed on the host from an assembled
-    post-override bit matrix ``bits`` [n_lines, n_columns] (cached rows +
-    residual rows + override splice). Mirrors ``FusedMatchScore._step``
-    downstream of the cube — same discovery order (line-major then
-    pattern: ``np.argwhere`` is row-major), same per-pattern slot layout
-    (``pat_sec``/``pat_seq``/``pat_ctx_shape``), same integer semantics —
-    so the returned records are bit-identical to what the device would
-    have produced for the full batch. Arrays are exact-size (K = M):
-    finalize_batch and _verify_approx slice ``[:n_matches]``, so no
-    padding rows are needed."""
+    """The device extraction, replayed on the host from a request's
+    post-override hits (``line``, ``col``, in any order).
+    Mirrors ``FusedMatchScore._extract`` — same discovery order (line
+    then pattern), same per-pattern slot layout (``pat_sec``/``pat_seq``/
+    ``pat_ctx_shape``), same integer semantics — so the records are
+    bit-identical to what the device would have produced for the full
+    batch. Arrays are exact-size (K = M): finalize_batch and
+    _verify_approx slice ``[:n_matches]``, so no padding rows are
+    needed."""
     B = int(n_lines)
-    P = bank.n_patterns
+    C = bank.n_columns
     s_w = max(1, tables.s_max)
     q_w = max(1, tables.q_max)
 
-    def _empty() -> MatchRecords:
+    # ---- primaries: columns are interned, so one column may be the
+    # primary of several patterns — expand each hit through a column →
+    # patterns CSR, then order by (line, pattern) --------------------------
+    pcols = bank.primary_columns.astype(np.int64)
+    per_col = np.bincount(pcols, minlength=C)
+    k = per_col[col]
+    m = int(k.sum())
+    if m == 0:
         return MatchRecords(
             n_matches=0,
             line=np.zeros(0, dtype=np.int32),
@@ -789,36 +915,52 @@ def records_from_bits(
             seq_ok=np.zeros((0, q_w), dtype=bool),
             ctx_counts=np.zeros((0, 5), dtype=np.int32),
         )
+    col_pats = np.argsort(pcols, kind="stable")
+    col_start = np.cumsum(per_col) - per_col
+    src = np.repeat(col_start[col] - (np.cumsum(k) - k), k) + np.arange(m)
+    rl = np.repeat(line, k)
+    rp = col_pats[src]
+    order = np.lexsort((rp, rl))
+    rl = rl[order]
+    rec_pat = rp[order].astype(np.int32)
+    rec_line = rl.astype(np.int32)
 
-    if P == 0 or B == 0:
-        return _empty()
-
-    pm = bits[:, bank.primary_columns]  # [B, P]
-    matched = np.argwhere(pm)  # row-major == discovery order
-    m = len(matched)
-    if m == 0:
-        return _empty()
-    rec_line = matched[:, 0].astype(np.int32)
-    rec_pat = matched[:, 1].astype(np.int32)
-
-    # ---- proximity distances (per-pattern secondary slots) ----------------
+    # ---- proximity distances: once per distinct secondary column, at the
+    # record lines only; strict prev/next hit (own row excluded) ----------
     rec_dist = np.full((m, s_w), NO_HIT, dtype=np.int32)
     if len(tables.sec_cols):
-        dist = _host_prev_next_dist(bits[:, tables.sec_cols])  # [B, S_entries]
+        ucols, inv = np.unique(tables.sec_cols, return_inverse=True)
+        sec = _ColumnLines(line, col, ucols, B, C)
         sec_idx = tables.pat_sec[rec_pat]  # [m, s_w]
-        rec_dist = np.where(
-            sec_idx >= 0,
-            dist[rec_line[:, None], np.maximum(sec_idx, 0)],
-            np.int32(NO_HIT),
-        ).astype(np.int32)
+        r, j = np.nonzero(sec_idx >= 0)
+        u = inv[sec_idx[r, j]]
+        at = rl[r]
+        prev = sec.before(u, at)
+        nxt = sec.after(u, at)
+        d_prev = np.where(prev >= 0, at - prev, int(NO_HIT))
+        d_next = np.where(nxt >= 0, nxt - at, int(NO_HIT))
+        rec_dist[r, j] = np.minimum(d_prev, d_next)
 
-    # ---- sequence flags (per-pattern sequence slots) ----------------------
+    # ---- sequence flags: last event within ±SEQUENCE_NEAR_WINDOW, earlier
+    # events chained strictly backwards from the primary line -------------
     rec_seq = np.zeros((m, q_w), dtype=bool)
     if bank.sequences:
-        em = bits[:, np.asarray(tables.seq_event_cols, dtype=np.int64)]
-        flags = _host_sequence_flags(
-            bank.sequences, tables, em, rec_line.astype(np.int64), B
-        )  # [m, n_sequences]
+        ev = _ColumnLines(line, col, tables.seq_event_cols, B, C)
+        w = SEQUENCE_NEAR_WINDOW
+        flags = np.zeros((m, len(bank.sequences)), dtype=bool)
+        for qi, seq in enumerate(bank.sequences):
+            if not seq.event_columns:
+                continue
+            last_e = tables.seq_col_pos[seq.event_columns[-1]]
+            lo = np.clip(rl - w, 0, B)
+            hi = np.clip(np.minimum(rl + w + 1, B), 0, B)
+            ok = ev.count(last_e, lo, hi) > 0
+            cur = rl
+            for c in reversed(seq.event_columns[:-1]):
+                g = ev.before(tables.seq_col_pos[c], cur)
+                ok &= g >= 0
+                cur = np.clip(g, 0, B - 1)
+            flags[:, qi] = ok
         q_idx = tables.pat_seq[rec_pat]  # [m, q_w]
         rec_seq = np.where(
             q_idx >= 0,
@@ -827,19 +969,17 @@ def records_from_bits(
         )
 
     # ---- context window counts -------------------------------------------
-    err = bits[:, CTX_ERROR]
-    warn = bits[:, CTX_WARN] & ~err
-    stack = bits[:, CTX_STACK]
-    exc = bits[:, CTX_EXCEPTION]
-    flags4 = np.stack([err, warn, stack, exc], axis=1).astype(np.int64)  # [B, 4]
+    flags4 = np.zeros((B, 4), dtype=np.int64)  # err, warn, stack, exc
+    for f, c in enumerate((CTX_ERROR, CTX_WARN, CTX_STACK, CTX_EXCEPTION)):
+        flags4[line[col == c], f] = 1
+    flags4[:, 1] &= 1 - flags4[:, 0]  # warn is shadowed by error
     ps = np.concatenate(
         [np.zeros((1, 4), dtype=np.int64), np.cumsum(flags4, axis=0)]
     )
     shape_ids = tables.pat_ctx_shape[rec_pat]  # [m]
     rec_ctx = np.zeros((m, 5), dtype=np.int32)
-    rl = rec_line.astype(np.int64)
-    for u, (has_rules, before, after) in enumerate(tables.ctx_shapes):
-        sel = shape_ids == u
+    for s, (has_rules, before, after) in enumerate(tables.ctx_shapes):
+        sel = shape_ids == s
         if not sel.any():
             continue
         li = rl[sel]
@@ -849,7 +989,7 @@ def records_from_bits(
             total = np.ones(len(li), dtype=np.int64)
         else:
             lo = np.clip(li - before, 0, B)
-            hi = np.clip(np.minimum(li + 1 + after, n_lines), 0, B)
+            hi = np.clip(np.minimum(li + 1 + after, B), 0, B)
             counts = ps[hi] - ps[lo]
             total = hi - lo
         rec_ctx[sel] = np.concatenate(
@@ -864,3 +1004,15 @@ def records_from_bits(
         seq_ok=rec_seq,
         ctx_counts=rec_ctx,
     )
+
+
+def records_from_bits(
+    bits: np.ndarray,
+    n_lines: int,
+    bank: PatternBank,
+    tables: FusedStaticTables,
+) -> MatchRecords:
+    """:func:`records_from_hits` over a dense post-override bit matrix
+    ``bits`` [n_lines, n_columns] (the follow-mode stream keeps one)."""
+    line, col = bool_hits(bits[:n_lines])
+    return records_from_hits(line, col, n_lines, bank, tables)

@@ -76,9 +76,11 @@ def main() -> None:
     from log_parser_tpu.runtime.finalize import finalize_batch
     from log_parser_tpu.runtime.linecache import (
         KeyInterner,
+        SlotHits,
         dedup_slots,
         line_key,
-        records_from_bits,
+        records_from_hits,
+        request_hits,
     )
 
     if args.repeat_ratio is not None:
@@ -177,10 +179,13 @@ def main() -> None:
     report["patterns"] = sum(len(s.patterns or []) for s in sets)
     n = corpus.n_lines
     U = len(keys)
-    # synthesize the post-cache unique bit matrix exactly as the cached
-    # path would hold it (content of the bits doesn't change the cost;
-    # use the real device-equivalent rows for honest record counts)
-    bits_u = np.zeros((U, engine.bank.n_columns), dtype=bool)
+    # the post-cache unique slots with no hits, as the cached path would
+    # hold them for an all-miss line set (the sparse extract's cost
+    # follows the hits; these times are its floor)
+    hits = SlotHits(
+        np.zeros(U, dtype=np.int64), np.zeros(U, dtype=np.int64),
+        np.zeros(0, dtype=np.int64),
+    )
     probe = engine.analyze(
         PodFailureData(pod={"metadata": {"name": "prof"}}, logs=logs)
     )
@@ -188,16 +193,15 @@ def main() -> None:
     fin_ref = engine.last_finalized
 
     def assemble():
-        bits = bits_u[line_slot]  # unique rows → per-line fan-out
-        return bits
+        return request_hits(hits, line_slot, n)  # slots → (line, col)
 
     t_min, _ = timeit(assemble, n=args.repeats)
     report["assemble_s"] = round(t_min, 4)
 
-    bits = bits_u[line_slot]
+    line, col = assemble()
 
     def extract():
-        return records_from_bits(bits, n, engine.bank, engine.tables)
+        return records_from_hits(line, col, n, engine.bank, engine.tables)
 
     t_min, _ = timeit(extract, n=args.repeats)
     report["extract_s"] = round(t_min, 4)
