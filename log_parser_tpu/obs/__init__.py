@@ -227,6 +227,18 @@ class Obs:
         self.extract_hit_coords = reg.counter(
             "logparser_extract_hit_coords_total", ("tenant",)
         )
+        self.shard_relaunches = reg.counter(
+            "logparser_shard_relaunches_total", ("tenant",)
+        )
+        self.shard_exchange_bytes = reg.counter(
+            "logparser_shard_exchange_bytes_total", ("tenant",)
+        )
+        self.shard_record_slots = reg.counter(
+            "logparser_shard_record_slots_total", ("tenant",)
+        )
+        self.shard_records = reg.counter(
+            "logparser_shard_records_total", ("tenant",)
+        )
         reg.register_collector("slo", self.slo.samples)
         reg.register_collector("spans", self._span_samples)
         reg.register_collector("native", _native_samples)
@@ -381,6 +393,18 @@ class Obs:
         """One line-cache extract: the ``(line, col)`` hit coordinates it
         carried. Over lines × columns, the hit density its cost follows."""
         self.extract_hit_coords.inc(coords, tenant=tenant)
+
+    def note_shard_step(self, tenant: str, relaunches: int,
+                        exchange_bytes: int, record_slots: int,
+                        records: int) -> None:
+        """One request through the line-sharded SPMD step
+        (parallel/sharded.py): its launches beyond the first (K-ladder
+        overflows), the bytes its launches' collectives delivered between
+        chips, and the record slots read back against the live records."""
+        self.shard_relaunches.inc(relaunches, tenant=tenant)
+        self.shard_exchange_bytes.inc(exchange_bytes, tenant=tenant)
+        self.shard_record_slots.inc(record_slots, tenant=tenant)
+        self.shard_records.inc(records, tenant=tenant)
 
     def note_dropped(self, transport: str) -> None:
         """A computed response the transport could not write back —

@@ -108,6 +108,19 @@ METRICS = {
     "logparser_extract_hit_coords_total": (
         "counter", "(line, column) match-bit coordinates the line-cache "
         "extract carried, by tenant."),
+    # ------------------------------------------------ sharded step
+    "logparser_shard_relaunches_total": (
+        "counter", "Line-sharded SPMD launches beyond a request's first "
+        "(a shard overflowed its record bucket), by tenant."),
+    "logparser_shard_exchange_bytes_total": (
+        "counter", "Bytes the line-sharded step's halo ppermutes and "
+        "all_gathers delivered between chips, by tenant."),
+    "logparser_shard_record_slots_total": (
+        "counter", "Per-shard record slots (shards x bucket) the "
+        "line-sharded step read back, by tenant."),
+    "logparser_shard_records_total": (
+        "counter", "Live match records the line-sharded step read back, "
+        "by tenant."),
     # ------------------------------------------------------ batcher
     "logparser_batch_queue_depth": (
         "gauge", "Requests parked in micro-batcher queues."),

@@ -322,12 +322,7 @@ class DistributedShardedEngine(ShardedEngine):
             step = self._local_step
             if step is None:
                 raise RuntimeError("degraded mode: no usable local devices")
-            B = enc.u8.shape[0]
-            C = self.bank.n_columns
-            if om is None:
-                om = np.zeros((B, C), dtype=bool)
-                ov = np.zeros((B, C), dtype=bool)
-            return step(enc.u8, enc.lengths, om, ov, n_lines, k_hint=self._k_hint)
+            return self._run_step(step, enc, n_lines, om, ov, trace)
         return super()._run_device(enc, n_lines, om, ov, trace=trace)
 
     def _analyze_degraded(self, data: PodFailureData):

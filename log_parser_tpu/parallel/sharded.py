@@ -36,6 +36,8 @@ padding contribute nothing.
 
 from __future__ import annotations
 
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -77,6 +79,16 @@ def _ring_halo(x: jax.Array, h: int, d: int) -> jax.Array:
         x[:h], DATA_AXIS, [(i + 1, i) for i in range(d - 1)]
     )
     return jnp.concatenate([from_left, x, from_right], axis=0)
+
+
+class ShardedRun(NamedTuple):
+    """One request's pass through :class:`ShardedFusedStep`: its records,
+    the SPMD launches the K ladder took (1 when the first rung held every
+    shard's matches) and the per-shard record capacity that held them."""
+
+    records: MatchRecords
+    launches: int
+    k_local: int
 
 
 class ShardedFusedStep:
@@ -144,6 +156,19 @@ class ShardedFusedStep:
 
         return np.asarray(multihost_utils.process_allgather(x, tiled=True))
 
+    def _read_back(self, outs, trace) -> list[np.ndarray]:
+        """Wait for ``outs`` (``device.wait``), then copy them to the host
+        (``device.readback``), as ``ops/fused._read_back`` does: each
+        copy is queued before the wait, so it starts when the program
+        ends. A process-spanning array is gathered after the wait."""
+        if not self.multiprocess:
+            for x in outs:
+                x.copy_to_host_async()
+        with trace.stage("device.wait"):
+            jax.block_until_ready(outs)
+        with trace.stage("device.readback"):
+            return [self._host(x) for x in outs]
+
     def _sharded(self, k_local: int):
         return jax.shard_map(
             lambda lines, lens, om, ov, n: self._step(k_local, lines, lens, om, ov, n),
@@ -176,41 +201,51 @@ class ShardedFusedStep:
         override_val: np.ndarray,
         n_lines: int,
         k_hint: int = 0,
-    ) -> MatchRecords:
+        trace=NO_TRACE,
+    ) -> ShardedRun:
         """Runs the SPMD step, growing per-shard record buffers until every
-        shard's matches fit; returns globally-ordered match records."""
+        shard's matches fit; returns globally-ordered match records.
+        ``trace`` (a PhaseTrace) times the ``device.upload``,
+        ``device.launch``, ``device.wait`` and ``device.readback`` stages,
+        as the one-chip step does."""
         B = lines_u8.shape[0]
         D = self.n_shards
         cap_local = (B // D) * max(1, self.bank.n_patterns)
         # contiguous [B, T] upload; the step transposes on device (a host
         # .T copy measured ~9x the contiguous upload — ops/fused.py)
-        lines_bt = self._put(lines_u8, P(DATA_AXIS, None))
-        lens = self._put(lengths, P(DATA_AXIS))
-        om = self._put(override_mask, P(DATA_AXIS, None))
-        ov = self._put(override_val, P(DATA_AXIS, None))
-        n = self._put(np.asarray(n_lines, dtype=np.int32), P())
+        with trace.stage("device.upload"):
+            lines_bt = self._put(lines_u8, P(DATA_AXIS, None))
+            lens = self._put(lengths, P(DATA_AXIS))
+            om = self._put(override_mask, P(DATA_AXIS, None))
+            ov = self._put(override_val, P(DATA_AXIS, None))
+            n = self._put(np.asarray(n_lines, dtype=np.int32), P())
 
         start = 0
         per_shard_hint = -(-max(1, k_hint) // D)
         while start < len(K_LADDER) - 1 and K_LADDER[start] < per_shard_hint:
             start += 1
-        for k_bucket in (*K_LADDER[start:], cap_local):
+        for launches, k_bucket in enumerate((*K_LADDER[start:], cap_local), 1):
             k_l = min(k_bucket, cap_local)
-            out = self._jit(k_l, lines_bt, lens, om, ov, n)
-            n_per_shard = self._host(out[0])
+            with trace.stage("device.launch"):
+                out = self._jit(k_l, lines_bt, lens, om, ov, n)
+            (n_per_shard,) = self._read_back(out[:1], trace)
             if n_per_shard.max(initial=0) <= k_l or k_l >= cap_local:
-                return self._assemble(k_l, n_per_shard, out)
+                return ShardedRun(
+                    self._assemble(k_l, n_per_shard, out, trace), launches, k_l
+                )
         raise AssertionError("unreachable: ladder capped at per-shard B*P")
 
-    def _assemble(self, k_l: int, n_per_shard: np.ndarray, out) -> MatchRecords:
+    def _assemble(self, k_l: int, n_per_shard: np.ndarray, out,
+                  trace) -> MatchRecords:
         """Concatenate each shard's live records; shard-major order is
         line-major order because line sharding is contiguous."""
         D = self.n_shards
-        line = self._host(out[1]).reshape(D, k_l)
-        pat = self._host(out[2]).reshape(D, k_l)
-        dist = self._host(out[3]).reshape(D, k_l, -1)
-        seq = self._host(out[4]).reshape(D, k_l, -1)
-        ctx = self._host(out[5]).reshape(D, k_l, -1)
+        line, pat, dist, seq, ctx = self._read_back(out[1:], trace)
+        line = line.reshape(D, k_l)
+        pat = pat.reshape(D, k_l)
+        dist = dist.reshape(D, k_l, -1)
+        seq = seq.reshape(D, k_l, -1)
+        ctx = ctx.reshape(D, k_l, -1)
         keep = [np.arange(min(int(n), k_l)) for n in n_per_shard]
         return MatchRecords(
             n_matches=int(sum(len(k) for k in keep)),
@@ -220,6 +255,34 @@ class ShardedFusedStep:
             seq_ok=np.concatenate([seq[d, k] for d, k in enumerate(keep)] or [seq[0, :0]]),
             ctx_counts=np.concatenate([ctx[d, k] for d, k in enumerate(keep)] or [ctx[0, :0]]),
         )
+
+    def exchange_bytes(self, B: int) -> int:
+        """Bytes one launch's collectives deliver between chips for a
+        ``B``-row batch, from the static shapes :meth:`_step` exchanges:
+        each halo family's two ``ppermute`` directions, ``D - 1`` sends
+        of ``h`` rows each (edge shards receive zeros), or, where the
+        halo reaches past a neighbour, the ``D - 1`` shards each one
+        receives from an ``all_gather``; and the sequence events'
+        ``all_gather``. Mirrors the choices of :meth:`_extend`,
+        :meth:`_secondary_distances`, :meth:`_sequence_flags` and
+        :meth:`_context_counts`."""
+        D = self.n_shards
+        Bl = B // D
+        if self.bank.n_patterns == 0:
+            return 0
+
+        def neighbourhood(h: int, row_bytes: int) -> int:
+            if h < Bl:
+                return 2 * (D - 1) * h * row_bytes
+            return D * (D - 1) * Bl * row_bytes
+
+        total = 0
+        if len(self.t.sec_cols):  # bool secondary columns
+            total += neighbourhood(max(1, self.h_prox), len(self.t.sec_cols))
+        total += neighbourhood(max(1, self.h_ctx), 4 * 4)  # 4 int32 flags
+        if self.bank.sequences:  # bool event columns, all_gathered
+            total += D * (D - 1) * Bl * len(self.t.seq_event_cols)
+        return total
 
     # ------------------------------------------------------------ the step
 
@@ -368,11 +431,26 @@ class ShardedEngine(AnalysisEngine):
         return max(8, self.mesh.devices.size)
 
     def _run_device(self, enc, n_lines: int, om, ov, trace=NO_TRACE):
+        return self._run_step(self.step, enc, n_lines, om, ov, trace)
+
+    def _run_step(self, step: ShardedFusedStep, enc, n_lines: int, om, ov,
+                  trace=NO_TRACE) -> MatchRecords:
+        """One request through ``step``, its launches, exchanged bytes and
+        record slots counted (``logparser_shard_*``)."""
         B = enc.u8.shape[0]
         C = self.bank.n_columns
         if om is None:  # the SPMD program's in_specs always take overrides
             om = np.zeros((B, C), dtype=bool)
             ov = np.zeros((B, C), dtype=bool)
-        return self.step(
-            enc.u8, enc.lengths, om, ov, n_lines, k_hint=self._k_hint
+        run = step(
+            enc.u8, enc.lengths, om, ov, n_lines, k_hint=self._k_hint,
+            trace=trace,
         )
+        self.obs.note_shard_step(
+            self.obs_tenant,
+            relaunches=run.launches - 1,
+            exchange_bytes=run.launches * step.exchange_bytes(B),
+            record_slots=step.n_shards * run.k_local,
+            records=run.records.n_matches,
+        )
+        return run.records
