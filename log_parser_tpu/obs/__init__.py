@@ -419,7 +419,7 @@ class Obs:
 
     def add_engine_collector(self, engine) -> None:
         """Scrape-time view over one engine's counters and its enabled
-        subsystems' ``stats()`` dicts (line cache, interner, batcher,
+        subsystems' ``stats()`` dicts (line cache, batcher,
         kernel tier, quarantine, shadow, miner)."""
 
         def collect():
@@ -501,13 +501,6 @@ class Obs:
 
                 out.extend(samples_from_stats(
                     cache.stats(), lc.CACHE_METRIC_SAMPLES, labels
-                ))
-            interner = getattr(engine, "key_interner", None)
-            if interner is not None:
-                from log_parser_tpu.runtime import linecache as lc
-
-                out.extend(samples_from_stats(
-                    interner.stats(), lc.INTERNER_METRIC_SAMPLES, labels
                 ))
             batcher = getattr(engine, "batcher", None)
             if batcher is not None:

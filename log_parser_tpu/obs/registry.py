@@ -94,17 +94,16 @@ METRICS = {
         "counter", "Device dispatches by execution tier (kernel vs xla)."),
     "logparser_kernel_rows_total": (
         "counter", "Rows dispatched through the Pallas union-DFA kernel."),
-    # ----------------------------------------- line cache + interner
+    # ------------------------------------------------------- line cache
     "logparser_line_cache_hits_total": ("counter", "Line-cache hit lines."),
     "logparser_line_cache_misses_total": ("counter", "Line-cache miss lines."),
     "logparser_line_cache_evictions_total": (
         "counter", "Line-cache entries evicted."),
     "logparser_line_cache_resident_bytes": (
         "gauge", "Line-cache resident bytes."),
-    "logparser_interner_probe_hits_total": (
-        "counter", "KeyInterner 64-bit probe hits (blake2b skipped)."),
-    "logparser_interner_inserts_total": (
-        "counter", "KeyInterner first-touch inserts (blake2b paid)."),
+    "logparser_line_cache_probe_collisions_total": (
+        "counter", "Line-cache keys whose probe led to a different line "
+        "(looked up or stored as misses)."),
     "logparser_extract_hit_coords_total": (
         "counter", "(line, column) match-bit coordinates the line-cache "
         "extract carried, by tenant."),
@@ -265,9 +264,8 @@ TRACE_BLOCKS = {
     "lineCache": ("logparser_line_cache_hits_total",
                   "logparser_line_cache_misses_total",
                   "logparser_line_cache_evictions_total",
-                  "logparser_line_cache_resident_bytes"),
-    "interner": ("logparser_interner_probe_hits_total",
-                 "logparser_interner_inserts_total"),
+                  "logparser_line_cache_resident_bytes",
+                  "logparser_line_cache_probe_collisions_total"),
     "kernel": ("logparser_kernel_batches_total",
                "logparser_kernel_rows_total",
                "logparser_kernel_plan_vmem_bytes",

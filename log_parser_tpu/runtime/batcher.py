@@ -77,9 +77,11 @@ from log_parser_tpu.native.ingest import Corpus
 from log_parser_tpu.ops.encode import _pad_rows
 from log_parser_tpu.runtime import faults
 from log_parser_tpu.runtime.linecache import (
+    LineKeys,
     dedup_slots,
-    line_key,
+    group_rows,
     records_from_hits,
+    regroup_exact,
     request_hits,
     slot_hits,
 )
@@ -533,90 +535,79 @@ class MicroBatcher:
         actually contributed a residual row — a request served wholly
         from cache can never strike quarantine."""
         engine = self.engine
-        # per-item array-speed dedup (linecache.dedup_slots), then merge
-        # at the UNIQUE level into a flush-global map keyed by digest —
-        # the cache keys on digests already, so digest identity IS line
-        # identity here. Per unique slot: the (item, line) the encode
-        # would be sliced from; prefer a non-needs_host appearance — a
-        # truncated/replaced encode is width-dependent and must neither
-        # populate the cache nor serve another item's clean line. Within
-        # one item duplicate content shares one verdict (same bytes, same
-        # width), so the item-local representative is exact.
-        slot_of: dict[bytes, int] = {}  # digest -> flush-global slot
-        uniq_src: list[tuple[int, int]] = []
-        keys: list[bytes] = []  # digest per slot; insertion == slot order
-        per_item: list[np.ndarray] = []  # per item: line index -> slot
-        for r, item in enumerate(items):
-            corpus = item.corpus
-            enc = corpus.encoded
-            ded = dedup_slots(corpus, interner=engine.key_interner)
-            if ded is None:
-                # lone-surrogate corpus: no contiguous byte view — build
-                # the item-local unique set with the per-line dict loop
-                local_of: dict[bytes, int] = {}
-                reps: list[int] = []
-                ls = np.empty(corpus.n_lines, dtype=np.int64)
-                for i in range(corpus.n_lines):
-                    lb = corpus.line_key_bytes(i)
-                    s = local_of.get(lb)
-                    if s is None:
-                        s = len(reps)
-                        local_of[lb] = s
-                        reps.append(i)
-                    ls[i] = s
-                local_keys = [line_key(lb) for lb in local_of]
-            else:
-                ls, rep_arr, local_keys, _ = ded
-                reps = rep_arr.tolist()
-            g_of_local = np.empty(max(len(reps), 1), dtype=np.int64)
-            for s_local, (k, i) in enumerate(zip(local_keys, reps)):
-                g = slot_of.get(k)
-                if g is None:
-                    g = len(uniq_src)
-                    slot_of[k] = g
-                    uniq_src.append((r, i))
-                    keys.append(k)
-                else:
-                    sr, si = uniq_src[g]
-                    if (
-                        items[sr].corpus.encoded.needs_host[si]
-                        and not enc.needs_host[i]
-                    ):
-                        uniq_src[g] = (r, i)
-                g_of_local[s_local] = g
-            per_item.append(g_of_local[ls] if len(ls) else ls)
-        U = len(uniq_src)
+        # per-item array-speed dedup (linecache.dedup_slots), then one
+        # content grouping of every item's unique lines into flush-global
+        # slots (the cross-request half of the dedup). Per global slot,
+        # the (item, line) the encode is sliced from prefers a storable
+        # (non-needs_host) appearance: a truncated/replaced encode is
+        # width-dependent and must neither populate the cache nor serve
+        # another item's clean line.
+        deds = [dedup_slots(item.corpus) for item in items]
+        sizes = [d[2].rows.size for d in deds]
+        offs = np.cumsum([0] + sizes)
+        widths = np.array([i.corpus.encoded.u8.shape[1] for i in items])
+        words = np.zeros(
+            (int(offs[-1]), max(d[2].words.shape[1] for d in deds)),
+            dtype=np.uint64,
+        )
+        for r, (_, _, k, _) in enumerate(deds):
+            words[offs[r] : offs[r + 1], : k.words.shape[1]] = k.words[k.rows]
+        lengths = np.concatenate([d[2].lengths for d in deds])
+        probes = np.concatenate([d[2].probes for d in deds])
+        storable = np.concatenate([d[2].storable for d in deds])
+        src_item = np.repeat(np.arange(len(items)), sizes)
+        src_line = np.concatenate([d[1] for d in deds])
+        slot, first = group_rows(words, lengths, probes)
+        long_rows = np.flatnonzero(~storable & (lengths >= widths[src_item]))
+        if long_rows.size:
+            slot, first = regroup_exact(
+                slot, long_rows,
+                lambda j: items[src_item[j]].corpus.line_key_bytes(
+                    int(src_line[j])
+                ),
+            )
+        U = first.size
+        best = np.full(U, slot.size, dtype=np.int64)
+        st = np.flatnonzero(storable)
+        np.minimum.at(best, slot[st], st)
+        rep = np.where(best < slot.size, best, first)
+        keys = LineKeys(words, rep, lengths[rep], probes[rep], storable[rep])
+        per_item = [
+            slot[offs[r] : offs[r + 1]][d[0]] for r, d in enumerate(deds)
+        ]
         all_slots = (
             np.concatenate(per_item) if per_item else np.zeros(0, dtype=np.int64)
         )
-        counts = np.bincount(all_slots, minlength=max(U, 1))
-        packed = cache.lookup_packed(keys, counts=counts.tolist())
-        miss_slots = [s for s in range(U) if packed[s] is None]
+        counts = np.bincount(all_slots, minlength=U)
+        found = cache.lookup(keys, counts)
+        miss_slots = np.flatnonzero(found.row < 0)
+        m_item = src_item[rep[miss_slots]]
+        m_line = src_line[rep[miss_slots]]
 
         miner = engine.miner
         if miner is not None:
             # miss-stream tap: one non-blocking bounded-queue offer per
             # unique novel line (sampling + drop accounting live in the
             # tap); mining happens on the miner thread, never here
-            for s in miss_slots:
-                r, i = uniq_src[s]
+            for j, s in enumerate(miss_slots.tolist()):
                 miner.tap.offer(
-                    items[r].corpus.line_key_bytes(i), int(counts[s])
+                    items[m_item[j]].corpus.line_key_bytes(int(m_line[j])),
+                    int(counts[s]),
                 )
 
         fresh = None
-        if miss_slots:
-            u = len(miss_slots)
-            T = max(i.corpus.encoded.u8.shape[1] for i in items)
+        if miss_slots.size:
+            u = miss_slots.size
+            T = int(widths.max())
             pad = _pad_rows(u, engine._corpus_min_rows())
             res_u8 = np.zeros((pad, T), dtype=np.uint8)
             res_len = np.zeros(pad, dtype=np.int32)
-            contributed = sorted({uniq_src[s][0] for s in miss_slots})
-            for j, s in enumerate(miss_slots):
-                r, i = uniq_src[s]
+            contributed = np.unique(m_item).tolist()
+            for r in contributed:
+                j = np.flatnonzero(m_item == r)
                 enc = items[r].corpus.encoded
-                res_u8[j, : enc.u8.shape[1]] = enc.u8[i]
-                res_len[j] = enc.lengths[i]
+                res_u8[j, : enc.u8.shape[1]] = enc.u8[m_line[j]]
+                res_len[j] = enc.lengths[m_line[j]]
 
             def _device_step():
                 for r in contributed:
@@ -638,18 +629,10 @@ class MicroBatcher:
                 "wasteRatio": round((pad - u) / pad, 4) if pad else 0.0,
             })
             cache.note_residual(u, int(counts[miss_slots].sum()) - u)
-            keep = [
-                j
-                for j, s in enumerate(miss_slots)
-                if not items[uniq_src[s][0]].corpus.encoded.needs_host[
-                    uniq_src[s][1]
-                ]
-            ]
-            cache.populate_rows(
-                [keys[miss_slots[j]] for j in keep], fresh[keep]
-            )
+            with stages.stage("cache.populate"):
+                cache.populate(keys.take(miss_slots), fresh, found)
 
-        hits = slot_hits(cache, packed, miss_slots, fresh)
+        hits = slot_hits(found, miss_slots, fresh)
         out = []
         for r, item in enumerate(items):
             n = item.corpus.n_lines

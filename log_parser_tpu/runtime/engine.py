@@ -56,10 +56,8 @@ from log_parser_tpu.ops.fused import FusedMatchScore, FusedStaticTables
 from log_parser_tpu.runtime import faults
 from log_parser_tpu.runtime.linecache import (
     DEFAULT_LINE_CACHE_MB,
-    KeyInterner,
     LineCache,
     dedup_slots,
-    line_key,
     records_from_hits,
     request_hits,
     slot_hits,
@@ -507,7 +505,6 @@ class AnalysisEngine:
         # exact-match line cache (runtime/linecache.py): None until
         # enable_line_cache() — repeat lines then skip the match cube
         self.line_cache = None
-        self.key_interner = None
         # poison-request quarantine (runtime/quarantine.py): organic
         # device failures strike the request's fingerprint; at the
         # threshold repeats route straight to golden until TTL expiry
@@ -1196,20 +1193,16 @@ class AnalysisEngine:
 
     def enable_line_cache(self, mb: float = DEFAULT_LINE_CACHE_MB):
         """Attach the exact-match line cache (runtime/linecache.py):
-        per-line pre-override match-bit rows keyed by the hash of the
-        ingest-normalized line bytes. Repeat lines skip the match cube;
-        novel lines go to the device as a compacted residual batch and
-        populate the cache on the way back. Single-device engines only —
-        the residual program is the full-bank cube (sharded/distributed
-        engines keep the uncached path; the serve layer gates the flag
-        exactly like micro-batching)."""
+        per-line pre-override match-bit rows keyed by the
+        ingest-normalized line bytes themselves. Repeat lines skip the
+        match cube; novel lines go to the device as a compacted residual
+        batch and populate the cache on the way back. Single-device
+        engines only — the residual program is the full-bank cube
+        (sharded/distributed engines keep the uncached path; the serve
+        layer gates the flag exactly like micro-batching)."""
         self.line_cache = LineCache(
             self.bank.n_columns, int(float(mb) * 1024 * 1024)
         )
-        # two-level keying rides along: repeat lines resolve their
-        # digest by vectorized probe + memcmp instead of blake2b
-        # (content-pure, so reloads/breaker trips never touch it)
-        self.key_interner = KeyInterner()
         return self.line_cache
 
     def enable_shadow(self, rate: float, seed: int | None = None):
@@ -1519,48 +1512,20 @@ class AnalysisEngine:
         enc = corpus.encoded
         n = corpus.n_lines
         with trace.phase("cache"):
-            # dedup to unique lines FIRST (bytes-keyed dict, C speed),
-            # then hash once per unique line: one device row per distinct
+            # dedup to unique lines FIRST: one device row per distinct
             # novel line (the in-request half of the dedup; the batcher
             # dedups across a whole flush the same way). Within one
             # request duplicate content always shares one needs_host
             # verdict (same bytes, same device width), so slot-level
             # bookkeeping indexed at the first appearance is exact.
-            ded = dedup_slots(corpus, interner=self.key_interner)
-            if ded is not None:
-                # array-speed lane: lexsort grouping over the contiguous
-                # byte view (same first-appearance slot order, same
-                # digests — linecache.dedup_slots pins the parity)
-                line_slot, rep_lines, keys, counts = ded
-                uniq_lines = rep_lines.tolist()
-                U = len(uniq_lines)
-                counts = (
-                    counts if U else np.zeros(1, dtype=np.int64)
-                )
-            else:
-                # lone-surrogate corpora have no contiguous byte view —
-                # keep the per-line dict loop
-                slot_of: dict[bytes, int] = {}
-                uniq_lines = []
-                line_slot = np.empty(n, dtype=np.int64)
-                for i in range(n):
-                    lb = corpus.line_key_bytes(i)
-                    s = slot_of.get(lb)
-                    if s is None:
-                        s = len(uniq_lines)
-                        slot_of[lb] = s
-                        uniq_lines.append(i)
-                    line_slot[i] = s
-                U = len(uniq_lines)
-                keys = [line_key(lb) for lb in slot_of]  # insertion == slot order
-                counts = np.bincount(line_slot, minlength=max(U, 1))
-            packed = cache.lookup_packed(keys, counts=counts.tolist())
-            miss_slots = [s for s in range(U) if packed[s] is None]
+            line_slot, rep_lines, keys, counts = dedup_slots(corpus)
+            found = cache.lookup(keys, counts)
+            miss_slots = np.flatnonzero(found.row < 0)
 
         fresh = None
-        if miss_slots:
-            miss_lines = [uniq_lines[s] for s in miss_slots]
-            u = len(miss_lines)
+        if miss_slots.size:
+            miss_lines = rep_lines[miss_slots]
+            u = miss_lines.size
             miner = self.miner
             if miner is not None:
                 # miss-stream tap: one non-blocking bounded-queue offer
@@ -1568,7 +1533,7 @@ class AnalysisEngine:
                 # in the tap); the mining work itself happens on the
                 # miner thread, never here
                 cts = counts[miss_slots]
-                for j, i in enumerate(miss_lines):
+                for j, i in enumerate(miss_lines.tolist()):
                     miner.tap.offer(corpus.line_key_bytes(i), int(cts[j]))
             pad = _pad_rows(u, self._corpus_min_rows())
             res_u8 = np.zeros((pad, enc.u8.shape[1]), dtype=np.uint8)
@@ -1587,18 +1552,12 @@ class AnalysisEngine:
             with trace.phase("device"):
                 fresh = self.watchdog.run(_device_step)[:u]
             cache.note_residual(u, int(counts[miss_slots].sum()) - u)
-            # needs_host lines are excluded: their truncated/replaced
-            # encode is width-dependent, so their device bits are not a
-            # function of the line content alone (harmless to LOOK UP —
-            # their columns are fully overridden below — but never stored)
-            keep = [
-                j
-                for j, i in enumerate(miss_lines)
-                if not enc.needs_host[i]
-            ]
-            cache.populate_rows(
-                [keys[miss_slots[j]] for j in keep], fresh[keep]
-            )
+            # needs_host lines are never stored (their keys are not
+            # storable): their truncated/replaced encode is width-
+            # dependent, so their device bits are not a function of the
+            # line content alone (their columns are overridden below)
+            with trace.stage("cache.populate"):
+                cache.populate(keys.take(miss_slots), fresh, found)
 
         with trace.phase("extract"):
             # the override splice (host-only columns, needs_host lines,
@@ -1606,7 +1565,7 @@ class AnalysisEngine:
             # which is what makes a breaker trip an exact per-pattern
             # invalidation
             line, col = request_hits(
-                slot_hits(cache, packed, miss_slots, fresh),
+                slot_hits(found, miss_slots, fresh),
                 line_slot, n, om, ov,
             )
             recs = records_from_hits(line, col, n, self.bank, self.tables)

@@ -7,8 +7,10 @@ cube is gather-bound at ~9 ns/element and pays per (row × automaton ×
 byte) (PERF.md §1), so the cheapest row is the one that never reaches
 the device. This module memoizes the per-line *device-side* result — the
 post-valid match-bit row of the cube, NOT final scores — keyed by the
-hash of the ingest-normalized line bytes (the same normalization the
-quarantine fingerprint uses, native/ingest.py ``normalize_blob``).
+ingest-normalized line bytes themselves (the same normalization the
+quarantine fingerprint uses, native/ingest.py ``normalize_blob``): the
+encoded row's content words and length, compared word for word, so a
+row is only ever served to the bytes it was computed for.
 
 What is cacheable, exactly: in ``FusedMatchScore._step`` everything
 downstream of the cube is a pure function of the post-override bit
@@ -41,23 +43,24 @@ frequency-coupled factors replay on the host under ``state_lock``
 exactly as before.
 
 Novel lines flow to the device as a *compacted* residual batch —
-deduplicated by key within a request and within a batcher flush before
-padding, one device row per unique line — then populate the cache on the
-way back (``dedupFanout`` counts the rows that never had to exist).
+deduplicated by content within a request and within a batcher flush
+before padding, one device row per unique line — then populate the cache
+on the way back (``dedupFanout`` counts the rows that never had to
+exist).
 
 Invalidation: wholesale on ``reload_epoch`` bump (``apply_library``
 flushes under the quiesced swap, so no stale populate can race it) and
 functionally per-pattern on a shadow-verifier breaker trip via the
-override replay described above. Bounded: LRU by resident bytes
-(``--line-cache-mb``). Quarantine-compatible: a request served entirely
-from cache never reaches the device step, so it can never strike.
+override replay described above. Bounded by the arrays' bytes
+(``--line-cache-mb``): the oldest entries are evicted first.
+Quarantine-compatible: a request served entirely from cache never
+reaches the device step, so it can never strike.
 """
 
 from __future__ import annotations
 
-import hashlib
 import threading
-from collections import OrderedDict, deque
+from collections import deque
 from typing import NamedTuple
 
 import numpy as np
@@ -74,381 +77,335 @@ from log_parser_tpu.patterns.bank import (
 
 DEFAULT_LINE_CACHE_MB = 64.0
 
-# per-entry bookkeeping estimate beyond key + packed row: OrderedDict
-# node, bytes objects' headers. Deliberately generous — the budget is an
-# operator-facing ceiling, and under-counting would let the cache outgrow
-# its flag.
-_ENTRY_OVERHEAD = 96
+_GOLDEN = np.uint64(0x9E3779B97F4A7C15)
+
+# bytes of word rows a chunked pass holds at once (~16 MB), so probing
+# or verifying a wide batch never doubles its size in temporaries
+_COMPARE_BYTES = 1 << 24
+
+# the stamp of a free slot: never the oldest, never evicted again
+_FREE = np.iinfo(np.int64).max
 
 
-def line_key(line_bytes: bytes) -> bytes:
-    """Cache key for one ingest-normalized line. blake2b-128 over the
-    exact content bytes: collisions are cryptographically negligible and
-    cache poisoning is impossible — there is no way to make line A serve
-    line B's bits without a preimage."""
-    return hashlib.blake2b(line_bytes, digest_size=16).digest()
+def _fmix64(x: np.ndarray) -> np.ndarray:
+    """murmur3's 64-bit finalizer, in place: a bijection with fmix(0) = 0."""
+    x ^= x >> np.uint64(33)
+    x *= np.uint64(0xFF51AFD7ED558CCD)
+    x ^= x >> np.uint64(33)
+    x *= np.uint64(0xC4CEB9FE1A85EC53)
+    x ^= x >> np.uint64(33)
+    return x
 
 
-_FNV_OFFSET = np.uint64(0xCBF29CE484222325)
-_FNV_PRIME = np.uint64(0x100000001B3)
+def probe64(words: np.ndarray, lengths: np.ndarray) -> np.ndarray:
+    """64-bit probe of each row of ``words`` (uint64 content words, zero
+    past the row's ``lengths`` bytes): the sum of each word mixed with a
+    multiplier of its position, then the length, finalized. A zero word
+    adds nothing, so a line has one probe at every batch width. The
+    probe only indexes: equality is decided by the words and lengths."""
+    n, w = words.shape
+    mult = (np.arange(w, dtype=np.uint64) * np.uint64(2) + np.uint64(1)) * _GOLDEN
+    h = np.empty(n, dtype=np.uint64)
+    step = max(1, _COMPARE_BYTES // max(8, 8 * w))
+    for lo in range(0, n, step):
+        h[lo : lo + step] = _fmix64(words[lo : lo + step] * mult).sum(axis=1)
+    h ^= _fmix64(lengths.astype(np.uint64) + _GOLDEN)
+    return _fmix64(h)
 
 
-def probe64(v64: np.ndarray, lengths: np.ndarray, width: int) -> np.ndarray:
-    """Vectorized 64-bit probe over :func:`dedup_slots`' int64 key-matrix
-    rows: an FNV-1a fold of each row's content-carrying words plus its
-    length, splitmix64-finalized. Width-independent for lines that fit
-    the device width (the padding past a line's last partial word is
-    zeros at every width, and padded-only words are skipped), so the
-    same line yields the same probe across requests with different
-    batch widths — the property the cross-request :class:`KeyInterner`
-    needs. Lines longer than ``width`` hash their truncated prefix — an
-    ambiguous key, which is why :meth:`KeyInterner.digests` never interns
-    them (the stored word row would be truncated too, so the memcmp
-    verify could not tell two same-length lines apart)."""
-    n = v64.shape[0]
-    wc_total = width // 8
-    u = v64[:, :wc_total].view(np.uint64)
-    # words that carry content; the fold skips the all-padding tail so
-    # probes do not depend on this batch's padded width
-    nw = np.minimum(-(-lengths // 8), wc_total)
-    h = np.full(n, _FNV_OFFSET, dtype=np.uint64)
-    max_w = int(nw.max()) if n else 0
-    for j in range(max_w):
-        h = np.where(nw > j, (h ^ u[:, j]) * _FNV_PRIME, h)
-    h = (h ^ lengths.astype(np.uint64)) * _FNV_PRIME
-    h ^= h >> np.uint64(30)
-    h *= np.uint64(0xBF58476D1CE4E5B9)
-    h ^= h >> np.uint64(27)
-    h *= np.uint64(0x94D049BB133111EB)
-    h ^= h >> np.uint64(31)
-    return h
+def word_class(lengths: np.ndarray) -> np.ndarray:
+    """The words a line's key holds: its content words rounded up to a
+    power of two, as the device width's rungs are."""
+    nw = np.maximum(1, -(-np.asarray(lengths, dtype=np.int64) // 8))
+    return np.left_shift(1, np.ceil(np.log2(nw)).astype(np.int64))
 
 
-DEFAULT_INTERNER_MB = 32.0
-
-# interned-content ceiling: 64 words = 512 bytes covers essentially every
-# real log line (device_width already sits at the 99.5% length quantile);
-# longer lines simply keep paying blake2b — exactness never depends on
-# the ceiling
-_INTERN_WORDS = 64
-# fixed per-entry cost: words row + probe + length + recency stamp +
-# digest bytes object + ndarray slot overheads
-_INTERN_ENTRY_BYTES = _INTERN_WORDS * 8 + 8 + 8 + 8 + 16 + _ENTRY_OVERHEAD
+def _as_words(u8: np.ndarray) -> np.ndarray:
+    """uint64 view of a ``[n, width]`` uint8 batch, zero-padded to whole
+    words where the width is not."""
+    if u8.shape[1] % 8 or not u8.flags.c_contiguous:
+        buf = np.zeros((u8.shape[0], -(-u8.shape[1] // 8) * 8), dtype=np.uint8)
+        buf[:, : u8.shape[1]] = u8
+        u8 = buf
+    return u8.view(np.uint64)
 
 
-class KeyInterner:
-    """Two-level cache keying (PERF.md §15): the per-unique-line
-    blake2b-128 fan-in is the keying lane's floor once ingest is
-    vectorized, and repeat traffic pays it again for lines whose digest
-    an earlier request already computed. The interner short-circuits
-    that: a vectorized :func:`probe64` per unique line, a single
-    ``searchsorted`` against the flat probe table, and a numpy
-    word-matrix equality check (the vectorized memcmp) — warm requests
-    recover their digests with ZERO per-line Python and zero
-    cryptographic hashing. Only first-touch lines (and the
-    cryptographically-negligible probe collisions) pay blake2b.
+class LineKeys(NamedTuple):
+    """Line-cache keys: key ``i`` is the content words ``words[rows[i]]``
+    (zero past the line) and the byte length ``lengths[i]``, indexed by
+    ``probes[i]``. Only ``storable`` keys are looked up or stored: the
+    others are ``needs_host`` lines, whose device bits are not a function
+    of these bytes alone (their columns are overridden on the host)."""
 
-    Poisoning stays impossible: a digest is only ever returned for
-    content whose padded word row AND true length compared equal to the
-    content blake2b was run on — the same (prefix, length) ⇒ equality
-    argument :func:`dedup_slots` rests on. Digests are pure functions of
-    line content, so entries survive pattern reloads and breaker trips;
-    the only bound is the byte budget, enforced by evicting the
-    least-recently-used half when full.
-    """
+    words: np.ndarray  # uint64 [R, W]
+    rows: np.ndarray  # int64 [U]
+    lengths: np.ndarray  # int64 [U]
+    probes: np.ndarray  # uint64 [U]
+    storable: np.ndarray  # bool [U]
 
-    def __init__(self, budget_bytes: int = int(DEFAULT_INTERNER_MB * 2**20)):
-        self.lock = threading.Lock()
-        self.budget_bytes = max(0, int(budget_bytes))
-        self.max_entries = max(64, self.budget_bytes // _INTERN_ENTRY_BYTES)
-        self._n = 0
-        self._probes = np.zeros(0, dtype=np.uint64)
-        self._words = np.zeros((0, _INTERN_WORDS), dtype=np.uint64)
-        self._lengths = np.zeros(0, dtype=np.int64)
-        self._stamp = np.zeros(0, dtype=np.int64)  # recency, for eviction
-        self._digests = np.zeros(0, dtype=object)
-        self._gen = 0
-        self._sorted: tuple[np.ndarray, np.ndarray] | None = None
-        self.probe_hits = 0
-        self.inserts = 0
-        self.collisions = 0
-        self.evictions = 0
+    def take(self, idx) -> "LineKeys":
+        return LineKeys(self.words, self.rows[idx], self.lengths[idx],
+                        self.probes[idx], self.storable[idx])
 
-    def _sorted_view(self) -> tuple[np.ndarray, np.ndarray]:
-        if self._sorted is None:
-            order = np.argsort(self._probes[: self._n], kind="stable")
-            self._sorted = (self._probes[order], order)
-        return self._sorted
-
-    def _grow(self, need: int) -> None:
-        cap = len(self._probes)
-        if need <= cap:
-            return
-        new = max(need, 256, cap * 2)
-        for name in ("_probes", "_lengths", "_stamp", "_digests"):
-            old = getattr(self, name)
-            buf = np.zeros(new, dtype=old.dtype)
-            buf[: self._n] = old[: self._n]
-            setattr(self, name, buf)
-        w = np.zeros((new, _INTERN_WORDS), dtype=np.uint64)
-        w[: self._n] = self._words[: self._n]
-        self._words = w
-
-    def evict_half(self) -> int:
-        """Memory-pressure lever (runtime/pressure.py): drop the
-        least-recently-used half of the *current* entries, regardless of
-        table fullness. Returns how many entries were dropped. Safe at
-        any time — a dropped digest is recomputed on next touch."""
-        with self.lock:
-            keep_n = self._n // 2
-            if self._n <= 1 or keep_n < 1:
-                return 0
-            dropped = self._n - keep_n
-            keep = np.argpartition(self._stamp[: self._n], dropped)[dropped:]
-            self.evictions += dropped
-            for name in ("_probes", "_lengths", "_stamp", "_digests"):
-                arr = getattr(self, name)
-                arr[:keep_n] = arr[keep]
-                setattr(self, name, arr)
-            self._words[:keep_n] = self._words[keep]
-            self._n = keep_n
-            self._sorted = None
-            return dropped
-
-    def _evict_half(self) -> None:
-        """Table full: keep the most-recently-used half. Coarser than a
-        per-entry LRU but keeps eviction a single vectorized compaction
-        instead of a per-insert OrderedDict walk."""
-        keep_n = self.max_entries // 2
-        if self._n <= keep_n:
-            return
-        keep = np.argpartition(self._stamp[: self._n], self._n - keep_n)[
-            self._n - keep_n:
-        ]
-        self.evictions += self._n - keep_n
-        for name in ("_probes", "_lengths", "_stamp", "_digests"):
-            arr = getattr(self, name)
-            arr[:keep_n] = arr[keep]
-            setattr(self, name, arr)
-        self._words[:keep_n] = self._words[keep]
-        self._n = keep_n
-        self._sorted = None
-
-    def digests(
-        self,
-        v64_rows: np.ndarray,
-        lengths: np.ndarray,
-        width: int,
-        blob,
-        starts,
-        ends,
-    ) -> list[bytes]:
-        """Digest per unique line, hashing only first-touch content.
-        ``v64_rows``/``lengths`` are :func:`dedup_slots`' int64 key-matrix
-        rows and true byte lengths for the unique lines;
-        ``starts``/``ends`` are plain lists indexing ``blob`` (the same
-        slices :func:`line_key` would hash)."""
-        n = v64_rows.shape[0]
-        if n == 0:
-            return []
-        probes = probe64(v64_rows, lengths, width)
-        wc = width // 8
-        u = v64_rows[:, : min(wc, _INTERN_WORDS)].view(np.uint64)
-        if wc >= _INTERN_WORDS:
-            batch_words = np.ascontiguousarray(u)
-            internable = lengths <= _INTERN_WORDS * 8
-        else:
-            batch_words = np.zeros((n, _INTERN_WORDS), dtype=np.uint64)
-            batch_words[:, :wc] = u
-            # rows longer than the device width are TRUNCATED in v64: two
-            # distinct lines sharing a width prefix (and length) would
-            # compare equal word-for-word and share one digest. They stay
-            # on blake2b — the same guard the wide branch applies at the
-            # interning ceiling.
-            internable = lengths <= width
-        # comparing only the words any batch line can occupy is exact: an
-        # entry with content past that point has a larger length, and the
-        # length check fails first
-        wmax = max(1, min(_INTERN_WORDS, -(-int(lengths.max()) // 8)))
-        out = np.empty(n, dtype=object)
-        found = np.zeros(n, dtype=bool)
-        with self.lock:
-            self._gen += 1
-            present = np.zeros(n, dtype=bool)
-            if self._n:
-                sp, sid = self._sorted_view()
-                pos = np.minimum(
-                    np.searchsorted(sp, probes), self._n - 1
-                )
-                present = sp[pos] == probes
-                cand = np.flatnonzero(present & internable)
-                if cand.size:
-                    eid = sid[pos[cand]]
-                    ok = (self._lengths[eid] == lengths[cand]) & (
-                        self._words[eid, :wmax] == batch_words[cand, :wmax]
-                    ).all(axis=1)
-                    hit_rows = cand[ok]
-                    hit_eids = eid[ok]
-                    self._stamp[hit_eids] = self._gen
-                    self.probe_hits += len(hit_rows)
-                    out[hit_rows] = self._digests[hit_eids]
-                    found[hit_rows] = True
-                    # probe matched but content differs: a 64-bit
-                    # collision — those lines stay on blake2b forever
-                    self.collisions += int(ok.size - ok.sum())
-            miss_rows = np.flatnonzero(~found).tolist()
-            ins_rows: list[int] = []
-            batch_probes: set[int] = set()
-            for i in miss_rows:
-                out[i] = line_key(blob[starts[i] : ends[i]])
-                p = int(probes[i])
-                if internable[i] and not present[i] and p not in batch_probes:
-                    batch_probes.add(p)
-                    ins_rows.append(i)
-            if self._n + len(ins_rows) > self.max_entries:
-                self._evict_half()
-                ins_rows = ins_rows[: max(0, self.max_entries - self._n)]
-            if ins_rows:
-                self._grow(self._n + len(ins_rows))
-                ir = np.asarray(ins_rows, dtype=np.int64)
-                sl = slice(self._n, self._n + len(ins_rows))
-                self._probes[sl] = probes[ir]
-                self._words[sl] = batch_words[ir]
-                self._lengths[sl] = lengths[ir]
-                self._stamp[sl] = self._gen
-                self._digests[sl] = out[ir]
-                self._n += len(ins_rows)
-                self.inserts += len(ins_rows)
-                self._sorted = None
-        return out.tolist()
-
-    def stats(self) -> dict:
-        with self.lock:
-            return {
-                "budgetMb": round(self.budget_bytes / 2**20, 3),
-                "entries": self._n,
-                "residentBytes": self._n * _INTERN_ENTRY_BYTES,
-                "probeHits": self.probe_hits,
-                "inserts": self.inserts,
-                "collisions": self.collisions,
-                "evictions": self.evictions,
-            }
+    def words_of(self, idx: np.ndarray, k: int) -> np.ndarray:
+        """The first ``k`` content words of keys ``idx``."""
+        w = self.words[self.rows[idx], :k]
+        return np.pad(w, ((0, 0), (0, k - w.shape[1]))) if w.shape[1] < k else w
 
 
-def dedup_slots(
-    corpus, interner: "KeyInterner | None" = None
-) -> tuple[np.ndarray, np.ndarray, list[bytes], np.ndarray] | None:
-    """Vectorized request-level dedup: unique lines and the line→slot
-    fan-in in array speed instead of a per-line dict loop.
+def line_keys(lines: list[bytes]) -> LineKeys:
+    """Storable keys of lines given as their ingest-normalized bytes (the
+    follow-mode stream keys its device-pure lines so)."""
+    n = len(lines)
+    lengths = np.fromiter(map(len, lines), dtype=np.int64, count=n)
+    words = np.zeros((n, int(word_class(lengths).max(initial=1))), np.uint64)
+    for j, b in enumerate(lines):
+        words[j].view(np.uint8)[: len(b)] = np.frombuffer(b, dtype=np.uint8)
+    return LineKeys(words, np.arange(n), lengths, probe64(words, lengths),
+                    np.ones(n, dtype=bool))
 
-    Returns ``(line_slot, rep_lines, keys, counts)`` where slots are
-    numbered by first appearance (bit-compatible with the scalar dict
-    loop it replaces), ``rep_lines[s]`` is the first line index of slot
-    ``s``, ``keys[s]`` its :func:`line_key` digest and ``counts[s]`` its
-    multiplicity. Returns ``None`` when the corpus has no contiguous
-    byte view (the lone-surrogate scalar path) — callers keep the dict
-    loop there.
 
-    Exactness: the comparison key is the encoded ``[width]`` u8 row
-    concatenated with the true byte length. For lines that fit the
-    device width the row IS the content (zero-padding is disambiguated
-    by the length word: equal lengths + equal prefix ⇒ equal bytes).
-    Lines longer than the width are ambiguous under truncation, so they
-    are re-grouped exactly on their blob slices — they can never collide
-    with a short line (lengths differ) and are rare by construction
-    (device_width covers the 99.5% quantile, ops/encode.py).
-    """
-    kv = corpus.key_view()
-    if kv is None:
-        return None
-    blob, starts, ends = kv
+def _by_first_appearance(group: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    """``(slot, first)`` for arbitrary group ids: slots numbered by the
+    first row of each group, as the scalar dict loop numbers them."""
+    uniq, first = np.unique(group, return_index=True)
+    order = np.argsort(first, kind="stable")
+    remap = np.empty(uniq.size, dtype=np.int64)
+    remap[order] = np.arange(uniq.size)
+    return remap[np.searchsorted(uniq, group)], first[order]
+
+
+def group_rows(
+    words: np.ndarray, lengths: np.ndarray, probes: np.ndarray
+) -> tuple[np.ndarray, np.ndarray]:
+    """Group rows of equal content (``words`` and ``lengths``): the slot
+    of each row, numbered by first appearance, and each slot's first row.
+    One argsort of the probes; every row of a run of equal probes is
+    compared word for word with the run's first, and the rows of a run
+    that holds different lines (a probe collision) are regrouped on
+    their whole content."""
+    n = probes.size
+    order = np.argsort(probes)
+    newrun = np.empty(n, dtype=bool)
+    newrun[:1] = True
+    sp = probes[order]
+    np.not_equal(sp[1:], sp[:-1], out=newrun[1:])
+    run = np.cumsum(newrun) - 1
+    starts = np.flatnonzero(newrun)
+    heads = np.minimum.reduceat(order, starts) if n else starts
+    member = np.flatnonzero(~newrun)
+    a, b = order[member], heads[run[member]]
+    bad = lengths[a] != lengths[b]
+    step = max(1, _COMPARE_BYTES // max(1, words.itemsize * words.shape[1]))
+    for lo in range(0, member.size, step):
+        hi = lo + step
+        bad[lo:hi] |= (words[a[lo:hi]] != words[b[lo:hi]]).any(axis=1)
+    group = np.empty(n, dtype=np.int64)
+    group[order] = run
+    if bad.any():
+        rows = order[np.isin(run, run[member[bad]])]
+        content = np.concatenate(
+            [words[rows], lengths[rows, None].astype(np.uint64)], axis=1
+        )
+        _, inv = np.unique(content, axis=0, return_inverse=True)
+        group[rows] = heads.size + inv.ravel()
+        return _by_first_appearance(group)
+    # number the runs by their first rows, in line order
+    is_head = np.zeros(n, dtype=bool)
+    is_head[heads] = True
+    rank = np.cumsum(is_head) - 1
+    return rank[heads][group], np.flatnonzero(is_head)
+
+
+def regroup_exact(
+    slot: np.ndarray, rows: np.ndarray, content_of
+) -> tuple[np.ndarray, np.ndarray]:
+    """Regroup ``rows`` on their whole bytes (``content_of(row)``): lines
+    past the batch width are truncated in their word rows, so two can
+    share words and length (rare: ``device_width`` covers 99.5%)."""
+    slot = slot.copy()
+    base = int(slot.max()) + 1
+    exact: dict[bytes, int] = {}
+    for i in rows.tolist():
+        slot[i] = exact.setdefault(content_of(i), base + len(exact))
+    return _by_first_appearance(slot)
+
+
+def dedup_slots(corpus) -> tuple[np.ndarray, np.ndarray, LineKeys, np.ndarray]:
+    """Request-level dedup at array speed: ``(line_slot, rep_lines, keys,
+    counts)``, slots numbered by first appearance (as the scalar dict
+    loop numbers them), ``rep_lines[s]`` the first line of slot ``s``,
+    ``keys`` each slot's :class:`LineKeys` entry, ``counts[s]`` its lines.
+
+    Exactness: a line's encoded ``u8`` row and length are its bytes when
+    it fits the batch width. A line past the width is ``needs_host`` at
+    the width's length, so those lines are regrouped on their bytes
+    (:func:`regroup_exact`), and never stored. Lone-surrogate corpora
+    key the same way: their encode replaces as ``line_key_bytes`` does."""
     enc = corpus.encoded
     n = int(enc.n_lines)
-    if n == 0:
-        z = np.zeros(0, dtype=np.int64)
-        return z, z, [], z
-    # the offset arrays may carry dropped trailing-empty parts past n
-    starts = starts[:n]
-    ends = ends[:n]
     width = enc.u8.shape[1]
-    lengths = (ends - starts).astype(np.int64)
-    # key row = u8 content ‖ true length, padded to an int64 boundary so
-    # the grouping sort runs over a handful of int64 columns (a memcmp
-    # sort over void rows is ~1.5× slower at this shape)
-    kw = -(-(width + 8) // 8) * 8
-    km = np.zeros((n, kw), dtype=np.uint8)
-    km[:, :width] = enc.u8[:n]
-    km[:, width : width + 8] = lengths.astype("<i8").reshape(n, 1).view(np.uint8)
-    v64 = km.view("<i8")
-    order = np.lexsort(v64.T[::-1])
-    srt = v64[order]
-    newrun = np.empty(n, dtype=bool)
-    newrun[0] = True
-    np.any(srt[1:] != srt[:-1], axis=1, out=newrun[1:])
-    gid_sorted = np.cumsum(newrun) - 1
-    group = np.empty(n, dtype=np.int64)
-    group[order] = gid_sorted
-    # lexsort is stable, so the first member of each run is the group's
-    # first appearance in line order
-    first_idx = order[np.flatnonzero(newrun)]
-    long_lines = np.flatnonzero(lengths > width)
+    words = _as_words(enc.u8[:n])
+    lengths = enc.lengths[:n].astype(np.int64)
+    probes = probe64(words, lengths)
+    slot, first = group_rows(words, lengths, probes)
+    long_lines = np.flatnonzero(enc.needs_host[:n] & (lengths >= width))
     if long_lines.size:
-        next_gid = int(first_idx.size)
-        exact: dict[bytes, int] = {}
-        s_l = starts.tolist()
-        e_l = ends.tolist()
-        for i in long_lines.tolist():
-            content = blob[s_l[i] : e_l[i]]
-            gid = exact.get(content)
-            if gid is None:
-                gid = next_gid
-                next_gid += 1
-                exact[content] = gid
-            group[i] = gid
-        # regrouping may have emptied gids and appended new ones: rebuild
-        # first-occurrence indices the general way
-        uniq_g, first = np.unique(group, return_index=True)
-        ord2 = np.argsort(first, kind="stable")
-        remap = np.empty(uniq_g.size, dtype=np.int64)
-        remap[ord2] = np.arange(uniq_g.size)
-        line_slot = remap[np.searchsorted(uniq_g, group)]
-        rep_lines = first[ord2]
-    else:
-        # renumber groups by first appearance so slot order matches the
-        # scalar dict loop byte-for-byte
-        ord2 = np.argsort(first_idx, kind="stable")
-        remap = np.empty(first_idx.size, dtype=np.int64)
-        remap[ord2] = np.arange(first_idx.size)
-        line_slot = remap[group]
-        rep_lines = first_idx[ord2]
-    s_l = starts[rep_lines].tolist()
-    e_l = ends[rep_lines].tolist()
-    if interner is not None and width % 8 == 0:
-        # two-level keying: vectorized probes + word-matrix-verified
-        # digest reuse; blake2b only for lines never seen before
-        keys = interner.digests(
-            v64[rep_lines], lengths[rep_lines], width, blob, s_l, e_l
+        slot, first = regroup_exact(slot, long_lines, corpus.line_key_bytes)
+    keys = LineKeys(words, first, lengths[first], probes[first],
+                    ~enc.needs_host[first])
+    return slot, first, keys, np.bincount(slot, minlength=first.size)
+
+
+def _classes(keys: LineKeys):
+    """``(k, idx)``: the storable keys of each word class."""
+    idx = np.flatnonzero(keys.storable)
+    cls = word_class(keys.lengths[idx])
+    for k in np.flatnonzero(np.bincount(cls)).tolist():
+        yield k, idx[cls == k]
+
+
+class _Table:
+    """The cached lines of one word class, ``k`` content words each, in
+    arrays of capacity ``cap``: slots ``[0, n)`` are in use but those in
+    ``free`` (evicted, stamp ``_FREE``), which the next appends take
+    first. ``sp``/``sid`` index the live entries by probe, ascending,
+    one entry a probe."""
+
+    def __init__(self, k: int, row_bytes: int):
+        self.k = k
+        self.n = self.epoch = 0  # epoch: compactions, which move entries
+        self.free = np.zeros(0, dtype=np.int64)
+        self.words = np.zeros((0, k), dtype=np.uint64)
+        self.lengths = np.zeros(0, dtype=np.int32)
+        self.packed = np.zeros((0, row_bytes), dtype=np.uint8)
+        self.stamp = np.zeros(0, dtype=np.int64)  # the call that last touched it
+        self.sp, self.sid = np.zeros(0, np.uint64), np.zeros(0, np.int64)
+        # a slot's bytes in every array, the probe index included
+        self.entry_bytes = k * 8 + 4 + row_bytes + 8 + 16
+
+    @property
+    def cap(self) -> int:
+        return len(self.lengths)
+
+    @property
+    def live(self) -> int:
+        return self.n - self.free.size
+
+    def find(self, keys: LineKeys, idx: np.ndarray):
+        """``(entry, collided)`` for keys ``idx``: the entry holding each
+        key's line (-1 where none does), and the keys whose probe leads to
+        a different line."""
+        eid = np.full(idx.size, -1, dtype=np.int64)
+        collided = np.zeros(idx.size, dtype=bool)
+        if not self.sp.size:
+            return eid, collided
+        probes = keys.probes[idx]
+        # sorted needles search ~8x faster than random ones at this size
+        o = np.argsort(probes)
+        pos = np.empty(idx.size, dtype=np.int64)
+        pos[o] = np.minimum(np.searchsorted(self.sp, probes[o]), self.sp.size - 1)
+        cand = np.flatnonzero(self.sp[pos] == probes)
+        e, i = self.sid[pos[cand]], idx[cand]
+        ok = (self.lengths[e] == keys.lengths[i]) & (
+            self.words[e] == keys.words_of(i, self.k)
+        ).all(axis=1)
+        eid[cand[ok]] = e[ok]
+        collided[cand[~ok]] = True
+        return eid, collided
+
+    def resize(self, cap: int) -> None:
+        """Grow or shrink the arrays to ``cap`` slots in place: a realloc,
+        which does not copy the entries kept. ``ndarray.resize`` refuses
+        an array that anything else references, so each is resized
+        through its attribute."""
+        self.words.resize((cap, self.k))
+        self.lengths.resize(cap)
+        self.packed.resize((cap, self.packed.shape[1]))
+        self.stamp.resize(cap)
+
+    def drop(self, slots: np.ndarray) -> None:
+        """Evict the entries in ``slots``: out of the index, their slots
+        free for reuse."""
+        gone = np.zeros(self.n, dtype=bool)
+        gone[slots] = True
+        keep = ~gone[self.sid]
+        self.sp, self.sid = self.sp[keep], self.sid[keep]
+        self.stamp[slots] = _FREE
+        self.free = np.concatenate([self.free, slots])
+
+    def copy(self, src: np.ndarray, dst: np.ndarray) -> None:
+        """Copy the entries in slots ``src`` to slots ``dst``."""
+        for name in ("words", "lengths", "packed", "stamp"):
+            arr = getattr(self, name)
+            arr[dst] = arr[src]
+
+    def compact(self) -> None:
+        """Close up the free slots, down to a capacity of ``live``: the
+        live entries past it move into the free slots below it."""
+        self.epoch += 1
+        live = self.live
+        holes = self.free[self.free < live]
+        movers = live + np.flatnonzero(self.stamp[live : self.n] != _FREE)
+        self.copy(movers, holes)
+        to = np.arange(self.n)
+        to[movers] = holes
+        self.sid = to[self.sid]
+        self.n, self.free = live, self.free[:0]
+        self.resize(live)
+
+    def append(self, keys: LineKeys, idx: np.ndarray, packed, gen: int) -> None:
+        """Store keys ``idx`` (in probe order, none of them present),
+        free slots first."""
+        f = min(idx.size, self.free.size)
+        slots = np.concatenate(
+            [self.free[:f], np.arange(self.n, self.n + idx.size - f)]
         )
-    else:
-        keys = [line_key(blob[a:b]) for a, b in zip(s_l, e_l)]
-    counts = np.bincount(line_slot, minlength=rep_lines.size)
-    return line_slot, rep_lines, keys, counts
+        self.free = self.free[f:]
+        self.n += idx.size - f
+        self.words[slots] = keys.words_of(idx, self.k)
+        self.lengths[slots] = keys.lengths[idx]
+        self.packed[slots] = packed
+        self.stamp[slots] = gen
+        # merge into the index: the new probes are sorted already
+        probes = keys.probes[idx]
+        at = np.searchsorted(self.sp, probes) + np.arange(idx.size)
+        old = np.ones(self.sp.size + idx.size, dtype=bool)
+        old[at] = False
+        for name, new in (("sp", probes), ("sid", slots)):
+            merged = np.empty(old.size, dtype=new.dtype)
+            merged[at], merged[old] = new, getattr(self, name)
+            setattr(self, name, merged)
+
+
+class CachedRows(NamedTuple):
+    """What one lookup found: ``row[s]`` is key ``s``'s row in
+    ``packed`` (-1 for a miss), the hits' bit-packed rows copied under
+    the lock in key order; ``touched`` the hit entries, ``(table, epoch,
+    slots)``, for the request's populate to touch again."""
+
+    row: np.ndarray  # int64 [U]
+    packed: np.ndarray  # uint8 [H, ceil(n_columns / 8)]
+    touched: tuple = ()
 
 
 class LineCache:
-    """Bounded LRU of per-line pre-override match-bit rows.
+    """Content-addressed table of per-line pre-override match-bit rows.
 
-    Thread-safe: one lock acquisition per ``lookup_packed`` /
-    ``populate`` call (the batcher and concurrent pipelined requests
-    share one instance). Rows are stored bit-packed (``np.packbits``) —
-    a 600-column bank costs 75 bytes per resident line."""
+    One :class:`_Table` a word class holds each cached line's content
+    words, length, packed bit row (``np.packbits``: a 108-column bank
+    costs 14 bytes a line) and recency stamp, indexed by the sorted
+    probes. Lookup and populate are array operations under one lock
+    acquisition a call; a hit needs equal words and length, so a row is
+    only ever served to the bytes it was computed for. ``budget_bytes``
+    bounds the arrays' bytes at their capacity: past it the oldest
+    entries are evicted, never those the current call touched."""
 
     def __init__(self, n_columns: int, budget_bytes: int):
         self.lock = threading.Lock()
         self.budget_bytes = max(0, int(budget_bytes))
-        self._entries: OrderedDict[bytes, bytes] = OrderedDict()
         self._set_columns(n_columns)
-        self.resident_bytes = 0
+        self._gen = 0
         # counters (GET /trace/last "lineCache"; guarded by lock)
         self.hits = 0
         self.misses = 0
@@ -456,123 +413,170 @@ class LineCache:
         self.dedup_fanout = 0
         self.evictions = 0
         self.epoch_flushes = 0
+        self.probe_collisions = 0
 
     def _set_columns(self, n_columns: int) -> None:
         self.n_columns = int(n_columns)
         self._row_bytes = (self.n_columns + 7) // 8
-        self._entry_cost = 16 + self._row_bytes + _ENTRY_OVERHEAD
+        self._tables: dict[int, _Table] = {}
+
+    def _resident(self) -> int:
+        return sum(t.cap * t.entry_bytes for t in self._tables.values())
 
     # ------------------------------------------------------------- data path
 
-    def lookup_packed(
-        self, keys: list[bytes], counts: list[int] | None = None
-    ) -> list[bytes | None]:
-        """Per-key packed bit rows (or None for misses), LRU touch +
-        hit/miss accounting in one lock acquisition. ``counts`` weights
-        each key by its line multiplicity — the hot paths dedup a request
-        to unique keys before looking up, but the counters keep describing
-        LINES (hit rate stays meaningful to an operator) while the
-        residual keeps describing device rows."""
-        packed: list[bytes | None] = []
+    def lookup(self, keys: LineKeys, counts=None) -> CachedRows:
+        """Find each key's row, touching the entries hit. ``counts``
+        weights each key by its line multiplicity, so the hit and miss
+        counters describe lines while the residual describes device rows;
+        keys that are not storable count as misses."""
+        U = keys.probes.size
+        w = np.ones(U, dtype=np.int64) if counts is None else np.asarray(counts)
+        found, rows, touched = [], [], []
         with self.lock:
-            hits = misses = 0
-            for j, k in enumerate(keys):
-                row = self._entries.get(k)
-                w = counts[j] if counts is not None else 1
-                if row is None:
-                    misses += w
-                else:
-                    self._entries.move_to_end(k)
-                    hits += w
-                packed.append(row)
-            self.hits += hits
-            self.misses += misses
-        return packed
+            self._gen += 1
+            for k, idx in _classes(keys):
+                table = self._tables.get(k)
+                if table is None:
+                    continue
+                eid, collided = table.find(keys, idx)
+                self.probe_collisions += int(collided.sum())
+                e = eid[eid >= 0]
+                table.stamp[e] = self._gen
+                touched.append((table, table.epoch, e))
+                found.append(idx[eid >= 0])
+                rows.append(table.packed[e])
+            hit = np.concatenate(found) if found else np.zeros(0, dtype=np.int64)
+            h = int(w[hit].sum())
+            self.hits += h
+            self.misses += int(w.sum()) - h
+        row = np.full(U, -1, dtype=np.int64)
+        if not hit.size:
+            return CachedRows(row, np.zeros((0, self._row_bytes), dtype=np.uint8))
+        order = np.argsort(hit)
+        row[hit[order]] = np.arange(hit.size)
+        return CachedRows(row, np.concatenate(rows)[order], tuple(touched))
 
-    def unpack(self, packed: list[bytes]) -> np.ndarray:
-        """Batch-unpack packed rows to bool [len(packed), n_columns] in
-        one ``np.unpackbits`` call — the per-row variant is ~20x slower
-        on a repeat-heavy request (PERF.md §11)."""
-        if not packed:
-            return np.zeros((0, self.n_columns), dtype=bool)
-        buf = np.frombuffer(b"".join(packed), dtype=np.uint8)
-        return np.unpackbits(
-            buf.reshape(len(packed), self._row_bytes),
-            axis=1,
-            count=self.n_columns,
-        ).astype(bool)
+    def unpack(self, packed: np.ndarray) -> np.ndarray:
+        """Packed rows to bool ``[H, n_columns]`` in one ``np.unpackbits``."""
+        return np.unpackbits(packed, axis=1, count=self.n_columns).astype(bool)
 
-    def row_hits(self, packed: list[bytes]) -> tuple[np.ndarray, np.ndarray]:
-        """``(row, col)`` of the set bits of packed rows, sorted by row
-        then column — the extract path's view: only the nonzero bytes
-        are unpacked, never the whole rows."""
-        if not packed:
-            z = np.zeros(0, dtype=np.int64)
-            return z, z
-        buf = np.frombuffer(b"".join(packed), dtype=np.uint8)
-        flat = _nonzero_bytes(buf)
-        k, bit = np.nonzero(np.unpackbits(buf[flat][:, None], axis=1))
-        row, byte = np.divmod(flat[k], self._row_bytes)
-        return row, byte * 8 + bit
-
-    def lookup(self, keys: list[bytes]) -> list[np.ndarray | None]:
-        """Per-key bit rows (bool [n_columns]) or None for misses —
-        convenience wrapper over :meth:`lookup_packed` for tests and
-        small callers; the engine/batcher hot paths stay packed."""
-        packed = self.lookup_packed(keys)
-        hit = [p for p in packed if p is not None]
-        rows = self.unpack(hit)
-        out: list[np.ndarray | None] = []
-        j = 0
-        for p in packed:
-            if p is None:
-                out.append(None)
-            else:
-                out.append(rows[j])
-                j += 1
-        return out
-
-    def populate_rows(self, keys: list[bytes], rows: np.ndarray) -> None:
-        """Insert freshly computed rows (bool [len(keys), n_columns]),
-        packed in one ``np.packbits`` call, evicting LRU entries past the
-        byte budget."""
-        if not keys:
-            return
-        packed = np.packbits(np.asarray(rows, dtype=bool), axis=1)
-        ready = [(k, packed[j].tobytes()) for j, k in enumerate(keys)]
-        self._insert(ready)
-
-    def populate(self, items: list[tuple[bytes, np.ndarray]]) -> None:
-        """Insert freshly computed (key, bool-row) pairs — convenience
-        wrapper over :meth:`populate_rows`."""
-        if items:
-            self.populate_rows(
-                [k for k, _ in items], np.stack([r for _, r in items])
+    def populate(self, keys: LineKeys, rows: np.ndarray, found=None) -> None:
+        """Store the storable keys' freshly computed rows (bool
+        ``[U, n_columns]``, one a key), bit-packed. A line already there
+        is touched; a key whose probe leads to a different line is
+        counted and not stored. The entries the request's lookup hit
+        (``found``) are touched again first: they are in use until the
+        request is done, so its rows never evict them."""
+        # packed from the set bits: the readback comes column-major, and
+        # a row-wise pack of it would stride across the whole matrix
+        r, c = bool_hits(np.asarray(rows, dtype=bool))
+        packed = np.zeros((len(rows), self._row_bytes), dtype=np.uint8)
+        np.bitwise_or.at(packed, (r, c >> 3), (128 >> (c & 7)).astype(np.uint8))
+        sel = np.flatnonzero(keys.storable)
+        if sel.size < keys.storable.size:
+            keys, packed = keys.take(sel), packed[sel]
+        # outside the lock: each class's keys in probe order (the index
+        # merge wants it), where a later key of this call under the same
+        # probe (the same line, or a collision) sits right after the first
+        classes = []
+        for k, idx in _classes(keys):
+            idx = idx[np.argsort(keys.probes[idx])]
+            p = keys.probes[idx]
+            later = np.zeros(idx.size, dtype=bool)
+            np.equal(p[1:], p[:-1], out=later[1:])
+            head = idx[np.maximum.accumulate(
+                np.where(later, 0, np.arange(idx.size)))]
+            collided = later.copy()
+            collided[later] = ~(
+                (keys.lengths[idx[later]] == keys.lengths[head[later]])
+                & (keys.words_of(idx[later], k)
+                   == keys.words_of(head[later], k)).all(axis=1)
             )
+            classes.append((k, idx, later, collided))
+        with self.lock:
+            self._gen += 1
+            for table, epoch, slots in found.touched if found else ():
+                if table.epoch == epoch:  # no compaction has moved them
+                    slots = slots[table.stamp[slots] != _FREE]
+                    table.stamp[slots] = self._gen
+            for k, idx, later, collided in classes:
+                table = self._tables.get(k) or self._tables.setdefault(
+                    k, _Table(k, self._row_bytes)
+                )
+                eid, coll = table.find(keys, idx)
+                table.stamp[eid[eid >= 0]] = self._gen
+                self.probe_collisions += int((collided | coll).sum())
+                new = idx[(eid < 0) & ~coll & ~later]
+                self._insert(table, keys, new, packed[new])
+            for k in [k for k, t in self._tables.items() if not t.live]:
+                del self._tables[k]
+
+    def _insert(self, table: _Table, keys: LineKeys, idx, packed) -> None:
+        """Store in ``table``: in its free slots, then in capacity grown
+        within the budget. Where the budget has no room, the oldest
+        entries of every class make way, at least a sixteenth of the
+        budget at a time: an eviction costs a pass over every entry, so
+        calls far smaller than the budget share one. What still does not
+        fit is not stored."""
+        eb = table.entry_bytes
+        short = idx.size - table.free.size - (table.cap - table.n)
+        room = max(0, self.budget_bytes - self._resident()) // eb
+        if short > room:
+            self._evict(max((short - room) * eb, self.budget_bytes // 16),
+                        table)
+            short = idx.size - table.free.size - (table.cap - table.n)
+            room = max(0, self.budget_bytes - self._resident()) // eb
+        grow = max(0, min(short, room))
+        if grow:
+            table.resize(table.cap + grow)
+        if short > grow:
+            idx, packed = idx[: grow - short], packed[: grow - short]
+        if idx.size:
+            table.append(keys, idx, packed, self._gen)
+
+    def _evict(self, need: int, keep: _Table | None = None) -> None:
+        """Evict the oldest entries of every class until they free
+        ``need`` bytes, never one this call touched (stamp >= the current
+        call). ``keep`` keeps its freed slots for its next append; any
+        other table that loses entries is compacted, giving its bytes
+        back to the budget. The cost follows the entries: one partition
+        of their stamps picks the oldest that could cover ``need``, and
+        only those are sorted."""
+        tables = [t for t in self._tables.values() if t.n]
+        if need <= 0 or not tables:
+            return
+        # free slots (stamp _FREE) and this call's entries sort last
+        stamps = np.concatenate([t.stamp[: t.n] for t in tables])
+        ends = np.cumsum([t.n for t in tables])
+        eb = np.array([t.entry_bytes for t in tables])
+        k = min(stamps.size, -(-need // int(eb.min())))
+        sel = (np.argpartition(stamps, k - 1)[:k] if k < stamps.size
+               else np.arange(stamps.size))
+        sel = sel[stamps[sel] < self._gen]
+        sel = sel[np.argsort(stamps[sel], kind="stable")]
+        which = np.searchsorted(ends, sel, side="right")
+        n = int(np.searchsorted(np.cumsum(eb[which]), need)) + 1
+        sel, which = sel[:n], which[:n]
+        for j, t in enumerate(tables):
+            slots = sel[which == j] - (ends[j] - t.n)
+            if slots.size:
+                self.evictions += slots.size
+                t.drop(slots)
+                if t is not keep:
+                    t.compact()
 
     def set_budget(self, budget_bytes: int) -> None:
         """Re-arbitrate the byte budget live (fleet/budget.py pushes
-        shares through ``POST /admin/budget``): shrink evicts LRU
-        entries down to the new budget immediately."""
+        shares through ``POST /admin/budget``): a shrink evicts the oldest
+        entries down to the new budget at once."""
         with self.lock:
             self.budget_bytes = max(0, int(budget_bytes))
-            while self.resident_bytes > self.budget_bytes and self._entries:
-                self._entries.popitem(last=False)
-                self.resident_bytes -= self._entry_cost
-                self.evictions += 1
-
-    def _insert(self, ready: list[tuple[bytes, bytes]]) -> None:
-        with self.lock:
-            for k, p in ready:
-                if k in self._entries:
-                    self._entries.move_to_end(k)
-                    continue
-                self._entries[k] = p
-                self.resident_bytes += self._entry_cost
-            while self.resident_bytes > self.budget_bytes and self._entries:
-                self._entries.popitem(last=False)
-                self.resident_bytes -= self._entry_cost
-                self.evictions += 1
+            if self._resident() > self.budget_bytes:
+                self._gen += 1
+                for t in self._tables.values():
+                    t.compact()
+                self._evict(self._resident() - self.budget_bytes)
 
     def note_residual(self, rows: int, fanout: int) -> None:
         """Account one residual dispatch: ``rows`` unique device rows
@@ -588,11 +592,10 @@ class LineCache:
         swap is structurally impossible. ``n_columns`` re-binds the row
         width when the new library changes the bank's column count."""
         with self.lock:
-            self._entries.clear()
-            self.resident_bytes = 0
+            self._set_columns(
+                self.n_columns if n_columns is None else n_columns
+            )
             self.epoch_flushes += 1
-            if n_columns is not None and n_columns != self.n_columns:
-                self._set_columns(n_columns)
 
     # ------------------------------------------------------- observability
 
@@ -600,29 +603,27 @@ class LineCache:
         with self.lock:
             return {
                 "budgetMb": round(self.budget_bytes / (1024 * 1024), 3),
-                "entries": len(self._entries),
-                "residentBytes": self.resident_bytes,
+                "entries": sum(t.live for t in self._tables.values()),
+                "residentBytes": self._resident(),
                 "hits": self.hits,
                 "misses": self.misses,
                 "residualRows": self.residual_rows,
                 "dedupFanout": self.dedup_fanout,
                 "evictions": self.evictions,
                 "epochFlushes": self.epoch_flushes,
+                "probeCollisions": self.probe_collisions,
             }
 
 
-# /metrics views over LineCache.stats() / KeyInterner.stats() — read by
-# the obs engine collector at scrape time (log_parser_tpu/obs), so the
-# exposition and /trace/last can never disagree on these counters
+# /metrics views over LineCache.stats() — read by the obs engine
+# collector at scrape time (log_parser_tpu/obs), so the exposition and
+# /trace/last can never disagree on these counters
 CACHE_METRIC_SAMPLES = (
     ("hits", "logparser_line_cache_hits_total", {}),
     ("misses", "logparser_line_cache_misses_total", {}),
     ("evictions", "logparser_line_cache_evictions_total", {}),
     ("residentBytes", "logparser_line_cache_resident_bytes", {}),
-)
-INTERNER_METRIC_SAMPLES = (
-    ("probeHits", "logparser_interner_probe_hits_total", {}),
-    ("inserts", "logparser_interner_inserts_total", {}),
+    ("probeCollisions", "logparser_line_cache_probe_collisions_total", {}),
 )
 
 
@@ -775,31 +776,39 @@ class SlotHits(NamedTuple):
     cols: np.ndarray  # int64 [H]
 
 
+def packed_hits(packed: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    """``(row, col)`` of the set bits of bit-packed rows ``[H, bytes]``,
+    sorted by row then column: only the nonzero bytes are unpacked."""
+    if not packed.size:
+        z = np.zeros(0, dtype=np.int64)
+        return z, z
+    buf = np.ascontiguousarray(packed).ravel()
+    flat = _nonzero_bytes(buf)
+    k, bit = np.nonzero(np.unpackbits(buf[flat][:, None], axis=1))
+    row, byte = np.divmod(flat[k], packed.shape[1])
+    return row, byte * 8 + bit
+
+
 def slot_hits(
-    cache: LineCache,
-    packed: list[bytes | None],
-    miss_slots: list[int],
+    found: CachedRows,
+    miss_slots: np.ndarray,
     fresh: np.ndarray | None,
 ) -> SlotHits:
-    """Hit columns per unique slot from the cached rows (``packed[s]``
-    where not None) and the readback rows (``fresh[j]`` for slot
-    ``miss_slots[j]``), never unpacked to a dense matrix."""
-    U = len(packed)
+    """Hit columns per unique slot from the cached rows (``found``) and
+    the readback rows (``fresh[j]`` for slot ``miss_slots[j]``), never
+    unpacked to a dense matrix."""
+    U = found.row.size
     start = np.zeros(U, dtype=np.int64)
     count = np.zeros(U, dtype=np.int64)
-    hit_slots = [s for s, p in enumerate(packed) if p is not None]
     groups = []
-    if hit_slots:
-        groups.append(
-            (hit_slots, cache.row_hits([packed[s] for s in hit_slots]))
-        )
-    if fresh is not None and miss_slots:
-        groups.append((miss_slots, bool_hits(fresh)))
+    if found.packed.shape[0]:
+        groups.append((np.flatnonzero(found.row >= 0), packed_hits(found.packed)))
+    if fresh is not None and len(miss_slots):
+        groups.append((np.asarray(miss_slots, dtype=np.int64), bool_hits(fresh)))
     parts: list[np.ndarray] = []
     base = 0
-    for slots, (row, col) in groups:
-        c = np.bincount(row, minlength=len(slots))
-        idx = np.asarray(slots, dtype=np.int64)
+    for idx, (row, col) in groups:
+        c = np.bincount(row, minlength=idx.size)
         count[idx] = c
         start[idx] = base + np.cumsum(c) - c
         parts.append(col)

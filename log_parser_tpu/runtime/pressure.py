@@ -34,8 +34,8 @@ that failure family is decided:
 
 * **Memory** — an RSS watermark (psutil-free, ``/proc/self/statm``)
   composes the levers the earlier PRs built individually — line-cache
-  shrink, interner evict-half, tenant LRU eviction, span staging trim,
-  miner tap close — under one controller: one lever per poll in
+  shrink, tenant LRU eviction, span staging trim, miner tap close —
+  under one controller: one lever per poll in
   severity order while over the watermark, released in reverse once RSS
   clears the watermark by the same hysteresis margin.
 

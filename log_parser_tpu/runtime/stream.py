@@ -75,7 +75,7 @@ from log_parser_tpu.native.ingest import Corpus, StreamNormalizer
 from log_parser_tpu.ops.encode import DEFAULT_MAX_LINE_BYTES, _pad_rows
 from log_parser_tpu.runtime import faults
 from log_parser_tpu.runtime.finalize import finalize_batch
-from log_parser_tpu.runtime.linecache import line_key, records_from_bits
+from log_parser_tpu.runtime.linecache import line_keys, records_from_bits
 from log_parser_tpu.runtime.quarantine import fingerprint as quarantine_fingerprint
 
 DEFAULT_EMIT_THRESHOLD = 0.0
@@ -395,16 +395,16 @@ class StreamSession:
         cache = self.engine.line_cache
         if cache is None:
             return None
-        packed = cache.lookup_packed([line_key(line_bytes)], counts=[1])
-        if packed[0] is None:
+        found = cache.lookup(line_keys([line_bytes]))
+        if found.row[0] < 0:
             return None
-        return cache.unpack([packed[0]])[0]
+        return cache.unpack(found.packed)[0]
 
     def _cache_populate(self, line_bytes: bytes, row: np.ndarray) -> None:
         cache = self.engine.line_cache
         if cache is not None:
-            cache.populate_rows(
-                [line_key(line_bytes)], np.asarray(row, dtype=bool)[None, :]
+            cache.populate(
+                line_keys([line_bytes]), np.asarray(row, dtype=bool)[None, :]
             )
 
     def _chunk_device_step(self, chunk_text: str, batch_idx: list[int]) -> None:

@@ -386,7 +386,7 @@ def main(argv: list[str] | None = None) -> int:
     parser.add_argument(
         "--mem-soft-mb", type=float, default=None, metavar="MB",
         help="RSS soft watermark: over it the memory levers apply one "
-        "per poll in severity order (line-cache shrink, interner evict, "
+        "per poll in severity order (line-cache shrink, "
         "tenant eviction, span staging trim, miner tap close), released "
         "in reverse with hysteresis (0 disables; "
         "LOG_PARSER_TPU_MEM_SOFT_MB)",
@@ -1069,11 +1069,6 @@ def main(argv: list[str] | None = None) -> int:
                 saved_knobs.pop("line_cache_bytes")
             )
 
-    def _lever_interner() -> None:
-        interner = getattr(engine, "key_interner", None)
-        if interner is not None:
-            interner.evict_half()
-
     def _lever_span_staging() -> None:
         spans = engine.obs.spans
         saved_knobs["staging_capacity"] = spans.staging_capacity
@@ -1095,7 +1090,6 @@ def main(argv: list[str] | None = None) -> int:
     pressure_ctl.add_lever(
         "line_cache", _lever_line_cache, _release_line_cache
     )
-    pressure_ctl.add_lever("interner", _lever_interner)
     pressure_ctl.add_lever("tenants", lambda: tenants.shed_idle(0.5))
     pressure_ctl.add_lever(
         "span_staging", _lever_span_staging, _release_span_staging

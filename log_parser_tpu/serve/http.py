@@ -827,11 +827,6 @@ class _Handler(BaseHTTPRequestHandler):
                 # routing-tier hit/residual/eviction counters (docs/OPS.md
                 # "Line cache (routing tier)")
                 payload["lineCache"] = line_cache.stats()
-            interner = getattr(self.server.engine, "key_interner", None)
-            if interner is not None:
-                # two-level keying: probe hits are digests served without
-                # blake2b (docs/OPS.md "Line cache (routing tier)")
-                payload["interner"] = interner.stats()
             kernel_stats = getattr(self.server.engine, "kernel_stats", None)
             if kernel_stats is not None:
                 # Pallas union-DFA kernel tier: admission reason +

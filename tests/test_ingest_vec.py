@@ -30,7 +30,7 @@ from log_parser_tpu.golden.javacompat import java_split_lines
 from log_parser_tpu.native import available
 from log_parser_tpu.native.ingest import Corpus, StreamNormalizer
 from log_parser_tpu.ops.encode import encode_lines
-from log_parser_tpu.runtime.linecache import dedup_slots, line_key
+from log_parser_tpu.runtime.linecache import dedup_slots, line_keys
 
 HOSTILE = [
     "",
@@ -159,8 +159,13 @@ class TestVectorizedVsNative:
             assert native_corpus.line_key_bytes(i) == vec_corpus.line_key_bytes(i)
 
 
+def _key_bytes(keys, s: int) -> bytes:
+    """The content bytes key ``s`` holds (its words up to its length)."""
+    return keys.words[keys.rows[s]].tobytes()[: int(keys.lengths[s])]
+
+
 class TestDedupSlots:
-    """The lexsort keying lane vs the per-line dict loop it replaced."""
+    """The content-keyed dedup lane vs the per-line dict loop it replaced."""
 
     def _reference(self, corpus):
         slot_of: dict[bytes, int] = {}
@@ -174,7 +179,7 @@ class TestDedupSlots:
                 slot_of[lb] = s
                 reps.append(i)
             line_slot.append(s)
-        keys = [line_key(lb) for lb in slot_of]
+        keys = list(slot_of)
         counts = np.bincount(
             np.asarray(line_slot, dtype=np.int64), minlength=len(reps)
         )
@@ -196,7 +201,13 @@ class TestDedupSlots:
             ref_slot, ref_reps, ref_keys, ref_counts = self._reference(corpus)
             assert line_slot.tolist() == ref_slot
             assert reps.tolist() == ref_reps
-            assert keys == ref_keys
+            # a storable key is the line's bytes, with the probe the
+            # bytes alone give; the rest (needs_host) are never stored
+            needs_host = corpus.encoded.needs_host[reps]
+            assert keys.storable.tolist() == (~needs_host).tolist()
+            for s in np.flatnonzero(keys.storable).tolist():
+                assert _key_bytes(keys, s) == ref_keys[s]
+                assert keys.probes[s] == line_keys([ref_keys[s]]).probes[0]
             assert counts.tolist() == ref_counts.tolist()
 
     def test_long_lines_grouped_exactly(self, no_native):
@@ -208,22 +219,30 @@ class TestDedupSlots:
         line_slot, reps, keys, counts = dedup_slots(corpus)
         assert line_slot.tolist() == [0, 1, 0, 1, 0]
         assert counts.tolist() == [3, 2]
-        assert keys[0] == line_key(a.encode())
-        assert keys[1] == line_key(b.encode())
+        # both keys hold the same truncated words and are never stored
+        assert _key_bytes(keys, 0) == _key_bytes(keys, 1)
+        assert not keys.storable.any()
 
     def test_surrogate_corpus_returns_none(self, no_native):
-        assert dedup_slots(Corpus("\ud800x\nok")) is None
+        # lone-surrogate corpora key from their encode like any other:
+        # the surrogate line is needs_host, the clean line storable
+        corpus = Corpus("\ud800x\nok\n\ud800x")
+        line_slot, reps, keys, counts = dedup_slots(corpus)
+        assert line_slot.tolist() == [0, 1, 0]
+        assert keys.storable.tolist() == [False, True]
+        assert _key_bytes(keys, 1) == b"ok"
 
     def test_empty_string_is_one_empty_line(self, no_native):
         # Java split: "" -> [""] — one (empty) line, one slot
         line_slot, reps, keys, counts = dedup_slots(Corpus(""))
         assert line_slot.tolist() == [0]
-        assert keys == [line_key(b"")]
+        assert _key_bytes(keys, 0) == b"" and keys.storable.tolist() == [True]
+        assert keys.probes[0] == line_keys([b""]).probes[0]
 
     def test_zero_line_corpus(self, no_native):
         # "\n" -> ["", ""] -> all trailing empties dropped -> no lines
         line_slot, reps, keys, counts = dedup_slots(Corpus("\n"))
-        assert line_slot.size == 0 and len(keys) == 0
+        assert line_slot.size == 0 and keys.rows.size == 0
 
 
 class TestStreamNormalizerChunkInvariance:

@@ -24,11 +24,12 @@ from log_parser_tpu.models.pod import PodFailureData
 from log_parser_tpu.native.ingest import Corpus, normalize_blob
 from log_parser_tpu.runtime import AnalysisEngine, faults
 from log_parser_tpu.runtime.faults import FaultRegistry
+import log_parser_tpu.runtime.linecache as lc
 from log_parser_tpu.runtime.linecache import (
-    KeyInterner,
     LineCache,
     dedup_slots,
-    line_key,
+    line_keys,
+    word_class,
 )
 from log_parser_tpu.runtime.quarantine import QuarantineTable
 
@@ -116,17 +117,23 @@ def _cached_engine(mb: float = 4.0) -> AnalysisEngine:
 # ------------------------------------------------------------ LRU mechanics
 
 
+def _rows(cache: LineCache, lines: list[bytes]) -> list[np.ndarray | None]:
+    """Each line's cached bool row, or None for a miss."""
+    found = cache.lookup(line_keys(lines))
+    unpacked = cache.unpack(found.packed)
+    return [None if r < 0 else unpacked[r] for r in found.row.tolist()]
+
+
 class TestLineCacheUnit:
     def test_lookup_populate_and_counters(self):
         cache = LineCache(n_columns=10, budget_bytes=1 << 20)
-        k1, k2 = line_key(b"alpha"), line_key(b"beta")
-        assert cache.lookup([k1, k2, k1]) == [None, None, None]
+        assert _rows(cache, [b"alpha", b"beta", b"alpha"]) == [None] * 3
         assert cache.stats()["misses"] == 3
 
         row = np.zeros(10, dtype=bool)
         row[3] = True
-        cache.populate([(k1, row)])
-        got = cache.lookup([k1, k2])
+        cache.populate(line_keys([b"alpha"]), row[None, :])
+        got = _rows(cache, [b"alpha", b"beta"])
         assert got[1] is None
         np.testing.assert_array_equal(got[0], row)
         s = cache.stats()
@@ -134,26 +141,29 @@ class TestLineCacheUnit:
 
     def test_lru_eviction_bounded_by_resident_bytes(self):
         cache = LineCache(n_columns=64, budget_bytes=2000)
-        rows = [(line_key(b"line-%d" % i), np.zeros(64, dtype=bool)) for i in range(100)]
-        cache.populate(rows)
+        lines = [b"line-%d" % i for i in range(100)]
+        for j in range(0, 100, 10):  # ten calls, oldest first
+            cache.populate(
+                line_keys(lines[j : j + 10]), np.zeros((10, 64), dtype=bool)
+            )
         s = cache.stats()
         assert s["evictions"] > 0
         assert s["residentBytes"] <= 2000
         assert s["entries"] < 100
-        # the survivors are the most recently inserted (LRU order)
-        assert cache.lookup([rows[-1][0]])[0] is not None
-        assert cache.lookup([rows[0][0]])[0] is None
+        # the survivors are the most recently stored
+        assert _rows(cache, [lines[-1]])[0] is not None
+        assert _rows(cache, [lines[0]])[0] is None
 
     def test_flush_clears_and_rebinds_columns(self):
         cache = LineCache(n_columns=16, budget_bytes=1 << 20)
-        cache.populate([(line_key(b"x"), np.ones(16, dtype=bool))])
+        cache.populate(line_keys([b"x"]), np.ones((1, 16), dtype=bool))
         cache.flush(n_columns=24)
         s = cache.stats()
         assert s["entries"] == 0
         assert s["residentBytes"] == 0
         assert s["epochFlushes"] == 1
         assert cache.n_columns == 24
-        assert cache.lookup([line_key(b"x")]) == [None]
+        assert _rows(cache, [b"x"]) == [None]
 
 
 # ----------------------------------------------------------- exact parity
@@ -221,7 +231,9 @@ class TestParity:
     def test_empty_and_trivial_logs(self):
         off = AnalysisEngine(_sets(), ScoringConfig())
         on = _cached_engine()
-        for logs in ("", "\n", "INFO only"):
+        # a lone surrogate takes the scalar encode, and keys like any line
+        surrogate = "FATAL \ud800 x\nINFO only\nFATAL \ud800 x\nFATAL ? x"
+        for logs in ("", "\n", "INFO only", surrogate, surrogate):
             assert _events(off.analyze_pipelined(_pod(logs))) == _events(
                 on.analyze_pipelined(_pod(logs))
             )
@@ -319,14 +331,15 @@ class TestInvalidation:
         # simulate a divergent device result resident in the cache:
         # clear the oom primary bit of the cached OOM line
         cache = engine.line_cache
-        key = line_key(b"java.lang.OutOfMemoryError: heap")
+        keys = line_keys([b"java.lang.OutOfMemoryError: heap"])
         oom_pat = [p.id for p in engine.bank.patterns].index("oom")
         oom_col = int(engine.bank.primary_columns[oom_pat])
         with cache.lock:
-            packed = np.frombuffer(cache._entries[key], dtype=np.uint8).copy()
-            row = np.unpackbits(packed, count=cache.n_columns).astype(bool)
-            row[oom_col] = False
-            cache._entries[key] = np.packbits(row).tobytes()
+            table = cache._tables[int(word_class(keys.lengths)[0])]
+            (eid,), _ = table.find(keys, np.arange(1))
+            row = np.unpackbits(table.packed[eid], count=cache.n_columns)
+            row[oom_col] = 0
+            table.packed[eid] = np.packbits(row)
 
         # corrupted bits ARE served (proves the hit path is live)
         broken = _events(engine.analyze_pipelined(_pod(logs)))
@@ -443,12 +456,10 @@ class TestKeyStability:
 
         keys = []
         for decoded in (http_logs, grpc_logs, framed_logs):
-            corpus = Corpus(decoded)
+            _, _, k, _ = dedup_slots(Corpus(decoded))
             keys.append(
-                [
-                    line_key(corpus.line_key_bytes(i))
-                    for i in range(corpus.n_lines)
-                ]
+                (k.words[k.rows].tolist(), k.lengths.tolist(),
+                 k.probes.tolist(), k.storable.tolist())
             )
         assert keys[0] == keys[1] == keys[2]
 
@@ -505,89 +516,350 @@ def test_concurrent_cached_requests_thread_safe():
     assert _freq_counts(engine) == _freq_counts(serial)
 
 
-# ------------------------------------------- two-level keying (interner)
+# ------------------------------------------------ content-addressed table
 
 
-class TestKeyInterner:
-    """dedup_slots with an interner must return digests bit-identical to
-    the blake2b path — cold, warm, across corpus shapes, past the
-    512-byte interning ceiling, and through eviction."""
+def _bits_of(line: bytes, n_columns: int = 24) -> np.ndarray:
+    """A stand-in device row: a pure function of the line's bytes."""
+    rng = np.random.default_rng(list(line) + [len(line)])
+    return rng.random(n_columns) < 0.3
 
-    def _parity(self, corpus, interner):
-        ref = dedup_slots(corpus)
-        got = dedup_slots(corpus, interner=interner)
-        assert ref is not None and got is not None
-        np.testing.assert_array_equal(ref[0], got[0])
-        np.testing.assert_array_equal(ref[1], got[1])
-        assert ref[2] == got[2]
-        np.testing.assert_array_equal(ref[3], got[3])
 
-    def test_cold_and_warm_parity(self):
+def _dict_loop(corpus):
+    """The scalar reference: slots by first appearance, keyed by bytes."""
+    slot_of: dict[bytes, int] = {}
+    line_slot, reps = [], []
+    for i in range(corpus.n_lines):
+        lb = corpus.line_key_bytes(i)
+        if lb not in slot_of:
+            slot_of[lb] = len(reps)
+            reps.append(i)
+        line_slot.append(slot_of[lb])
+    return line_slot, reps
+
+
+class TestContentKeys:
+    """The table keyed by content words against a plain dict keyed by the
+    content bytes: same hits, same rows, through collisions, widths and
+    eviction."""
+
+    def _serve(self, cache, ref, lines, n_columns=24):
+        """One request through dedup → lookup → populate, checked against
+        ``ref`` (bytes → row of every line stored so far)."""
+        corpus = Corpus("\n".join(lines))
+        line_slot, reps, keys, counts = dedup_slots(corpus)
+        found = cache.lookup(keys, counts)
+        rows = cache.unpack(found.packed)
+        for s, r in enumerate(found.row.tolist()):
+            lb = corpus.line_key_bytes(int(reps[s]))
+            if r >= 0:
+                assert keys.storable[s]
+                np.testing.assert_array_equal(rows[r], ref[lb])
+        miss = np.flatnonzero(found.row < 0)
+        fresh = np.stack(
+            [_bits_of(corpus.line_key_bytes(int(reps[s])), n_columns)
+             for s in miss]
+        ) if miss.size else np.zeros((0, n_columns), dtype=bool)
+        cache.populate(keys.take(miss), fresh)
+        for j, s in enumerate(miss.tolist()):
+            if keys.storable[s]:
+                ref[corpus.line_key_bytes(int(reps[s]))] = fresh[j]
+        return found, keys
+
+    def test_cold_and_warm_parity_with_bytes_dict(self):
         lines = [
             REPEAT_TEMPLATES[(i * 5) % len(REPEAT_TEMPLATES)]
             for i in range(200)
         ] + [f"novel line {i}" for i in range(40)]
+        cache, ref = LineCache(24, 1 << 20), {}
+        cold, _ = self._serve(cache, ref, lines)
+        assert (cold.row < 0).all()
+        warm, keys = self._serve(cache, ref, lines)
+        assert (warm.row >= 0).all()
+        assert cache.stats()["entries"] == len(ref) == keys.rows.size
+        # another corpus shape (a wider batch) hits the same entries
+        wide, _ = self._serve(cache, ref, lines + ["x" * 200])
+        assert (wide.row >= 0).sum() == keys.rows.size
+        assert cache.stats()["probeCollisions"] == 0
+
+    def test_forced_probe_collision_stays_exact_and_counted(self, monkeypatch):
+        monkeypatch.setattr(
+            lc, "probe64", lambda words, lengths: np.full(
+                words.shape[0], 7, dtype=np.uint64)
+        )
+        lines = ["alpha", "beta", "alpha", "gamma", "beta", "alphb"]
         corpus = Corpus("\n".join(lines))
-        interner = KeyInterner()
-        self._parity(corpus, interner)  # cold: every unique line inserts
-        cold = interner.stats()
-        assert cold["inserts"] > 0 and cold["collisions"] == 0
-        self._parity(corpus, interner)  # warm: pure probe hits
-        warm = interner.stats()
-        assert warm["inserts"] == cold["inserts"]
-        assert warm["probeHits"] >= cold["inserts"]
-        # a different corpus shape (other width bucket) stays exact
-        self._parity(Corpus("\n".join(lines + ["x" * 200])), interner)
+        line_slot, reps, keys, counts = dedup_slots(corpus)
+        ref_slot, ref_reps = _dict_loop(corpus)
+        assert line_slot.tolist() == ref_slot
+        assert reps.tolist() == ref_reps
+        cache, ref = LineCache(24, 1 << 20), {}
+        self._serve(cache, ref, lines)
+        # one entry a probe: the first line is stored, the rest collide
+        assert cache.stats()["entries"] == 1
+        assert cache.stats()["probeCollisions"] == 3
+        found, _ = self._serve(cache, ref, lines)
+        assert (found.row >= 0).sum() == 1
+        assert cache.stats()["probeCollisions"] > 3
 
-    def test_long_lines_stay_on_blake2b(self):
-        long = "L" + "x" * 600  # past the 64-word interning ceiling
-        corpus = Corpus("\n".join(["short line", long, "short line", long]))
-        interner = KeyInterner()
-        self._parity(corpus, interner)
-        self._parity(corpus, interner)
-        # the long line is never interned — it pays blake2b every pass
-        assert interner.stats()["entries"] <= 1
+    def test_same_line_hits_at_widths_64_and_128(self):
+        line = "java.lang.OutOfMemoryError: heap"
+        narrow = Corpus("\n".join([line, "y" * 60]))
+        wide = Corpus("\n".join([line, "y" * 120]))
+        assert narrow.encoded.u8.shape[1] == 64
+        assert wide.encoded.u8.shape[1] == 128
+        cache = LineCache(24, 1 << 20)
+        _, reps, keys, counts = dedup_slots(narrow)
+        cache.populate(keys, np.stack([_bits_of(b"%d" % i) for i in range(2)]))
+        _, _, wkeys, wcounts = dedup_slots(wide)
+        assert wkeys.probes[0] == keys.probes[0]
+        found = cache.lookup(wkeys, wcounts)
+        assert found.row.tolist() == [0, -1]
+        np.testing.assert_array_equal(
+            cache.unpack(found.packed)[0], _bits_of(b"0")
+        )
+        # and a key built from the bytes alone (the stream's) hits too
+        assert cache.lookup(line_keys([line.encode()])).row.tolist() == [0]
 
-    def test_truncated_rows_never_intern(self):
-        """Regression: under a narrow device width (< the 512-byte
-        interning ceiling), rows longer than the width are TRUNCATED in
-        the key matrix. Two distinct long lines sharing a width prefix
-        and a byte length must not share a digest — the second warm
-        pass used to probe-hit the first line's entry and serve its
-        blake2b key (and therefore its cached match bits)."""
+    def test_over_long_and_needs_host_lines_never_stored(self):
         shorts = [f"short {i:04d}" for i in range(600)]
         prefix = "P" * 120
-        a = prefix + "A" * 40
-        b = prefix + "B" * 40  # differs only past the device width
-        corpus = Corpus("\n".join(shorts + [a, b]))
-        width = corpus.encoded.u8.shape[1]
-        assert width < len(a), "corpus must exercise the truncated branch"
-        interner = KeyInterner()
-        self._parity(corpus, interner)  # cold: both pay blake2b
-        self._parity(corpus, interner)  # warm: B must NOT reuse A's key
-        keys = dedup_slots(corpus, interner=interner)[2]
-        assert keys[-1] != keys[-2]
-        assert keys[-2] == line_key(a.encode())
-        assert keys[-1] == line_key(b.encode())
+        a, b = prefix + "A" * 40, prefix + "B" * 40  # differ past the width
+        lines = shorts + [a, b, "café ☃", a]
+        corpus = Corpus("\n".join(lines))
+        assert corpus.encoded.u8.shape[1] < len(a)
+        line_slot, reps, keys, counts = dedup_slots(corpus)
+        assert line_slot.tolist() == _dict_loop(corpus)[0]
+        tail = keys.take(np.arange(600, 603))
+        assert not tail.storable.any()
+        cache = LineCache(24, 1 << 20)
+        cache.populate(tail, np.ones((3, 24), dtype=bool))
+        assert cache.stats()["entries"] == 0
+        found = cache.lookup(tail, counts[600:603])
+        assert (found.row < 0).all()
+        assert cache.stats()["misses"] == 4  # line-weighted: a twice
 
-    def test_eviction_keeps_parity(self):
-        # a budget of ~100 entries against 300 unique lines: every pass
-        # evicts, digests stay exact throughout
-        from log_parser_tpu.runtime.linecache import _INTERN_ENTRY_BYTES
+    def test_eviction_keeps_parity_and_the_current_call(self):
+        # a budget of 100 entries against 60 unique lines a call
+        cache, ref = LineCache(24, 100 * (2 * 8 + 4 + 3 + 8 + 16)), {}
+        for r in range(4):
+            lines = [f"round {r} line {i}" for i in range(60)]
+            found, keys = self._serve(cache, ref, lines + lines[:30])
+            # every row this call stored is still there
+            again = cache.lookup(keys)
+            assert (again.row >= 0).all()
+            s = cache.stats()
+            assert s["residentBytes"] <= cache.budget_bytes
+        assert cache.stats()["evictions"] > 0
+        # an old round's lines miss, and nothing served was ever wrong
+        old, _ = self._serve(cache, ref, [f"round 0 line {i}" for i in range(60)])
+        assert (old.row < 0).any()
 
-        interner = KeyInterner(budget_bytes=100 * _INTERN_ENTRY_BYTES)
-        for r in range(3):
-            lines = [f"round {r} line {i}" for i in range(300)]
-            self._parity(Corpus("\n".join(lines)), interner)
-        s = interner.stats()
-        assert s["evictions"] > 0
-        assert s["entries"] <= interner.max_entries
+    def test_eviction_never_drops_what_the_call_touched(self):
+        # room for four entries; the second call touches L1 and brings
+        # four new lines: L2-L4 go, L1 stays, and three new lines fit
+        eb = 2 * 8 + 4 + 3 + 8 + 16
+        cache = LineCache(24, 4 * eb)
+        old = [b"line %d" % i for i in range(1, 5)]
+        cache.populate(line_keys(old), np.stack([_bits_of(b) for b in old]))
+        new = [b"new %d" % i for i in range(4)]
+        batch = old[:1] + new
+        cache.populate(line_keys(batch), np.stack([_bits_of(b) for b in batch]))
+        assert _rows(cache, old)[1:] == [None] * 3
+        np.testing.assert_array_equal(_rows(cache, old[:1])[0], _bits_of(old[0]))
+        s = cache.stats()
+        assert s["entries"] == 4 and s["residentBytes"] <= 4 * eb
 
-    def test_engine_cache_path_uses_interner(self):
+    def test_eviction_cost_follows_entries_not_calls(self):
+        # a server that ran ~1e9 calls before its first eviction: evicting
+        # (on a populate, then on a budget shrink) allocates by the
+        # entries, not by the calls since the oldest stamp
+        import tracemalloc
+
+        eb = 2 * 8 + 4 + 3 + 8 + 16
+        cache = LineCache(24, 50 * eb)
+        old = [b"old line %d" % i for i in range(40)]
+        new = [b"new line %d" % i for i in range(30)]
+
+        def store(lines):
+            cache.populate(line_keys(lines), np.stack([_bits_of(b) for b in lines]))
+
+        store(old)
+        tracemalloc.start()
+        try:
+            cache._gen = 10**9
+            store(new)
+            cache._gen = 2 * 10**9
+            cache.set_budget(30 * eb)
+            peak = tracemalloc.get_traced_memory()[1]
+        finally:
+            tracemalloc.stop()
+        assert peak < 1 << 20
+        assert cache.stats()["residentBytes"] <= 30 * eb
+        for line, row in zip(new, _rows(cache, new)):
+            np.testing.assert_array_equal(row, _bits_of(line))
+        assert all(r is None for r in _rows(cache, old))
+
+    def test_eviction_takes_the_oldest_entries_of_every_class(self):
+        # calls of one- and two-word lines under a full budget: each call
+        # evicts the oldest calls' lines, whatever their class, and every
+        # table closed up or grown keeps its rows and its index
+        cache = LineCache(24, 200 * (2 * 8 + 4 + 3 + 8 + 16))
+        calls = []
+        for r in range(12):
+            lines = [b"tick %03d %04d" % (r, i) for i in range(40 + 7 * (r % 3))]
+            lines += [b"p%02d%03d" % (r, i) for i in range(5 + 9 * (r % 2))]
+            cache.populate(line_keys(lines),
+                           np.stack([_bits_of(b) for b in lines]))
+            calls.append(lines)
+            s = cache.stats()
+            assert s["residentBytes"] <= cache.budget_bytes
+            assert s["residentBytes"] == sum(
+                t.cap * t.entry_bytes for t in cache._tables.values())
+        kept = [[r is not None for r in _rows(cache, lines)] for lines in calls]
+        # whole calls survive from the newest back, the oldest not at all
+        whole = [all(k) for k in kept]
+        assert whole[-1] and not any(kept[0])
+        assert whole == sorted(whole)
+        assert sum(any(k) and not all(k) for k in kept) <= 1
+        for lines in calls[-3:]:
+            for line, row in zip(lines, _rows(cache, lines)):
+                np.testing.assert_array_equal(row, _bits_of(line))
+
+    def test_small_calls_share_one_eviction_of_a_sixteenth(self):
+        eb = 2 * 8 + 4 + 3 + 8 + 16
+        cache = LineCache(24, 160 * eb)
+
+        def store(lines):
+            cache.populate(line_keys(lines), np.stack([_bits_of(b) for b in lines]))
+
+        for r in range(4):
+            store([b"fill %d %03d" % (r, i) for i in range(40)])
+        assert cache.stats()["evictions"] == 0
+        # two lines need 94 bytes; the first such call evicts 470 (ten
+        # entries) and the next four calls store into what it freed
+        store([b"small 0 %d" % i for i in range(2)])
+        assert cache.stats()["evictions"] == 10
+        for r in range(1, 5):
+            store([b"small %d %d" % (r, i) for i in range(2)])
+        s = cache.stats()
+        assert s["evictions"] == 10 and s["entries"] == 160
+        gone = [sum(r is None for r in _rows(cache, [b"fill %d %03d" % (r, i)
+                                                    for i in range(40)]))
+                for r in range(4)]
+        assert gone == [10, 0, 0, 0]  # of the oldest call
+
+    def test_a_new_class_takes_the_other_classes_oldest_bytes(self):
+        eb2 = 2 * 8 + 4 + 3 + 8 + 16
+        cache = LineCache(24, 200 * eb2)
+        calls = [[b"tick %02d %04d" % (r, i) for i in range(n)]
+                 for r, n in enumerate((100, 60, 40))]
+        for lines in calls:
+            cache.populate(line_keys(lines),
+                           np.stack([_bits_of(b) for b in lines]))
+        short = [b"s%d" % i for i in range(10)]  # the first one-word lines
+        cache.populate(line_keys(short), np.stack([_bits_of(b) for b in short]))
+        t1 = cache._tables[1]
+        assert t1.live == t1.cap == 10
+        assert cache.stats()["residentBytes"] <= cache.budget_bytes
+        # the bytes came from the oldest call's lines, and from no other
+        gone = [sum(r is None for r in _rows(cache, lines)) for lines in calls]
+        assert gone[0] > 0 and gone[1:] == [0, 0]
+        for line, row in zip(short, _rows(cache, short)):
+            np.testing.assert_array_equal(row, _bits_of(line))
+
+    def test_populate_touches_the_lines_its_request_hit(self):
+        # room for ten entries. Request 2 hits F, then another request
+        # stores five lines before request 2 stores its one miss: F is in
+        # use until request 2 is done, so the next five rows evict the
+        # other request's lines before F.
+        cache = LineCache(24, 10 * (8 + 4 + 3 + 8 + 16))
+
+        def store(lines, found=None):
+            cache.populate(line_keys(lines),
+                           np.stack([_bits_of(b) for b in lines]), found)
+
+        store([b"F", b"a1", b"a2", b"a3", b"a4"])
+        found = cache.lookup(line_keys([b"F", b"r2"]))
+        store([b"x%d" % i for i in range(5)])
+        store([b"r2"], found)
+        store([b"y%d" % i for i in range(5)])
+        np.testing.assert_array_equal(_rows(cache, [b"F"])[0], _bits_of(b"F"))
+        others = _rows(cache, [b"x%d" % i for i in range(5)])
+        assert sum(r is None for r in others) == 2
+
+    def test_slot_order_by_first_appearance_matches_dict_loop(self, monkeypatch):
+        # a 2-value fold makes every run of equal probes hold different
+        # lines: the exact regroup must still number slots as the dict does
+        real = lc.probe64
+        monkeypatch.setattr(
+            lc, "probe64", lambda words, lengths: real(words, lengths)
+            & np.uint64(1)
+        )
+        import random
+
+        rng = random.Random(11)
+        pool = [f"err {i}" for i in range(12)] + ["", "a" * 64, "a" * 65]
+        for _ in range(40):
+            lines = [rng.choice(pool) for _ in range(rng.randrange(1, 50))]
+            corpus = Corpus("\n".join(lines))
+            line_slot, reps, keys, counts = dedup_slots(corpus)
+            ref_slot, ref_reps = _dict_loop(corpus)
+            assert line_slot.tolist() == ref_slot
+            assert reps.tolist() == ref_reps
+            assert counts.tolist() == np.bincount(ref_slot).tolist()
+
+    def test_threads_sharing_one_table_never_serve_a_wrong_row(self):
+        """Lookups, populates and evictions from more threads than cores
+        under a short switch interval: every hit is the row computed for
+        that line's bytes, and the budget holds."""
+        import sys
+
+        cache = LineCache(24, 40 * (4 * 8 + 4 + 3 + 8 + 16))
+        lines = [b"shared line %d" % i for i in range(30)]
+        bad: list = []
+
+        def worker(w):
+            for r in range(30):
+                batch = lines[(w + r) % 10 :][:12] + [b"own %d %d" % (w, r)]
+                keys = line_keys(batch)
+                found = cache.lookup(keys)
+                rows = cache.unpack(found.packed)
+                for j, at in enumerate(found.row.tolist()):
+                    if at >= 0 and not (rows[at] == _bits_of(batch[j])).all():
+                        bad.append(batch[j])
+                miss = np.flatnonzero(found.row < 0)
+                cache.populate(
+                    keys.take(miss),
+                    np.stack([_bits_of(batch[j]) for j in miss])
+                    if miss.size else np.zeros((0, 24), dtype=bool),
+                )
+
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            threads = [threading.Thread(target=worker, args=(w,))
+                       for w in range(16)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(60.0)
+            assert not any(t.is_alive() for t in threads)
+        finally:
+            sys.setswitchinterval(old)
+        assert bad == []
+        s = cache.stats()
+        assert s["residentBytes"] <= cache.budget_bytes
+        assert s["evictions"] > 0 and s["hits"] > 0
+
+    def test_engine_cache_path_counts_no_collisions(self):
         engine = _cached_engine()
         data = _pod("\n".join(REPEAT_TEMPLATES))
         engine.analyze_pipelined(data)
+        assert "cache.populate" in engine.last_trace.stage_dict()
         engine.analyze_pipelined(data)
-        s = engine.key_interner.stats()
-        assert s["inserts"] > 0
-        assert s["probeHits"] > 0
+        assert "cache.populate" not in engine.last_trace.stage_dict()
+        s = engine.line_cache.stats()
+        assert s["entries"] == len(REPEAT_TEMPLATES)
+        assert s["hits"] == len(REPEAT_TEMPLATES)
+        assert s["probeCollisions"] == 0

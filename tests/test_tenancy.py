@@ -11,8 +11,7 @@ for requests larger than the bucket's whole capacity), the resolve
 lease (a pinned context is eviction-proof from resolution to the
 transport's release), tenant-scoped hot reload that provably never quiesces another
 tenant's engine, LRU eviction/rebuild under a bank budget, id
-validation, and the two-level line-cache keying parity pin
-(KeyInterner ≡ blake2b digests).
+validation, and per-tenant line-cache isolation.
 """
 
 from __future__ import annotations

@@ -4,7 +4,8 @@ phases.
 
 With the routing tier short-circuiting the match cube, a repeat-heavy
 request's cost is host-side: ingest (blob → padded u8 batch), keying
-(line → unique slot + digest), extraction (bits → MatchRecords),
+(line → unique slot + content key; the cache's dedup, lookup and
+populate, each timed a request), extraction (bits → MatchRecords),
 assembly (unique rows → per-line bit matrix + override splice), and
 finalize (records → scores + factor rows). Each phase is timed both as
 the scalar reference path and (where one exists) the vectorized lane
@@ -75,10 +76,10 @@ def main() -> None:
     from log_parser_tpu.runtime import AnalysisEngine
     from log_parser_tpu.runtime.finalize import finalize_batch
     from log_parser_tpu.runtime.linecache import (
-        KeyInterner,
+        DEFAULT_LINE_CACHE_MB,
+        LineCache,
         SlotHits,
         dedup_slots,
-        line_key,
         records_from_hits,
         request_hits,
     )
@@ -112,7 +113,7 @@ def main() -> None:
     enc = corpus.encoded
     report["batch_rows"], report["batch_cols"] = (int(x) for x in enc.u8.shape)
 
-    # ---- keying: per-line dict loop vs lexsort dedup ---------------------
+    # ---- keying: per-line dict loop vs the content-keyed dedup ---------
     def key_scalar():
         slot_of: dict[bytes, int] = {}
         line_slot = np.empty(corpus.n_lines, dtype=np.int64)
@@ -123,62 +124,55 @@ def main() -> None:
                 s = len(slot_of)
                 slot_of[lb] = s
             line_slot[i] = s
-        return [line_key(lb) for lb in slot_of], line_slot
+        return list(slot_of), line_slot
 
     t_min, _ = timeit(key_scalar, n=args.repeats)
     report["key_scalar_s"] = round(t_min, 4)
     t_min, _ = timeit(lambda: dedup_slots(corpus), n=args.repeats)
     report["key_vec_s"] = round(t_min, 4)
-    # two-level keying: warm interner turns the per-unique-line blake2b
-    # into a vectorized probe64 + memcmp verify (first touch paid once in
-    # the warmup pass), the serving shape for repeat-heavy traffic
-    interner = KeyInterner()
-    dedup_slots(corpus, interner=interner)  # first touch: populate
-    t_min, _ = timeit(
-        lambda: dedup_slots(corpus, interner=interner), n=args.repeats
-    )
-    report["key_vec_interned_s"] = round(t_min, 4)
-    report["interner"] = interner.stats()
     line_slot, rep_lines, keys, counts = dedup_slots(corpus)
-    report["unique_lines"] = len(keys)
+    report["unique_lines"] = int(rep_lines.size)
 
-    # the digest sub-phase in isolation (the part the interner replaces;
-    # the lexsort dedup above it is shared by both lanes): per-unique
-    # blake2b vs warm probe64+verify digest recovery
-    kv = corpus.key_view()
-    blob, starts, ends = kv
-    nl = corpus.n_lines
-    starts, ends = starts[:nl], ends[:nl]
-    width = corpus.encoded.u8.shape[1]
-    lengths = (ends - starts).astype(np.int64)
-    kw = -(-(width + 8) // 8) * 8
-    km = np.zeros((nl, kw), dtype=np.uint8)
-    km[:, :width] = corpus.encoded.u8[:nl]
-    km[:, width : width + 8] = (
-        lengths.astype("<i8").reshape(nl, 1).view(np.uint8)
-    )
-    v64 = km.view("<i8")
-    s_l = starts[rep_lines].tolist()
-    e_l = ends[rep_lines].tolist()
-    t_min, _ = timeit(
-        lambda: [line_key(blob[a:b]) for a, b in zip(s_l, e_l)],
-        n=args.repeats,
-    )
-    report["digest_blake2b_s"] = round(t_min, 4)
-    t_min, _ = timeit(
-        lambda: interner.digests(
-            v64[rep_lines], lengths[rep_lines], width, blob, s_l, e_l
-        ),
-        n=args.repeats,
-    )
-    report["digest_interned_s"] = round(t_min, 4)
-
-    # ---- extract + assemble: the cache-hit serving path ------------------
     sets = load_builtin_pattern_sets()
     engine = AnalysisEngine(sets, ScoringConfig())
     report["patterns"] = sum(len(s.patterns or []) for s in sets)
+
+    # ---- the cached path's three keying steps, request by request: a
+    # stream of distinct requests of this shape through one cache at the
+    # serving budget, so lookups miss as served traffic's do and
+    # populate evicts once the budget fills. The first two requests warm
+    # the cache up; the rest are timed.
+    cache = LineCache(
+        engine.bank.n_columns, int(DEFAULT_LINE_CACHE_MB * 2**20)
+    )
+    steps: dict[str, list[float]] = {"dedup": [], "lookup": [], "populate": []}
+    for r in range(args.repeats + 2):
+        req = ingest_mod.Corpus(bench_common.repeat_corpus(
+            args.lines, args.repeat_ratio or 0.0, f"prof{r}",
+            random.Random(r),
+        ))
+        t0 = time.perf_counter()
+        _, _, rkeys, rcounts = dedup_slots(req)
+        t1 = time.perf_counter()
+        found = cache.lookup(rkeys, rcounts)
+        miss = np.flatnonzero(found.row < 0)
+        t2 = time.perf_counter()
+        cache.populate(
+            rkeys.take(miss),
+            np.zeros((miss.size, engine.bank.n_columns), dtype=bool),
+        )
+        t3 = time.perf_counter()
+        if r >= 2:
+            steps["dedup"].append(t1 - t0)
+            steps["lookup"].append(t2 - t1)
+            steps["populate"].append(t3 - t2)
+    for name, ts in steps.items():
+        report[f"key_{name}_s"] = round(statistics.median(ts), 4)
+    report["line_cache"] = cache.stats()
+
+    # ---- extract + assemble: the cache-hit serving path ------------------
     n = corpus.n_lines
-    U = len(keys)
+    U = int(rep_lines.size)
     # the post-cache unique slots with no hits, as the cached path would
     # hold them for an all-miss line set (the sparse extract's cost
     # follows the hits; these times are its floor)
@@ -232,9 +226,6 @@ def main() -> None:
     )
     report["host_total_vec_s"] = round(
         report["ingest_vec_s"] + report["key_vec_s"], 4
-    )
-    report["host_total_interned_s"] = round(
-        report["ingest_vec_s"] + report["key_vec_interned_s"], 4
     )
     print(json.dumps(report))
 
