@@ -20,10 +20,10 @@ One chip:
    1e-6. ``GET /trace/last`` must show no fallback and no host-routed
    request, and the native library must have loaded.
 2. kernels: the same requests through engines with the Pallas kernels
-   on (``LOG_PARSER_TPU_PALLAS=1``, ``LOG_PARSER_TPU_PALLAS_DFA=1``). On
-   a TPU the builtin bank's dense columns ride the bit tier, so the
-   bitglush kernel runs in the served layout. The union tier only packs
-   groups with the bit tier off, a layout no TPU deployment builds and
+   on (``LOG_PARSER_TPU_PALLAS=1``, ``LOG_PARSER_TPU_PALLAS_DFA=1``). The
+   builtin bank's dense columns ride the bit tier, so the bitglush
+   kernel runs in the served layout. The union tier only packs
+   groups with the bit tier off, a layout no deployment builds and
    ``serve`` cannot reach: the union-DFA kernel is proved in a second
    engine whose matchers are swapped for ``bitglush_max_words=0`` before
    its fused program exists, and its output lines say ``layout=off-path``.
@@ -55,11 +55,9 @@ import urllib.request
 
 REPO = os.path.dirname(os.path.abspath(__file__))
 PATTERN_DIR = os.path.join(REPO, "log_parser_tpu", "patterns", "builtin")
-# what jax.devices()[0].platform must be, and the kernels that must have
-# run in the kernel phase: a CPU rehearsal (tests/test_chip_smoke.py)
-# steers both — on the CPU the tier policy leaves the bit tier empty
+# what jax.devices()[0].platform must be: a CPU rehearsal
+# (tests/test_chip_smoke.py) steers it
 REQUIRED_PLATFORM = "tpu"
-EXPECTED_KERNELS = ("bitglush", "union_dfa")
 SCORE_TOL = 1e-6
 CONFIG2_LINES = 10_000
 SEEDED_LINES = 200_000
@@ -328,8 +326,8 @@ def one_chip(args, compile_clock) -> None:
     say(f"served: compile_s={compile_clock.seconds - c0!r} "
         f"lineCache={json.dumps(trace.get('lineCache'))}")
 
-    # phase 2: the Pallas kernels — bitglush where the TPU tier policy
-    # puts the builtin bank's columns, union-DFA in an off-path layout
+    # phase 2: the Pallas kernels — bitglush where the tier plan puts
+    # the builtin bank's columns, union-DFA in an off-path layout
     traced = {"bitglush": 0}
     real = bitglush_pallas.bitglush_hits_pallas
 
@@ -339,33 +337,32 @@ def one_chip(args, compile_clock) -> None:
 
     bitglush_pallas.bitglush_hits_pallas = counting
     try:
-        if "bitglush" in EXPECTED_KERNELS:
-            c0 = compile_clock.seconds
-            eng = build_engine(sets, pallas=True)
-            check(eng.matchers.bitglush_use_pallas,
-                  "bitglush kernel not admitted: no bit tier")
-            got, _ = serve_requests("kernel-bitglush", eng, requests, want)
-            for (name, _), a, b in zip(requests, got, bodies):
-                compare(f"kernel-bitglush/{name} vs served", a, b)
-            check(traced["bitglush"] > 0, "bitglush kernel never traced")
-            say(f"kernel-bitglush: traced={traced['bitglush']} "
-                f"words={eng.matchers.bitglush.n_words} "
-                f"compile_s={compile_clock.seconds - c0!r}")
-        if "union_dfa" in EXPECTED_KERNELS:
-            c0 = compile_clock.seconds
-            eng = build_engine(sets, pallas=True, bit_words=0)
-            got, trace = serve_requests("kernel-union-dfa", eng, requests,
-                                        want)
-            for (name, _), a, b in zip(requests, got, bodies):
-                compare(f"kernel-union-dfa/{name} vs served", a, b)
-            k = trace["kernel"]
-            check(k["reason"] in ADMITTED, f"union-DFA kernel: {k['reason']}")
-            check(k["kernelBatches"] > 0, f"union-DFA kernel never ran: {k}")
-            say(f"kernel-union-dfa: layout=off-path reason={k['reason']} "
-                f"kernelBatches={k['kernelBatches']} "
-                f"kernelRows={k['kernelRows']} xlaBatches={k['xlaBatches']} "
-                f"geometry={json.dumps(k['geometry'])} "
-                f"compile_s={compile_clock.seconds - c0!r}")
+        c0 = compile_clock.seconds
+        eng = build_engine(sets, pallas=True)
+        check(eng.matchers.bitglush_use_pallas,
+              "bitglush kernel not admitted: no bit tier")
+        got, _ = serve_requests("kernel-bitglush", eng, requests, want)
+        for (name, _), a, b in zip(requests, got, bodies):
+            compare(f"kernel-bitglush/{name} vs served", a, b)
+        check(traced["bitglush"] > 0, "bitglush kernel never traced")
+        say(f"kernel-bitglush: traced={traced['bitglush']} "
+            f"words={eng.matchers.bitglush.n_words} "
+            f"compile_s={compile_clock.seconds - c0!r}")
+
+        c0 = compile_clock.seconds
+        eng = build_engine(sets, pallas=True, bit_words=0)
+        got, trace = serve_requests("kernel-union-dfa", eng, requests,
+                                    want)
+        for (name, _), a, b in zip(requests, got, bodies):
+            compare(f"kernel-union-dfa/{name} vs served", a, b)
+        k = trace["kernel"]
+        check(k["reason"] in ADMITTED, f"union-DFA kernel: {k['reason']}")
+        check(k["kernelBatches"] > 0, f"union-DFA kernel never ran: {k}")
+        say(f"kernel-union-dfa: layout=off-path reason={k['reason']} "
+            f"kernelBatches={k['kernelBatches']} "
+            f"kernelRows={k['kernelRows']} xlaBatches={k['xlaBatches']} "
+            f"geometry={json.dumps(k['geometry'])} "
+            f"compile_s={compile_clock.seconds - c0!r}")
     finally:
         bitglush_pallas.bitglush_hits_pallas = real
     say(f"compileCache: {json.dumps(xlacache.stats())}")

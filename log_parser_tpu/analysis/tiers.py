@@ -18,11 +18,11 @@ the exception's own ``code`` attribute, so the prediction and an actual
 build failure can never disagree on the reason — they are the same
 object. ``bit_capable`` is the orthogonal capability bit for the
 gather-free bit-parallel engine (ops/match.py admits bit programs per
-platform/word budget; capability here is the platform-independent part:
-the program compiles and fits the column position cap).
+word budget; capability here is the budget-independent part: the
+program compiles and fits the column position cap).
 
 The classifier is deliberately *capability*-level: MatcherBanks picks
-the executed tier per bank size and platform (e.g. Shift-Or only beyond
+the executed tier per bank size (e.g. Shift-Or only beyond
 ``shiftor_min_columns``), but artifacts are what the build produces and
 what the parity test (tests/test_patlint.py) pins column-for-column.
 """

@@ -282,8 +282,7 @@ class BitGlushBank:
         # boundary (no-assert ∪ \b positions), combo 0 is not (no-assert
         # ∪ \B); combos 2/1 and 3/0 are the same sets mirrored.
         # (Pair-composed and byte-class table variants of this stepper
-        # were measured SLOWER and deleted — ops/shiftor.py docstring,
-        # tools/probe_paircompose.py.)
+        # were measured SLOWER and deleted — ops/shiftor.py docstring.)
         self.allow_bc = jnp.asarray(allow4[1])  # boundary present
         self.allow_nb = jnp.asarray(allow4[0])  # no boundary
         # fused start injection: one [W] select on the scalar ``pos == 0``

@@ -204,7 +204,7 @@ class MultiDfaBank:
     (``[S, 256]`` — at most 8 MB under the 8192-state budget), whose
     packed words carry a "this state can report a match" flag in bit 30 —
     cost independent of R, vs the dense tier's ``[B, R]`` gather (measured
-    ~150ms/regex/200k lines on TPU v5e, PERF.md; per-element random
+    ~150ms/regex/200k lines on TPU v5e; per-element random
     gathers run on the scalar unit, so eliminating the separate
     byte→class gather halves the tier's hot cost). Exact per-pattern hit
     words are recovered after the scan by re-scanning ONLY the flagged
@@ -278,9 +278,9 @@ class MultiDfaBank:
         """(device buffer, base offset) of this group's byte-precomposed
         transition table, uploading it standalone on first use.  Never
         caches under an active jit trace (jnp.asarray would yield a
-        trace-local constant whose escape poisons every later call) —
-        MatcherBanks pre-uploads eagerly on the no-cluster path so the
-        guard is a backstop, not the common case."""
+        trace-local constant whose escape poisons every later call).
+        Inside MatcherBanks every group reads its cluster's buffer
+        (``_adopt_table``); standalone groups upload here."""
         if self._flat is None:
             arr = jnp.asarray(self._packed_byte_np)
             if isinstance(arr, jax.core.Tracer):
@@ -482,51 +482,30 @@ TIER_SCOPES = {
 class MatcherBanks:
     """Tiered device matchers for one PatternBank's columns.
 
-    Tier selection is static per column (patterns/bank.py) and
-    PLATFORM-DEPENDENT: on TPU, literal-shaped
-    regexes go to the bit-parallel Shift-Or bank (cost independent of bank
-    size), while on CPU hosts they ride the union multi-DFA / prefilter
-    instead (XLA:CPU's vectorized gathers beat mask arithmetic — see
-    SHIFTOR_MIN_COLUMNS; Shift-Or re-engages only on degraded hosts
-    without the native lib, and for DFA-less literal columns always);
-    in wide banks, regexes with required literals ride the AC
-    prefilter + per-record verify tier (ops/prefilter.py — cost per byte
-    independent of library width); the rest go to the packed dense DFA
-    bank; automaton-unsupported regexes stay host-side (the engine injects
+    Tier selection is static per column (patterns/bank.py) and the same
+    on every backend: literal-shaped regexes go to the bit-parallel
+    Shift-Or bank (cost independent of bank size); regexes in the bit
+    fragment ride the bit-parallel extended Shift-And tier
+    (ops/bitglush.py); in wide banks, regexes with required literals ride
+    the AC prefilter + per-record verify tier (ops/prefilter.py — cost
+    per byte independent of library width); the union multi-DFA takes
+    what those leave; the rest go to the packed dense DFA bank;
+    automaton-unsupported regexes stay host-side (the engine injects
     them as cube overrides).
     """
 
-    # CPU threshold. Shift-Or is a TPU-shaped tier: on the host, XLA's
-    # vectorized gathers beat [B, W] mask arithmetic at EVERY width
-    # measured — the 59 builtin literal columns scan 3.3x faster through
-    # the union multi-DFA (config-2 cube 1.455 -> 0.445 s, 200k lines,
-    # bit-equal; r5 A/B), and the 1008-column synthetic bank ran 4.5x
-    # faster through the prefilter (PERF.md §6). So DFA-backed literal
-    # columns are NEVER rerouted to Shift-Or on CPU; DFA-less literal
-    # columns still ride it everywhere (their only device tier).  On a
-    # degraded host WITHOUT the native library the union tier is off, so
-    # Shift-Or re-engages at the old threshold rather than stranding
-    # literal columns on the dense [B, R] gather.
-    SHIFTOR_MIN_COLUMNS = 10**9
-    SHIFTOR_MIN_COLUMNS_NO_NATIVE = 64
-    # below this many DENSE-DFA columns, the prefilter tier stays off: the
-    # dense gather is cheap and the extra scans aren't worth their latency
-    PREFILTER_MIN_COLUMNS = 64
-
-    # TPU thresholds. Measured on v5e (tools/profile_fused.py, 229k-row
-    # batch, PERF.md): a dense-DFA column costs ~150ms per 200k lines —
-    # the [B, R] transition gather is scalar-unit bound — while a Shift-Or
-    # column costs ~3ms and the AC words tier has a fixed cost of roughly
-    # eight dense columns. Literal-shaped columns therefore ALWAYS ride
+    # Measured on v5e (tools/profile_fused.py, 229k-row batch): a
+    # dense-DFA column costs ~150ms per 200k lines — the [B, R]
+    # transition gather is scalar-unit bound — while a Shift-Or column
+    # costs ~3ms and the AC words tier has a fixed cost of roughly eight
+    # dense columns. Literal-shaped columns therefore ALWAYS ride
     # Shift-Or, and the prefilter engages at 8 eligible columns.
-    SHIFTOR_MIN_COLUMNS_TPU = 1
-    PREFILTER_MIN_COLUMNS_TPU = 8
+    SHIFTOR_MIN_COLUMNS = 1
+    PREFILTER_MIN_COLUMNS = 8
 
     # Shift-Or's per-byte cost is a [B, n_words] mask gather — linear in
     # the packed WORD count (≈ total literal bytes / 32), not the column
-    # count. A 1008-literal-column synthetic bank packs 1488 words and its
-    # mask gather alone cost 4.5x the whole prefilter-routed cube (PERF.md
-    # §6); beyond this word budget, DFA-backed literal columns join the
+    # count. Beyond this word budget, DFA-backed literal columns join the
     # dense-eligible pool and ride the width policy (union / prefilter)
     # instead. Columns with no DFA stay on Shift-Or regardless — it is
     # their only device tier. 128 words keeps the builtin bank (66 words,
@@ -534,14 +513,14 @@ class MatcherBanks:
     # 1000-word synthetic banks.
     SHIFTOR_MAX_WORDS = 128
 
-    # Union multi-DFA tier (platform-independent: one [B] gather per byte
-    # beats a [B, R] gather for R >= 2 everywhere; the native builder makes
-    # group packing cheap). Above MULTI_PREFERRED_MAX dense columns the
-    # union would need many groups (each ~2 gathers/byte) — wide
-    # literal-BEARING sets ride the AC prefilter instead, whose any-hit
-    # stage is O(1)/byte in width; literal-free columns stay on the union
-    # whatever the width (their only alternative is the dense bank at
-    # ~150ms/column/200k lines on TPU).
+    # Union multi-DFA tier: one [B] gather per byte beats a [B, R] gather
+    # for R >= 2; the native builder makes group packing cheap. Above
+    # MULTI_PREFERRED_MAX dense columns the union would need many groups
+    # (each ~2 gathers/byte) — wide literal-BEARING sets ride the AC
+    # prefilter instead, whose any-hit stage is O(1)/byte in width;
+    # literal-free columns stay on the union whatever the width (their
+    # only alternative is the dense bank at ~150ms/column/200k lines on
+    # TPU).
     MULTI_MIN_COLUMNS = 2
     MULTI_STATE_BUDGET = 8192
     MULTI_MAX_GROUP = 64
@@ -554,12 +533,10 @@ class MatcherBanks:
     # elementwise cost the same way SHIFTOR_MAX_WORDS does: the builtin
     # 49 dense-eligible columns pack ~74 words, while a 2k-pattern
     # synthetic bank would need ~600 and rides the prefilter instead.
-    # TPU only: replacing the union tier with the bit tier measured the
-    # config-2 cube 0.62s -> 0.31s on v5e (random gathers are scalar-unit
-    # bound there) but 62k -> 23k lines/s on the host CPU, where XLA's
-    # vectorized gathers beat the [B, W] mask arithmetic.
-    BITGLUSH_MAX_WORDS_TPU = 192
-    BITGLUSH_MAX_WORDS_CPU = 0
+    # Replacing the union tier with the bit tier measured the config-2
+    # cube 0.62s -> 0.31s on v5e (random gathers are scalar-unit bound
+    # there).
+    BITGLUSH_MAX_WORDS = 192
     BITGLUSH_MAX_COLUMN_POSITIONS = 512
 
     def __init__(
@@ -571,7 +548,6 @@ class MatcherBanks:
         multi_min_columns: int | None = None,
         shiftor_max_words: int | None = None,
         bitglush_max_words: int | None = None,
-        shiftor_sinks: bool | None = None,
     ):
         import jax.numpy as jnp
 
@@ -580,32 +556,23 @@ class MatcherBanks:
         from log_parser_tpu.ops.shiftor import ShiftOrBank
 
         self.bank = bank
-        on_tpu = jax.default_backend() == "tpu"
-        threshold = shiftor_min_columns
-        if threshold is None:
-            if on_tpu:
-                threshold = self.SHIFTOR_MIN_COLUMNS_TPU
-            elif get_lib() is not None:
-                threshold = self.SHIFTOR_MIN_COLUMNS
-            else:
-                # degraded host (no native lib -> no union tier): Shift-Or
-                # is still far cheaper than stranding literal columns on
-                # the dense [B, R] gather — keep the old CPU engagement
-                threshold = self.SHIFTOR_MIN_COLUMNS_NO_NATIVE
-        pref_threshold = prefilter_min_columns
-        if pref_threshold is None:
-            pref_threshold = (
-                self.PREFILTER_MIN_COLUMNS_TPU
-                if on_tpu
-                else self.PREFILTER_MIN_COLUMNS
-            )
+        threshold = (
+            self.SHIFTOR_MIN_COLUMNS
+            if shiftor_min_columns is None
+            else shiftor_min_columns
+        )
+        pref_threshold = (
+            self.PREFILTER_MIN_COLUMNS
+            if prefilter_min_columns is None
+            else prefilter_min_columns
+        )
         n_device = sum(
             1
             for c in bank.columns
             if c.dfa is not None or c.exact_seqs is not None
         )
         bit_budget = (
-            (self.BITGLUSH_MAX_WORDS_TPU if on_tpu else self.BITGLUSH_MAX_WORDS_CPU)
+            self.BITGLUSH_MAX_WORDS
             if bitglush_max_words is None
             else bitglush_max_words
         )
@@ -616,15 +583,7 @@ class MatcherBanks:
         # the bit fragment) was measured: the merged bank needs 140 words
         # — the second lane-tile doubles the heavy ~18-op bitglush chain
         # (cube 0.44s vs 0.27s split, config-2, v5e). Two banks, each one
-        # tile, pay 18 + 8 op-tiles; that is the cheap shape (PERF.md §9).
-        # Shift-Or layout is platform-dependent (shiftor.py docstring):
-        # on TPU the take cost scales with gathered row width, so the
-        # bank packs bare (no sink bits) and accumulates hits per byte;
-        # on hosts the pair-composed sink stepper's halved serial chain
-        # wins, so the bank packs sinks (probe_sink_ab.py, PERF.md §9d)
-        self.shiftor_sinks = (
-            (not on_tpu) if shiftor_sinks is None else shiftor_sinks
-        )
+        # tile, pay 18 + 8 op-tiles; that is the cheap shape.
         use_shiftor = n_device >= threshold
         # Word-budget gate (see SHIFTOR_MAX_WORDS): DFA-backed literal
         # columns only ride Shift-Or while the packed word count stays
@@ -645,7 +604,7 @@ class MatcherBanks:
         # _chain_literal columns below (long literals in secondary/
         # sequence/context roles, where bitglush truncation would be
         # unsound — a couple of words of width beats re-chaining the
-        # whole bitglush bank, PERF.md §9d).
+        # whole bitglush bank).
         def _short_seqs(c) -> bool:
             return all(len(s) <= 32 for s in c.exact_seqs)
 
@@ -703,7 +662,6 @@ class MatcherBanks:
                     for seq in c.exact_seqs
                 ),
                 budget=word_budget,
-                sinks=self.shiftor_sinks,
             )
             if n_words > word_budget:
                 use_shiftor = False
@@ -896,9 +854,9 @@ class MatcherBanks:
         bit_set = set(self.bitglush_cols)
         dense_cols = [i for i in dense_cols if i not in bit_set]
         # experimental whole-tier Pallas kernel (bitglush_pallas.py):
-        # measured at parity with the scan path on v5e (PERF.md §9), kept
-        # opt-in. Read once here — cube() runs under jit, so an env read
-        # there would be frozen at first-trace time anyway.
+        # measured at parity with the scan path on v5e, kept opt-in. Read
+        # once here — cube() runs under jit, so an env read there would be
+        # frozen at first-trace time anyway.
         self.bitglush_use_pallas = (
             self.bitglush is not None
             and os.environ.get("LOG_PARSER_TPU_PALLAS") == "1"
@@ -985,28 +943,19 @@ class MatcherBanks:
         self.multidfa_use_pallas = self._dfa_pallas_plan is not None
         # built once: cube() runs under jit, and constructing the cluster
         # there would re-run the table concatenation and bake a duplicate
-        # copy of the fused table into every compiled executable.
-        # Platform split (r5 A/B, builtin bank, 200k lines): the ONE-wide-
-        # gather cluster is how TPU schedules several groups well (PERF.md
-        # §7.2: separate steppers cost 1.03 s vs 0.62 s clustered on v5e),
-        # but XLA:CPU runs the cluster 2x SLOWER than the same groups as
-        # separate scan stages (0.250 vs 0.124 s) — the cluster is a TPU
-        # shape; CPU keeps per-group steppers in the fused scan
+        # copy of the fused table into every compiled executable. The
+        # ONE-wide-gather cluster is how TPU schedules several groups well
+        # (separate steppers cost 1.03 s vs 0.62 s clustered on v5e,
+        # builtin bank, 200k lines).
         self.multi_cluster = (
-            MultiDfaCluster(self.multi_groups)
-            if self.multi_groups and on_tpu
-            else None
+            MultiDfaCluster(self.multi_groups) if self.multi_groups else None
         )
-        if self.multi_cluster is None:
-            for g in self.multi_groups:
-                g._table()  # upload now, outside any jit trace (_table)
         self.dfa_bank = DfaBank(
             [bank.columns[i].dfa for i in self.dfa_cols], stride=stride
         )
         self.shiftor = (
             ShiftOrBank(
-                [(i, bank.columns[i].exact_seqs) for i in self.shiftor_cols],
-                sinks=self.shiftor_sinks,
+                [(i, bank.columns[i].exact_seqs) for i in self.shiftor_cols]
             )
             if self.shiftor_cols
             else None
@@ -1133,16 +1082,6 @@ class MatcherBanks:
                     cluster.pair_stepper(B, lengths), cluster, False,
                     TIER_SCOPES["union"],
                 ))
-        elif self.multi_groups:
-            # CPU: per-group steppers in the same fused scan (see the
-            # cluster construction note); group order must match
-            # self.multi_groups — _multi_contribution zips against it
-            with jax.named_scope(TIER_SCOPES["union"]):
-                for g in self.multi_groups:
-                    steppers.append((
-                        g.pair_stepper(B, lengths), "multi_group", False,
-                        TIER_SCOPES["union"],
-                    ))
         if self.prefilter is not None:
             with jax.named_scope(TIER_SCOPES["prefilter"]):
                 steppers.append((
@@ -1181,15 +1120,12 @@ class MatcherBanks:
                 if isinstance(cols, MultiDfaCluster):  # per-group reported cols
                     multi_reps.extend(out)
                     continue
-                if isinstance(cols, str):  # "multi_group": one group's carry
-                    multi_reps.append(out[1])
-                    continue
                 if is_dfa:
                     out = out[:, : len(cols)]
                 # tier column sets are disjoint today, so .max equals .set;
                 # .max keeps the scatter an OR if a column ever lands in two
-                # tiers (a round-4 alternative-split experiment did exactly
-                # that and was silently masked by .set — PERF.md §9b)
+                # tiers (an alternative-split experiment did exactly that
+                # and was silently masked by .set)
                 cube = cube.at[:, jnp.asarray(np.asarray(cols))].max(out)
         if multi_pallas is not None:
             multi_reps.extend(multi_pallas)

@@ -31,32 +31,27 @@ tier is their only device path) stay exact instead of falling to host.
 
 The row-select ``mask[byte]`` is a small-table ``jnp.take`` ([256, W]
 rows, contiguous): one-level takes from a 256-row table indexed by the
-raw byte, minimal row width. The default stepper is *pair-composed*: two
-independent takes from that SAME table feed one shift-by-two update
-(``_composed_pair_stepper`` — the m-term is composed with vector ops at
-runtime, so no table grows), with sticky *sink* bits replacing the
-per-byte hit check. Two families of table-side "improvements" were
-built, measured SLOWER on TPU v5e, and deleted
-(tools/probe_paircompose.py, PERF.md §9b):
+raw byte, minimal row width. The stepper consumes one byte at a time and
+accumulates hits in an ungated carry: on v5e the take cost scales with
+the gathered row width and table locality, not take count, so the bank
+packs each sequence bare (no extra state bits). Two families of
+table-side "improvements" were built, measured SLOWER on TPU v5e, and
+deleted:
 
 - *m-term-precomposed tables* (``D2 = (D<<2) & SC2 | M2[b1,b2]`` with
-  ``M2`` materialized per byte pair): every variant lost because take
-  cost scales with gathered row width and table locality, not take
-  count — the composed tables need 1.5-2x the row words or 65536 rows
-  (0.130-0.242s vs 0.089s for the builtin bank, 229k lines). Composing
-  the recurrence is fine (it is exact and now the default); composing
-  the TABLE is what loses;
+  ``M2`` materialized per byte pair): the composed tables need 1.5-2x
+  the row words or 65536 rows (0.130-0.242s vs 0.089s for the builtin
+  bank, 229k lines);
 - *byte-class indirection* (``[C², 2W]`` tables behind a ``[256]``
   class map, C=62): any dependent two-level gather inside the scan adds
   ~3ms/step at this batch — 0.24-0.29s even with 40KB tables.
 
-A one-hot MXU matmul variant was likewise prototyped and DELETED
-(VERDICT r2 #6): with the SHIFTOR_MAX_WORDS gate this tier only ever
-runs at <=128 words where the take is already cheap, the matmul would
-materialize a [B, 256] f32 one-hot (~235 MB per scan step at the
-229k-row config-2 batch — pure HBM traffic), and the very wide banks an
-MXU formulation could serve no longer reach Shift-Or at all (they route
-to the any-hit prefilter, PERF.md §6).
+A one-hot MXU matmul variant was likewise prototyped and DELETED: with
+the SHIFTOR_MAX_WORDS gate this tier only ever runs at <=128 words where
+the take is already cheap, the matmul would materialize a [B, 256] f32
+one-hot (~235 MB per scan step at the 229k-row config-2 batch — pure HBM
+traffic), and the very wide banks an MXU formulation could serve no
+longer reach Shift-Or at all (they route to the any-hit prefilter).
 """
 
 from __future__ import annotations
@@ -135,60 +130,23 @@ class ShiftOrBank:
     """Packed Shift-Or program for a set of (column, sequences) entries."""
 
     @staticmethod
-    def _plan(seq_lengths, budget: int | None = None, sinks: bool = True):
-        """Packing plan via :func:`first_fit_plan`. With ``sinks`` every
-        sequence's allocation is its length + 2 *sink* bits (the sticky
-        match flags the pair-composed stepper reads at scan end);
-        without, exactly its length (the hits-carry steppers need no
-        extra state)."""
-        return first_fit_plan(
-            ((m + 2 if sinks else m) for m in seq_lengths), budget
-        )
+    def count_packed_words(seq_lengths, budget: int | None = None) -> int:
+        """Words the bank packs (:func:`first_fit_plan`; a sequence's
+        allocation is exactly its length)."""
+        return first_fit_plan(seq_lengths, budget)[1]
 
-    @classmethod
-    def count_packed_words(
-        cls, seq_lengths, budget: int | None = None, sinks: bool = True
-    ) -> int:
-        return cls._plan(seq_lengths, budget, sinks=sinks)[1]
-
-    def __init__(
-        self,
-        column_seqs: list[tuple[int, tuple[ByteSeq, ...]]],
-        sinks: bool = True,
-    ):
-        """``sinks`` picks the bank layout AND the stepper family:
-        True (the CPU default) packs two sticky sink bits per sequence
-        and steps with the pair-composed sink recurrence — the fastest
-        form on hosts, where the halved serial chain dominates. False
-        (the TPU default, chosen by MatcherBanks) packs sequences
-        bare and accumulates hits per byte — on v5e the take cost
-        scales with the gathered row width, so the ~12% narrower table
-        beats the halved chain (tools/probe_sink_ab.py, PERF.md §9d:
-        0.082 s vs 0.112 s at config-2 shapes; on CPU the same A/B
-        reads 0.253 s vs 0.151 s the other way)."""
+    def __init__(self, column_seqs: list[tuple[int, tuple[ByteSeq, ...]]]):
         self.columns = [c for c, _ in column_seqs]
-        self.sinks = sinks
         flat = [(col, seq) for col, seqs in column_seqs for seq in seqs]
-        starts, self.n_words = self._plan(
-            [len(seq) for _, seq in flat], sinks=sinks
-        )
+        starts, self.n_words = first_fit_plan([len(seq) for _, seq in flat])
         self.n_seqs = len(flat)
 
         # mask[c, w]: bit (o+j) = 1 iff byte c not allowed at position j;
-        # unused bits are always-1 (inert). Each sequence's allocation
-        # ends with two *sink* bits that admit EVERY byte (padding
-        # included): once the end position goes alive, the following
-        # shifts park the match in a sink, where the pair-composed
-        # stepper's persistence term keeps it for the rest of the scan —
-        # two sinks because a pair step shifts by two, so completions of
-        # either parity land in one of them.
+        # unused bits are always-1 (inert)
         mask = np.full((256, self.n_words), 0xFFFFFFFF, dtype=np.uint32)
         start_clear = np.full(self.n_words, 0xFFFFFFFF, dtype=np.uint32)
         cont_mask = np.zeros(self.n_words, dtype=np.uint32)
         end_mask = np.zeros(self.n_words, dtype=np.uint32)
-        sink_mask = np.zeros(self.n_words, dtype=np.uint32)
-        snk_word: list[int] = []
-        snk_bit: list[int] = []
         for (col, seq), g in zip(flat, starts):
             start_clear[g // 32] &= ~np.uint32(1 << (g % 32))
             for j, byteset in enumerate(seq):
@@ -200,55 +158,17 @@ class ShiftOrBank:
                     # pad0_transparent holds for every bank
                     if c != 0:
                         mask[c, p // 32] &= ~bit
-            if sinks:
-                for p in (g + len(seq), g + len(seq) + 1):  # the two sinks
-                    bit = np.uint32(1 << (p % 32))
-                    mask[:, p // 32] &= ~bit
-                    sink_mask[p // 32] |= bit
-                    snk_word.append(p // 32)
-                    snk_bit.append(p % 32)
             # chain continuation words receive shift carry from their
-            # predecessor (the allocation spans the sequence positions
-            # plus, in sink layout, the 2 sink bits)
-            last_p = g + len(seq) - 1 + (2 if sinks else 0)
-            for w in range(g // 32 + 1, last_p // 32 + 1):
-                cont_mask[w] |= np.uint32(1)
+            # predecessor
             e = g + len(seq) - 1
+            for w in range(g // 32 + 1, e // 32 + 1):
+                cont_mask[w] |= np.uint32(1)
             end_mask[e // 32] |= np.uint32(1 << (e % 32))
         self.mask = jnp.asarray(mask)
         self.start_clear = jnp.asarray(start_clear)
         self.end_mask = jnp.asarray(end_mask)
         self.has_chains = bool(cont_mask.any())
         self.cont_mask = jnp.asarray(cont_mask)
-        # pair-composed constants. One Shift-Or step is the affine map
-        # f(D) = s1(D) & sc | m[b] (s1 = chain-aware shift); s1 relocates
-        # bits, so it distributes over & and |, and two steps compose
-        # EXACTLY into one shift-by-two step:
-        #   f2(f1(D)) = s2(D) & C2 | (s1(m1) & sc | m2)
-        # with C2 = s1(sc) & sc precomputed — same-width rows from the
-        # same 256-row table, so take cost is unchanged while the vector
-        # chain (and the serial depth) nearly halves. The earlier
-        # pair-composition attempts that measured SLOWER (docstring
-        # above) precomposed the m-term into 65536-row or wider tables —
-        # the loss was table locality / row width, not the algebra.
-        np1 = lambda x: (  # noqa: E731 — numpy s1 on [..., W] constants
-            (x << 1).astype(np.uint32)
-            | (
-                np.concatenate(
-                    [np.zeros_like(x[..., :1]), x[..., :-1] >> 31], axis=-1
-                )
-                & cont_mask
-            )
-        )
-        if sinks:
-            self.c2 = jnp.asarray(np1(start_clear) & start_clear)
-            self.cont2_mask = jnp.asarray(cont_mask * np.uint32(3))
-            self.not_sink = jnp.asarray(~sink_mask)
-            # the virtual padding pair finish() applies for full-width
-            # rows: both bytes are padding, so the m-term is a constant
-            self.pad_m12 = jnp.asarray(np1(mask[0]) & start_clear | mask[0])
-        self.snk_word = np.asarray(snk_word, dtype=np.int32)
-        self.snk_bit = np.asarray(snk_bit, dtype=np.int32)
         # The hit term is ``hits |= (~d_new) & end_mask`` and
         # ``d_new = sh | mask[byte]`` — so a padding byte (0) can only
         # contribute a hit if some sequence's END position admits NUL
@@ -262,7 +182,7 @@ class ShiftOrBank:
         # should a future bank ever admit the padding byte.
         self.pad0_transparent = bool(((mask[0] & end_mask) == end_mask).all())
 
-        # host copies for probes/serialization (tools/probe_paircompose.py)
+        # host copies for the host carry (ShiftOrHostCarry)
         self._np = {"mask": mask, "start_clear": start_clear,
                     "end_mask": end_mask, "cont_mask": cont_mask}
 
@@ -275,8 +195,6 @@ class ShiftOrBank:
         self.seq_slot = np.asarray(
             [slot_of_col[col] for col, _ in flat], dtype=np.int32
         )
-        # two sink entries per sequence, in the same flat order
-        self.snk_slot = np.repeat(self.seq_slot, 2)
 
     # --------------------------------------------------------------- device
 
@@ -293,39 +211,21 @@ class ShiftOrBank:
             sh = sh | (carry & self.cont_mask[None, :])
         return sh
 
-    def _s2(self, x: jax.Array) -> jax.Array:
-        """Chain-aware shift by two positions (device)."""
-        sh = x << 2
-        if self.has_chains:
-            carry = jnp.concatenate(
-                [jnp.zeros_like(x[:, :1]), x[:, :-1] >> 30], axis=1
-            )
-            sh = sh | (carry & self.cont2_mask[None, :])
-        return sh
-
     def pair_stepper(self, B: int, lengths: jax.Array):
         """(init, step(carry, b1, b2, t), finish). On the (universal
-        today) ``pad0_transparent`` banks, sink layout steps with the
-        pair-composed sink recurrence — per byte PAIR, two independent
-        row takes and one composed update, no per-byte hit term, half
-        the serial depth; matches park in sticky sink bits that
-        ``finish`` reads once (after one virtual padding pair, so rows
-        that fill every scanned byte sweep their last-byte completions
-        in). Bare layout steps per byte with an ungated hits carry
-        (sound because a padding byte sets every end bit in ``d``, so
-        past-end positions can never contribute a hit). Non-transparent
-        banks keep the gated per-byte path."""
+        today) ``pad0_transparent`` banks, steps per byte with an ungated
+        hits carry (sound because a padding byte sets every end bit in
+        ``d``, so past-end positions can never contribute a hit).
+        Non-transparent banks keep the gated per-byte path."""
         if self.pad0_transparent:
-            if self.sinks:
-                return self._composed_pair_stepper(B)
             return self._ungated_hits_stepper(B)
         return self._perbyte_pair_stepper(B, lengths)
 
     def _ungated_hits_stepper(self, B: int):
-        """Per-byte hits accumulation with NO length gating — the TPU
-        form: one [256, W] row take plus four [B, W] vector ops per
-        byte on the narrowest possible rows (no sink bits). The carry
-        holds the COMPLEMENT (``nh`` — 1 = never hit): the update
+        """Per-byte hits accumulation with NO length gating: one
+        [256, W] row take plus four [B, W] vector ops per byte on the
+        narrowest possible rows. The carry holds the COMPLEMENT (``nh``
+        — 1 = never hit): the update
         ``nh & (d | ~e)`` is one op cheaper per byte than
         ``hits | (~d & e)`` because ``~e`` is a precomputed constant;
         one inversion at ``finish`` recovers the hit words."""
@@ -361,34 +261,6 @@ class ShiftOrBank:
             seq_hit.astype(jnp.int32)
         )
         return out.astype(bool)
-
-    def _composed_pair_stepper(self, B: int):
-        select = self._row_select
-        d0 = jnp.full((B, self.n_words), 0xFFFFFFFF, dtype=jnp.uint32)
-
-        def step(d, b1, b2, t):
-            m1 = select(b1)
-            m2 = select(b2)
-            m12 = (self._s1(m1) & self.start_clear[None, :]) | m2
-            cand = (self._s2(d) & self.c2[None, :]) | m12
-            # sinks (0 = a match parked there) persist; everywhere else
-            # the composed update stands
-            return cand & (d | self.not_sink[None, :])
-
-        def finish(d):
-            cand = (self._s2(d) & self.c2[None, :]) | self.pad_m12[None, :]
-            d = cand & (d | self.not_sink[None, :])
-            alive = (
-                jnp.take(d, jnp.asarray(self.snk_word), axis=1)
-                >> jnp.asarray(self.snk_bit)[None, :]
-            ) & 1  # [B, n_seqs * 2]; 0 = match parked in this sink
-            out = jnp.zeros((B, max(1, len(self.columns))), dtype=jnp.int32)
-            out = out.at[:, jnp.asarray(self.snk_slot)].max(
-                1 - alive.astype(jnp.int32)
-            )
-            return out.astype(bool)
-
-        return d0, step, finish
 
     def _perbyte_pair_stepper(self, B: int, lengths: jax.Array):
         """Per-byte gated fallback for banks whose padding byte is not
@@ -451,30 +323,19 @@ class ShiftOrBank:
 
 
 class ShiftOrHostCarry:
-    """Carried Shift-Or registers for one growing line (host, numpy).
-
-    Sink layout advances at byte-PAIR granularity — the sticky-sink
-    persistence term ``cand & (d | not_sink)`` is defined per pair, so a
-    byte-level replay could kill a candidate the device keeps. An odd
-    trailing byte is held in the carry and replayed as the device's
-    (byte, 0) pair inside :meth:`snapshot_bits`, which also applies the
-    same virtual padding pair as the device ``finish`` (extra padding
-    pairs are no-ops on a ``pad0_transparent`` bank, so padded width
-    does not matter — the width-independence the line cache relies on).
-    Bare layout accumulates complement-hits per byte exactly like
-    ``_ungated_hits_stepper``."""
+    """Carried Shift-Or registers for one growing line (host, numpy):
+    accumulates complement-hits per byte exactly like
+    ``_ungated_hits_stepper``, so padded width does not matter — the
+    width-independence the line cache relies on."""
 
     def __init__(self, bank: ShiftOrBank):
         self.bank = bank
         c = bank._np
         self._mask = c["mask"]
         self._sc = c["start_clear"]
+        self._not_e = ~c["end_mask"]
         self._em = c["end_mask"]
         self._cm = c["cont_mask"]
-        if bank.sinks:
-            self._c2 = np.asarray(bank.c2)
-            self._not_sink = np.asarray(bank.not_sink)
-            self._pad_m12 = np.asarray(bank.pad_m12)
         self.reset()
 
     def _s1(self, x: np.ndarray) -> np.ndarray:
@@ -484,65 +345,22 @@ class ShiftOrHostCarry:
             sh = sh | (carry & self._cm)
         return sh
 
-    def _s2(self, x: np.ndarray) -> np.ndarray:
-        sh = (x << 2).astype(np.uint32)
-        if self.bank.has_chains:
-            carry = np.concatenate([np.zeros(1, np.uint32), x[:-1] >> 30])
-            sh = sh | (carry & (self._cm * np.uint32(3)))
-        return sh
-
     def reset(self) -> None:
         W = self.bank.n_words
         self._d = np.full(W, 0xFFFFFFFF, dtype=np.uint32)
-        if not self.bank.sinks:
-            self._nh = np.full(W, 0xFFFFFFFF, dtype=np.uint32)
-        self._odd: int | None = None
-
-    def _pair(self, d: np.ndarray, b1: int, b2: int) -> np.ndarray:
-        m1 = self._mask[b1]
-        m2 = self._mask[b2]
-        m12 = (self._s1(m1) & self._sc) | m2
-        cand = (self._s2(d) & self._c2) | m12
-        return cand & (d | self._not_sink)
+        self._nh = np.full(W, 0xFFFFFFFF, dtype=np.uint32)
 
     def feed(self, data: bytes) -> None:
-        if not data:
-            return
-        if self.bank.sinks:
-            buf = data
-            if self._odd is not None:
-                buf = bytes([self._odd]) + buf
-                self._odd = None
-            if len(buf) % 2:
-                self._odd = buf[-1]
-                buf = buf[:-1]
-            d = self._d
-            for i in range(0, len(buf), 2):
-                d = self._pair(d, buf[i], buf[i + 1])
-            self._d = d
-        else:
-            d, nh = self._d, self._nh
-            not_e = ~self._em
-            for b in data:
-                d = (self._s1(d) & self._sc) | self._mask[b]
-                nh = nh & (d | not_e)
-            self._d, self._nh = d, nh
+        d, nh = self._d, self._nh
+        for b in data:
+            d = (self._s1(d) & self._sc) | self._mask[b]
+            nh = nh & (d | self._not_e)
+        self._d, self._nh = d, nh
 
     def snapshot_bits(self) -> np.ndarray:
         """bool [n_bank_columns]: the cube row the device would produce
         if the line ended at the bytes fed so far. Non-destructive."""
         bank = self.bank
-        if bank.sinks:
-            d = self._d
-            if self._odd is not None:
-                d = self._pair(d, self._odd, 0)
-            # the virtual padding pair the device finish applies
-            cand = (self._s2(d) & self._c2) | self._pad_m12
-            d = cand & (d | self._not_sink)
-            alive = (d[bank.snk_word] >> bank.snk_bit.astype(np.uint32)) & 1
-            out = np.zeros(max(1, len(bank.columns)), dtype=bool)
-            np.maximum.at(out, bank.snk_slot, alive == 0)
-            return out
         hits = ~self._nh & self._em
         seq_hit = (hits[bank.seq_word] >> bank.seq_bit.astype(np.uint32)) & 1
         out = np.zeros(max(1, len(bank.columns)), dtype=bool)

@@ -231,7 +231,7 @@ def test_builtin_bank_fully_deasserted():
     from log_parser_tpu.patterns.builtin import load_builtin_pattern_sets
 
     bank = PatternBank(load_builtin_pattern_sets())
-    mb = MatcherBanks(bank, bitglush_max_words=192)
+    mb = MatcherBanks(bank)
     g = mb.bitglush
     assert g is not None
     assert not g.has_preassert and not g.has_tb and not g.needs_wordness
@@ -483,7 +483,7 @@ def test_matcher_banks_bit_tier_cube_parity():
     from log_parser_tpu.patterns.builtin import load_builtin_pattern_sets
 
     bank = PatternBank(load_builtin_pattern_sets())
-    bit = MatcherBanks(bank, bitglush_max_words=192)
+    bit = MatcherBanks(bank)
     base = MatcherBanks(bank, bitglush_max_words=0)
     # long literal alternations ride Shift-Or chains (MAX_EXACT_LEN=64);
     # the bit tier keeps the ~32 genuinely non-literal columns
@@ -560,10 +560,10 @@ def test_pallas_engine_integration(monkeypatch):
 
     monkeypatch.setenv("LOG_PARSER_TPU_PALLAS", "1")
     bank = PatternBank(load_builtin_pattern_sets())
-    pal = MatcherBanks(bank, bitglush_max_words=192)
+    pal = MatcherBanks(bank)
     assert pal.bitglush_use_pallas and pal.bitglush_cols
     monkeypatch.delenv("LOG_PARSER_TPU_PALLAS")
-    base = MatcherBanks(bank, bitglush_max_words=192)
+    base = MatcherBanks(bank)
     assert not base.bitglush_use_pallas
 
     lines = [
@@ -715,17 +715,14 @@ def test_approx_caches_invalidate_on_matcher_swap():
         )
     ]
     engine = AnalysisEngine(sets, ScoringConfig())
-    # default CPU-policy matchers: nothing truncated, caches built empty
+    # start from matchers with the bit tier off: nothing truncated,
+    # caches built empty
+    engine._matchers = MatcherBanks(engine.bank, bitglush_max_words=0)
+    assert not engine.matchers.approx_cols
     assert not engine._approx_patterns().any()
     assert engine._approx_secondaries() == []
-    # swap in the TPU-style tier build that truncates the long literal
-    engine._matchers = MatcherBanks(
-        engine.bank,
-        bitglush_max_words=192,
-        shiftor_min_columns=10**9,
-        prefilter_min_columns=10**9,
-        multi_min_columns=10**9,
-    )
+    # swap in the default plan, whose bit tier truncates the long literal
+    engine._matchers = MatcherBanks(engine.bank)
     assert engine.matchers.approx_cols
     # the caches must follow the swap, not serve the stale empty sets
     assert engine._approx_patterns().any()
@@ -765,8 +762,8 @@ def test_truncated_primary_column_engine_exact():
     data = PodFailureData(logs=logs)
 
     engine = AnalysisEngine(sets, ScoringConfig())
-    # force the TPU tier policy on the CPU test backend so the long
-    # alternative actually rides (truncated) bitglush
+    # the other tiers pinned off, so the long alternative rides the
+    # (truncated) bit tier
     engine._matchers = MatcherBanks(
         engine.bank,
         bitglush_max_words=192,
@@ -826,11 +823,7 @@ def test_truncation_roles():
     ]
     p2 = make_pattern("p2", regex=sec_lit, confidence=0.5)
     bank = PatternBank([make_pattern_set([p1, p2])])
-    mb = MatcherBanks(
-        bank,
-        bitglush_max_words=192,
-        shiftor_min_columns=1,
-    )
+    mb = MatcherBanks(bank)
     sec_col = next(i for i, c in enumerate(bank.columns) if c.regex == sec_lit)
     seq_col = next(i for i, c in enumerate(bank.columns) if c.regex == seq_lit)
     # secondary-role long column: truncated on device, flagged approx
@@ -989,7 +982,7 @@ def test_all_poison_corpus_zero_events():
     engine = AnalysisEngine(sets, ScoringConfig())
     engine._matchers = MatcherBanks(
         engine.bank,
-        bitglush_max_words=MatcherBanks.BITGLUSH_MAX_WORDS_TPU,
+        bitglush_max_words=MatcherBanks.BITGLUSH_MAX_WORDS,
         shiftor_min_columns=10**9,
         prefilter_min_columns=10**9,
         multi_min_columns=10**9,

@@ -2,9 +2,8 @@
 
 The smoke's platform check is steered here, never through an option of
 the script: with ``REQUIRED_PLATFORM`` set to ``cpu`` its phases run end
-to end at a tiny size, the union-DFA kernel in interpret mode (the CPU
-tier policy leaves the bit tier empty, so only that kernel is expected).
-Unsteered, it must refuse the CPU and print no result.
+to end at a tiny size, both Pallas kernels in interpret mode. Unsteered,
+it must refuse the CPU and print no result.
 """
 
 from __future__ import annotations
@@ -28,7 +27,6 @@ def _last_json(out: str) -> dict:
 @pytest.fixture
 def on_cpu(monkeypatch):
     monkeypatch.setattr(chip_smoke, "REQUIRED_PLATFORM", "cpu")
-    monkeypatch.setattr(chip_smoke, "EXPECTED_KERNELS", ("union_dfa",))
     monkeypatch.setattr(chip_smoke, "CONFIG2_LINES", 600)
     monkeypatch.setattr(chip_smoke, "SEEDED_LINES", 700)
 
@@ -58,6 +56,7 @@ def test_one_chip_phases(on_cpu, capsys):
         "ok": True, "device": {"platform": "cpu", "kind": "cpu", "count": 8},
     }
     for line in ("served: config1-pod-failure", "served: seeded-700",
+                 "kernel-bitglush: traced=",
                  "kernel-union-dfa: layout=off-path reason=", "native: ",
                  "compileCache: "):
         assert line in out, line

@@ -89,12 +89,6 @@ def test_bit_tiers_pad0_transparent_for_builtin_bank():
     from log_parser_tpu.patterns.bank import PatternBank
     from log_parser_tpu.patterns.builtin import load_builtin_pattern_sets
 
-    # force the TPU tier shape on the CPU test backend: both bit tiers
-    # are TPU-policy tiers now (CPU routes literals through the union)
-    mb = MatcherBanks(
-        PatternBank(load_builtin_pattern_sets()),
-        bitglush_max_words=192,
-        shiftor_min_columns=1,
-    )
+    mb = MatcherBanks(PatternBank(load_builtin_pattern_sets()))
     assert mb.shiftor is not None and mb.shiftor.pad0_transparent
     assert mb.bitglush is not None and mb.bitglush.pad0_transparent

@@ -210,31 +210,16 @@ def random_long_logs(rng: random.Random, n_lines: int) -> str:
     return "\n".join(lines) + rng.choice(["", "\n"])
 
 
-def _force_bit_policy(engine: AnalysisEngine) -> None:
-    """Build the engine's matcher banks under the TPU tier policy (bit
-    tiers on, truncation active) on the CPU test backend. Must run
-    before the first ``engine.matchers`` access."""
-    from log_parser_tpu.ops.match import MatcherBanks
-
-    engine._matchers = MatcherBanks(
-        engine.bank,
-        bitglush_max_words=MatcherBanks.BITGLUSH_MAX_WORDS_TPU,
-        shiftor_min_columns=MatcherBanks.SHIFTOR_MIN_COLUMNS_TPU,
-        prefilter_min_columns=MatcherBanks.PREFILTER_MIN_COLUMNS_TPU,
-        shiftor_sinks=False,
-    )
-
-
 @pytest.mark.parametrize("seed", range(6))
 def test_random_long_literal_parity_bit_policy(seed):
     """Truncation + host verify/repair fuzz: long-literal libraries under
-    the TPU tier policy, corpora salted with prefix-only poison lines,
-    engine vs golden over evolving frequency state."""
+    the default tier plan (bit tier on, truncation active), corpora
+    salted with prefix-only poison lines, engine vs golden over evolving
+    frequency state."""
     rng = random.Random(31000 + seed)
     sets = random_long_library(rng, rng.randrange(2, 6))
     config = ScoringConfig(proximity_max_window=rng.choice([5, 100]))
     engine = AnalysisEngine(sets, config, clock=FakeClock())
-    _force_bit_policy(engine)
     assert engine.matchers.bitglush is not None
     golden = GoldenAnalyzer(sets, config, clock=FakeClock())
     for _ in range(3):

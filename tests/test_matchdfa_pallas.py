@@ -395,6 +395,7 @@ def test_engine_counts_kernel_fault_as_fallback(monkeypatch):
     from log_parser_tpu.config import ScoringConfig
     from log_parser_tpu.golden import GoldenAnalyzer
     from log_parser_tpu.models.pod import PodFailureData
+    from log_parser_tpu.ops.match import MatcherBanks
     from log_parser_tpu.runtime import AnalysisEngine
     from tests.test_engine_parity import assert_results_match
 
@@ -405,6 +406,9 @@ def test_engine_counts_kernel_fault_as_fallback(monkeypatch):
     ])]
     monkeypatch.setenv("LOG_PARSER_TPU_PALLAS_DFA", "1")
     engine = AnalysisEngine(sets, ScoringConfig())
+    # the bit tier off, so these columns pack union groups for the
+    # kernel (as chip_smoke.py's union-DFA engine does)
+    engine._matchers = MatcherBanks(engine.bank, bitglush_max_words=0)
     engine.fallback_to_golden = True
     golden = GoldenAnalyzer(sets, ScoringConfig())
     data = PodFailureData(pod={"metadata": {"name": "p"}}, logs="\n".join(LINES))

@@ -27,9 +27,7 @@ REGEXES = [
 ]
 
 
-def _bank_for(
-    regexes: list[str], sinks: bool = True
-) -> tuple[ShiftOrBank, list[re.Pattern]]:
+def _bank_for(regexes: list[str]) -> tuple[ShiftOrBank, list[re.Pattern]]:
     entries = []
     hosts = []
     for i, rx in enumerate(regexes):
@@ -37,17 +35,11 @@ def _bank_for(
         assert seqs is not None, rx
         entries.append((i, seqs))
         hosts.append(compile_java_regex(rx))
-    return ShiftOrBank(entries, sinks=sinks), hosts
+    return ShiftOrBank(entries), hosts
 
 
-BOTH_LAYOUTS = pytest.mark.parametrize(
-    "sinks", [True, False], ids=["sinks", "bare"]
-)
-
-
-@BOTH_LAYOUTS
-def test_exactness_vs_host_re(sinks):
-    bank, hosts = _bank_for(REGEXES, sinks)
+def test_exactness_vs_host_re():
+    bank, hosts = _bank_for(REGEXES)
     rng = random.Random(11)
     alphabet = "aAbx45 GCgcOutfMemoryErrConnectionRefusedTimeoutcodestatus=d019"
     lines = [
@@ -78,8 +70,8 @@ def test_exactness_vs_host_re(sinks):
         )
 
 
-def _check_exact(regexes: list[str], lines: list[str], sinks: bool = True):
-    bank, hosts = _bank_for(regexes, sinks)
+def _check_exact(regexes: list[str], lines: list[str]):
+    bank, hosts = _bank_for(regexes)
     enc = encode_lines(lines)
     got = np.asarray(bank._run(np.asarray(enc.u8.T), np.asarray(enc.lengths)))
     for i, host in enumerate(hosts):
@@ -88,13 +80,9 @@ def _check_exact(regexes: list[str], lines: list[str], sinks: bool = True):
             assert bool(got[j, i]) == want, (regexes[i], line)
 
 
-@BOTH_LAYOUTS
-def test_sink_full_width_lines(sinks):
-    """Completions at the scan's very last byte rely on finish()'s
-    virtual padding pair to sweep the end bit into a sink — exercised by
-    lines that exactly fill the padded width (multiples of 32)."""
-    regexes = ["Error", "ab"]
-    lines = [
+FULL_WIDTH = (
+    ["Error", "ab"],
+    [
         "x" * 27 + "Error",      # 32 chars, completion at byte 31
         "x" * 59 + "Error",      # 64 chars, completion at byte 63
         "x" * 26 + "Error" + "y",  # completion one byte before the end
@@ -102,53 +90,91 @@ def test_sink_full_width_lines(sinks):
         "x" * 31 + "a",          # suffix is only a prefix of the seq
         "Error" + "z" * 27,      # completion early in a full-width row
         "",
-    ]
-    _check_exact(regexes, lines, sinks)
+    ],
+)
+ONE_BYTE = (["q", "[0-9]"], ["q", "zq", "3", "zzz3", "none", ""])
+S31 = "abcdefghijklmnopqrstuvwxyz01234"
+S32 = S31 + "5"
+LEN_31_32 = (
+    [S31, S32],
+    [
+        S31, S32, "x" + S31, "xy" + S31, S31[:-1],
+        "x" * 30 + S32, S32 + "tail", S32[1:],
+    ],
+)
+S62 = "A fatal error has been detected by the Java Runtime Environmen"
+LONG_CHAIN = (
+    [S62],
+    [S62, "x" + S62 + "y", S62[:-1] + "X", "pad " * 8 + S62, ""],
+)
 
 
-@BOTH_LAYOUTS
-def test_sink_one_byte_sequences(sinks):
-    """m=1 sequences: start == end; the sink pair sits right after."""
-    _check_exact(["q", "[0-9]"], ["q", "zq", "3", "zzz3", "none", ""], sinks)
+def test_sink_full_width_lines():
+    """Completions at the scan's very last byte — lines that exactly
+    fill the padded width (multiples of 32) — must still count."""
+    _check_exact(*FULL_WIDTH)
 
 
-@BOTH_LAYOUTS
-def test_sink_31_32_length_sequences_chain(sinks):
-    """Lengths 31-32 now allocate 33-34 bits and ride cross-word chains;
-    exactness must survive the chain carry in both shift parities."""
-    s31 = "abcdefghijklmnopqrstuvwxyz01234"
-    s32 = s31 + "5"
-    bank, _ = _bank_for([s31, s32], sinks)
-    assert bank.has_chains or not sinks
-    _check_exact(
-        [s31, s32],
-        [
-            s31, s32, "x" + s31, "xy" + s31, s31[:-1],
-            "x" * 30 + s32, s32 + "tail", s32[1:],
-        ],
-        sinks,
-    )
+def test_sink_one_byte_sequences():
+    """m=1 sequences: start == end."""
+    _check_exact(*ONE_BYTE)
 
 
-@BOTH_LAYOUTS
-def test_sink_long_chain_sequences(sinks):
-    """>32-length sequences (multi-word chains) with the composed
-    stepper: carries cross two word boundaries."""
-    s62 = "A fatal error has been detected by the Java Runtime Environmen"
-    bank, _ = _bank_for([s62], sinks)
+def test_sink_31_32_length_sequences_chain():
+    """Lengths 31 and 32 fill a word each without a chain; exactness
+    must hold at the word's top bit."""
+    bank, _ = _bank_for([S31, S32])
+    assert not bank.has_chains and bank.n_words == 2
+    _check_exact(*LEN_31_32)
+
+
+def test_sink_long_chain_sequences():
+    """>32-length sequences (multi-word chains): carries cross two word
+    boundaries."""
+    bank, _ = _bank_for([S62])
     assert bank.has_chains
-    _check_exact(
-        [s62],
-        [s62, "x" + s62 + "y", s62[:-1] + "X", "pad " * 8 + s62, ""],
-        sinks,
+    _check_exact(*LONG_CHAIN)
+
+
+@pytest.mark.parametrize(
+    "regexes,lines",
+    [FULL_WIDTH, ONE_BYTE, LEN_31_32, LONG_CHAIN],
+    ids=["full_width", "one_byte", "len_31_32", "long_chain"],
+)
+def test_shiftor_exact_through_matcher_banks(regexes, lines):
+    """The same cases through ``MatcherBanks.cube`` under the default
+    tier plan: Shift-Or inside the fused scan it runs in on the chip.
+    Each regex is a sequence event (an exact role), so long literals too
+    ride Shift-Or's chains rather than the truncating bit tier."""
+    import jax.numpy as jnp
+
+    from helpers import make_pattern, make_pattern_set
+    from log_parser_tpu.patterns.bank import PatternBank
+
+    bank = PatternBank([make_pattern_set([
+        make_pattern("p0", regex="zzz-no-such-line", confidence=0.5,
+                     sequences=[(1.5, list(regexes))]),
+    ])])
+    mb = MatcherBanks(bank)
+    cols = [
+        next(i for i, c in enumerate(bank.columns) if c.regex == rx)
+        for rx in regexes
+    ]
+    assert set(cols) <= set(mb.shiftor_cols)
+    enc = encode_lines(lines)
+    got = np.asarray(
+        mb.cube(jnp.asarray(enc.u8.T), jnp.asarray(enc.lengths))
     )
+    for rx, col in zip(regexes, cols):
+        host = compile_java_regex(rx)
+        want = [bool(host.search(ln)) for ln in lines]
+        np.testing.assert_array_equal(got[: len(lines), col], want, err_msg=rx)
 
 
-@BOTH_LAYOUTS
-def test_word_packing_isolates_neighbors(sinks):
+def test_word_packing_isolates_neighbors():
     """Sequences packed into one word must not leak shift bits into each
     other: 'ab' and 'ba' share a word; 'aba' contains both, 'aa' neither."""
-    bank, _ = _bank_for(["ab", "ba"], sinks)
+    bank, _ = _bank_for(["ab", "ba"])
     assert bank.n_words == 1
     enc = encode_lines(["aba", "aa", "ab", "ba", ""])
     got = np.asarray(bank._run(np.asarray(enc.u8.T), np.asarray(enc.lengths)))
@@ -157,8 +183,7 @@ def test_word_packing_isolates_neighbors(sinks):
     )
 
 
-@BOTH_LAYOUTS
-def test_cross_word_chain_sequences(sinks):
+def test_cross_word_chain_sequences():
     """Sequences longer than 32 positions span words via the carry chain
     (cont_mask): exactness at every boundary-straddling offset, no leak
     into co-packed short sequences, correct restart mid-line."""
@@ -169,7 +194,7 @@ def test_cross_word_chain_sequences(sinks):
         (1, (tuple(frozenset([ord("b")]) for _ in range(33)),)),
         (2, (tuple(frozenset([ord(c)]) for c in "xy"),)),
     ]
-    bank = ShiftOrBank(entries, sinks=sinks)
+    bank = ShiftOrBank(entries)
     assert bank.has_chains and bank.n_words >= 3
     lines = [
         long_a,                       # exact
@@ -247,15 +272,20 @@ def test_adaptive_tier_split(monkeypatch):
         for i in range(8)
     ]
     bank = PatternBank([make_pattern_set(patterns)])
-    # under the Shift-Or threshold: nothing on the Shift-Or tier; the
-    # columns ride the union multi-DFA (or the dense bank without it)
-    small = MatcherBanks(bank, multi_min_columns=10**9, bitglush_max_words=0)
+    # under pinned Shift-Or and prefilter thresholds: nothing on the
+    # Shift-Or tier; the columns ride the union multi-DFA (or the dense
+    # bank without it)
+    under = {"shiftor_min_columns": 10**9, "prefilter_min_columns": 10**9}
+    small = MatcherBanks(
+        bank, multi_min_columns=10**9, bitglush_max_words=0, **under
+    )
     assert small.shiftor is None and len(small.dfa_cols) > 0
-    multi = MatcherBanks(bank, bitglush_max_words=0)
+    multi = MatcherBanks(bank, bitglush_max_words=0, **under)
     assert multi.shiftor is None
     # every column the no-multi config kept dense rides the union instead
     assert sorted(multi.multi_cols + multi.dfa_cols) == sorted(small.dfa_cols)
-    wide = MatcherBanks(bank, shiftor_min_columns=1)
+    # the default plan: Shift-Or engages from one column
+    wide = MatcherBanks(bank)
     assert wide.shiftor is not None
     assert len(wide.shiftor_cols) == 8  # all literal-shaped primaries
 
@@ -292,56 +322,4 @@ def test_word_budget_gate_reroutes_and_stays_exact():
     np.testing.assert_array_equal(
         np.asarray(wide.cube(lt, ln))[: len(lines)],
         np.asarray(gated.cube(lt, ln))[: len(lines)],
-    )
-
-
-def test_bare_layout_through_matcher_banks():
-    """The TPU-side bank layout (shiftor_sinks=False — no sink bits,
-    ungated hits stepper) produces an identical match cube to the CPU
-    sink layout through the full fused MatcherBanks path, at fewer
-    packed words."""
-    import jax.numpy as jnp
-
-    from helpers import make_pattern, make_pattern_set
-    from log_parser_tpu.ops.match import MatcherBanks
-    from log_parser_tpu.patterns.bank import PatternBank
-
-    patterns = [
-        make_pattern(f"p{i}", regex=rx, confidence=0.5)
-        for i, rx in enumerate(
-            [
-                "OutOfMemoryError",
-                "Connection refused",
-                "[Tt]imeout waiting",
-                "status=[45]\\d\\d",
-                "q",  # one-byte sequence: start == end
-                "A fatal error has been detected by the Java Runtime",
-            ]
-        )
-    ]
-    bank = PatternBank([make_pattern_set(patterns)])
-    sink = MatcherBanks(bank, shiftor_min_columns=1, shiftor_sinks=True)
-    bare = MatcherBanks(bank, shiftor_min_columns=1, shiftor_sinks=False)
-    assert sink.shiftor is not None and bare.shiftor is not None
-    assert sink.shiftor.sinks and not bare.shiftor.sinks
-    assert bare.shiftor.n_words < sink.shiftor.n_words
-
-    lines = [
-        "java.lang.OutOfMemoryError: heap",
-        "dial tcp: Connection refused",
-        "Timeout waiting for connection",
-        "status=503 from upstream",
-        "status=200 ok",
-        "zq",
-        "A fatal error has been detected by the Java Runtime",
-        "x" * 27 + "Error",  # full-width completion parity
-        "",
-        "no match here",
-    ]
-    enc = encode_lines(lines)
-    lt = jnp.asarray(enc.u8.T)
-    ln = jnp.asarray(enc.lengths)
-    np.testing.assert_array_equal(
-        np.asarray(sink.cube(lt, ln))[: len(lines)],
-        np.asarray(bare.cube(lt, ln))[: len(lines)],
     )

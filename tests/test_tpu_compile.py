@@ -54,11 +54,10 @@ def bank():
     return PatternBank(load_builtin_pattern_sets())
 
 
-def _tpu_policy(monkeypatch):
-    """MatcherBanks picks its tier layout from jax.default_backend()
-    (ops/match.py); steer it to the TPU layout for the duration of the
-    test, which also makes the Pallas entry points lower through Mosaic
-    instead of the interpreter."""
+def _lower_through_mosaic(monkeypatch):
+    """The Pallas entry points pick interpret mode from
+    jax.default_backend(); steer it to "tpu" for the duration of the
+    test, so they lower through Mosaic instead of the interpreter."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
 
@@ -67,10 +66,10 @@ def test_bitglush_kernel_compiles(one_chip, bank, monkeypatch, rows):
     from log_parser_tpu.ops.bitglush_pallas import bitglush_hits_pallas
     from log_parser_tpu.ops.match import MatcherBanks
 
-    _tpu_policy(monkeypatch)
+    _lower_through_mosaic(monkeypatch)
     mb = MatcherBanks(bank)
-    # the builtin bank's bit tier under the TPU word budget (PERF §9d)
-    assert 64 < mb.bitglush.n_words <= MatcherBanks.BITGLUSH_MAX_WORDS_TPU
+    # the builtin bank's bit tier under its word budget
+    assert 64 < mb.bitglush.n_words <= MatcherBanks.BITGLUSH_MAX_WORDS
     lines = jax.ShapeDtypeStruct((T, rows), jnp.uint8, sharding=one_chip)
     lens = jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=one_chip)
     compiled = (
@@ -82,14 +81,14 @@ def test_bitglush_kernel_compiles(one_chip, bank, monkeypatch, rows):
 
 
 def _union_matchers(bank, monkeypatch):
-    """TPU-policy matchers with the bit tier off: the only layout in which
-    the builtin bank packs union groups; its admitted plan re-splits two
+    """Matchers with the bit tier off: the only layout in which the
+    builtin bank packs union groups; its admitted plan re-splits two
     groups to fit the VMEM budget (chip_smoke.py's union-DFA engine)."""
     from log_parser_tpu.ops.match import MatcherBanks
     from log_parser_tpu.ops.matchdfa_pallas import DFA_VMEM_BUDGET
 
     monkeypatch.setenv("LOG_PARSER_TPU_PALLAS_DFA", "1")
-    _tpu_policy(monkeypatch)
+    _lower_through_mosaic(monkeypatch)
     mb = MatcherBanks(bank, bitglush_max_words=0)
     assert mb.multidfa_pallas_reason == "split"
     assert mb.dfa_kernel_geometry["vmemPerStep"] <= DFA_VMEM_BUDGET
@@ -112,10 +111,10 @@ def test_union_dfa_kernel_compiles(one_chip, bank, monkeypatch, rows):
 
 @pytest.mark.parametrize("kernel", ["xla", "bitglush", "union_dfa"])
 def test_fused_step_compiles(one_chip, bank, monkeypatch, kernel):
-    """The served path's whole device step, as the engine builds it on a
-    TPU: tiers from the TPU policy, every tier on the XLA scan (default),
-    or the bit tier as the Pallas kernel (LOG_PARSER_TPU_PALLAS=1), or
-    the union tier as the Pallas kernel."""
+    """The served path's whole device step, as the engine builds it:
+    every tier on the XLA scan (default), or the bit tier as the Pallas
+    kernel (LOG_PARSER_TPU_PALLAS=1), or the union tier as the Pallas
+    kernel."""
     from log_parser_tpu.config import ScoringConfig
     from log_parser_tpu.ops.fused import FusedMatchScore
     from log_parser_tpu.ops.match import MatcherBanks
@@ -126,7 +125,8 @@ def test_fused_step_compiles(one_chip, bank, monkeypatch, kernel):
         monkeypatch.setenv(
             "LOG_PARSER_TPU_PALLAS", "1" if kernel == "bitglush" else "0"
         )
-        _tpu_policy(monkeypatch)
+        if kernel == "bitglush":
+            _lower_through_mosaic(monkeypatch)
         mb = MatcherBanks(bank)
     fused = FusedMatchScore(bank, ScoringConfig(), mb)
     lines = jax.ShapeDtypeStruct((B, T), jnp.uint8, sharding=one_chip)

@@ -19,7 +19,7 @@ Three modes:
   counts (the pattern-axis / TP-analogue path, stable (line, pattern)
   merge) — mirrors ``test_pattern_sharded.test_random_parity_vs_golden``
   (suite seeds 9000..9002 x n_blocks {1,3,4}).
-- ``--long``: single-device engine under the TPU tier policy (bit tiers
+- ``--long``: single-device engine under the default tier plan (bit tiers
   on) with >31-char-literal libraries and prefix-poisoned corpora — the
   bitglush truncation + host verify / distance-repair paths; mirrors
   ``test_random_long_literal_parity_bit_policy`` (suite seeds
@@ -710,7 +710,6 @@ def run_stream_sweep(start: int, end: int) -> int:
 
 def run_sweep(mode: str, start: int, end: int) -> int:
     from test_engine_parity import (  # the suite's generators ARE the spec
-        _force_bit_policy,
         assert_results_match,
         random_library,
         random_logs,
@@ -760,7 +759,6 @@ def run_sweep(mode: str, start: int, end: int) -> int:
                 sets = random_long_library(rng, rng.randrange(2, 6))
                 config = ScoringConfig(proximity_max_window=rng.choice([5, 100]))
                 engine = AnalysisEngine(sets, config, clock=FakeClock())
-                _force_bit_policy(engine)
                 # guard against a vacuous pass: the mode exists to fuzz
                 # the bit tier's truncation/repair paths
                 assert engine.matchers.bitglush is not None

@@ -11,9 +11,10 @@ Tiers covered:
   and gets deleted with a recorded negative.
 - ``multidfa``  — ops/matchdfa_pallas.py (union-DFA scan, MXU one-hot
   planes instead of the scalar-unit gather) vs the gate-free
-  pair_stepper lax.scan the cube fuses when the kernel is off.  On a
-  CPU-policy host with no native union builder the probe rebuilds the
-  union groups through the Python construction so the A/B still runs.
+  pair_stepper lax.scan the cube runs when the kernel is off.  Where
+  the tier plan leaves no union groups (the bit tier took the columns,
+  or no native union builder) the probe rebuilds them through the
+  Python construction so the A/B still runs.
 
 Both comparisons are bit-exact or the probe says so loudly
 (``verdict: parity_failure`` trumps any timing).  On a non-TPU backend
@@ -112,8 +113,8 @@ def _probe_bitglush(bank, lines_tb, lens, repeats: int) -> dict:
         timeit(lambda: jax.block_until_ready(pallas_scan(lines_tb, lens)),
                n=repeats), 4
     )
-    # carry layouts differ (and may be sink-mode on the CPU policy), so
-    # parity goes through the bank's own column readers
+    # carry layouts differ (the scan may run in sink mode), so parity
+    # goes through the bank's own column readers
     cols_xla = np.asarray(stepper[2](out))
     cols_pallas = np.asarray(bank.columns_from_hits(phits))
     tier["bit_equal"] = bool(np.array_equal(cols_xla, cols_pallas))
@@ -130,11 +131,11 @@ REPACK_MAX_STATES = 2048
 
 def _union_groups(matchers, max_states: int | None = None):
     """The engine's union groups plus their per-group entries (the
-    admission planner needs entries to re-split oversized groups); on
-    hosts where the tier policy left them empty (no native builder), or
-    when a ``max_states`` re-pack is requested, rebuild through the
-    Python union construction over the same regex columns so the kernel
-    A/B runs."""
+    admission planner needs entries to re-split oversized groups); where
+    the tier plan left them empty (the bit tier took the columns, or no
+    native builder), or when a ``max_states`` re-pack is requested,
+    rebuild through the Python union construction over the same regex
+    columns so the kernel A/B runs."""
     if max_states is None and matchers.multi_groups:
         return (
             matchers.multi_groups,

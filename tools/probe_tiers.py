@@ -88,15 +88,8 @@ def main() -> None:
     full = lambda: jax.block_until_ready(cube_jit(lines_tb, lens))
     report["cube_s"] = round(timeit(full, n=args.repeats), 4)
 
-    # cluster A/B LAST: on CPU the shipped path has no cluster, and
-    # building a throwaway one re-points every group's table at the
-    # concatenated buffer (MultiDfaCluster adopts tables) — anything
-    # measured after this line is a hybrid shape, so nothing is
-    if m.multi_groups:
-        from log_parser_tpu.ops.match import MultiDfaCluster
-
-        cluster = m.multi_cluster or MultiDfaCluster(m.multi_groups)
-        fn = scan_only([cluster.pair_stepper(B, lens)])
+    if m.multi_cluster is not None:
+        fn = scan_only([m.multi_cluster.pair_stepper(B, lens)])
         report["multi_cluster_s"] = round(timeit(fn, n=args.repeats), 4)
 
     print(json.dumps(report))
