@@ -104,6 +104,9 @@ METRICS = {
     "logparser_line_cache_probe_collisions_total": (
         "counter", "Line-cache keys whose probe led to a different line "
         "(looked up or stored as misses)."),
+    "logparser_line_cache_index_probes_total": (
+        "counter", "Line-cache index buckets read past each key's home "
+        "bucket, over lookups and stores."),
     "logparser_extract_hit_coords_total": (
         "counter", "(line, column) match-bit coordinates the line-cache "
         "extract carried, by tenant."),
@@ -265,7 +268,8 @@ TRACE_BLOCKS = {
                   "logparser_line_cache_misses_total",
                   "logparser_line_cache_evictions_total",
                   "logparser_line_cache_resident_bytes",
-                  "logparser_line_cache_probe_collisions_total"),
+                  "logparser_line_cache_probe_collisions_total",
+                  "logparser_line_cache_index_probes_total"),
     "kernel": ("logparser_kernel_batches_total",
                "logparser_kernel_rows_total",
                "logparser_kernel_plan_vmem_bytes",

@@ -86,6 +86,16 @@ _COMPARE_BYTES = 1 << 24
 # the stamp of a free slot: never the oldest, never evicted again
 _FREE = np.iinfo(np.int64).max
 
+# an index bucket: empty ends a probe walk; deleted (an evicted entry's)
+# is walked past, and taken again by the next key stored there
+_EMPTY, _DELETED = -1, -2
+
+# buckets a round of probe walks reads, over all the walks still open
+_WALK_READS = 1 << 10
+
+# an index's buckets, and so its slots, are numbered in int32
+_MAX_BUCKETS = 1 << 31
+
 
 def _fmix64(x: np.ndarray) -> np.ndarray:
     """murmur3's 64-bit finalizer, in place: a bijection with fmix(0) = 0."""
@@ -265,24 +275,39 @@ def _classes(keys: LineKeys):
         yield k, idx[cls == k]
 
 
+def _buckets(cap: int) -> int:
+    """Index buckets for ``cap`` slots: the least power of two that is at
+    least twice as many (none for none)."""
+    return 1 << (2 * cap - 1).bit_length() if cap else 0
+
+
 class _Table:
     """The cached lines of one word class, ``k`` content words each, in
     arrays of capacity ``cap``: slots ``[0, n)`` are in use but those in
     ``free`` (evicted, stamp ``_FREE``), which the next appends take
-    first. ``sp``/``sid`` index the live entries by probe, ascending,
-    one entry a probe."""
+    first. ``index`` finds a live entry by its probe, one entry a probe:
+    an open-addressed hash of at least two buckets a slot, each holding
+    a slot, ``_EMPTY`` or ``_DELETED`` (an evicted entry's). A probe's
+    home is its top bits, and its entry the first bucket on (linear
+    probing) whose slot has that probe; a walk ends at an empty bucket.
+    Each slot keeps its probe and its bucket, so a call's upkeep follows
+    the keys it stores and the entries it evicts, never those that stay."""
 
     def __init__(self, k: int, row_bytes: int):
         self.k = k
         self.n = self.epoch = 0  # epoch: compactions, which move entries
+        self.layout = 0  # index rebuilds, which move every bucket
+        self.deleted = 0  # _DELETED buckets
         self.free = np.zeros(0, dtype=np.int64)
         self.words = np.zeros((0, k), dtype=np.uint64)
         self.lengths = np.zeros(0, dtype=np.int32)
         self.packed = np.zeros((0, row_bytes), dtype=np.uint8)
         self.stamp = np.zeros(0, dtype=np.int64)  # the call that last touched it
-        self.sp, self.sid = np.zeros(0, np.uint64), np.zeros(0, np.int64)
-        # a slot's bytes in every array, the probe index included
-        self.entry_bytes = k * 8 + 4 + row_bytes + 8 + 16
+        self.probe = np.zeros(0, dtype=np.uint64)
+        self.bucket = np.zeros(0, dtype=np.int32)
+        self.index = np.zeros(0, dtype=np.int32)
+        # a slot's bytes in every array of capacity; the index adds its own
+        self.entry_bytes = k * 8 + 4 + row_bytes + 8 + 8 + 4
 
     @property
     def cap(self) -> int:
@@ -292,71 +317,207 @@ class _Table:
     def live(self) -> int:
         return self.n - self.free.size
 
-    def find(self, keys: LineKeys, idx: np.ndarray):
-        """``(entry, collided)`` for keys ``idx``: the entry holding each
-        key's line (-1 where none does), and the keys whose probe leads to
-        a different line."""
+    @property
+    def nbytes(self) -> int:
+        return self.cap * self.entry_bytes + self.index.nbytes
+
+    def bytes_at(self, cap: int, tight: bool = False) -> int:
+        """The table's bytes at ``cap`` slots: grown to them, or (``tight``)
+        with the least index that holds them."""
+        size = _buckets(cap)
+        if not tight:
+            size = max(size, self.index.size)
+        return cap * self.entry_bytes + size * self.index.itemsize
+
+    def _home(self, probes: np.ndarray) -> np.ndarray:
+        shift = np.uint64(65 - self.index.size.bit_length())
+        return (probes >> shift).astype(np.int64)
+
+    def _walk(self, start: np.ndarray, stop) -> np.ndarray:
+        """For each walk ``i`` from bucket ``start[i]``, the first bucket
+        on whose slot ``stop(i, slots)`` accepts, unwrapped (past the
+        last bucket the walk goes on at the first). Each round reads a
+        window of buckets for every walk still open: one while a call's
+        many keys are open, more as they end, so a long run of full
+        buckets costs a few rounds and never a loop over keys. A quarter
+        of the buckets at least are empty, so a walk past every bucket
+        means a corrupt index: it raises rather than spin under the lock."""
+        mask = self.index.size - 1
+        end = start.copy()  # where each open walk is, then where it ended
+        open_ = np.arange(start.size)
+        walked = 0
+        while open_.size:
+            if walked > mask:
+                raise RuntimeError("line cache index: a walk found no stop")
+            w = min(64, max(1, _WALK_READS // open_.size))
+            walked += w
+            if w == 1:  # a row-wise argmax of one column costs more than the reads
+                at = end[open_]
+                done = stop(open_, self.index[at & mask][:, None])[:, 0]
+            else:
+                ok = stop(open_, self.index[
+                    (end[open_, None] + np.arange(w)) & mask])
+                j = ok.argmax(axis=1)
+                done = ok[np.arange(open_.size), j]
+                end[open_[done]] += j[done]
+            open_ = open_[~done]
+            end[open_] += w
+        return end
+
+    def find(self, keys: LineKeys, idx: np.ndarray, store: bool = False):
+        """``(entry, collided, at, reads)`` for keys ``idx``: the entry
+        holding each key's line (-1 where none does), the keys whose
+        probe leads to a different line, and the buckets the walks read
+        past their homes. With ``store``, ``at`` is where each absent
+        key's walk passed its first free bucket, for :meth:`append`."""
+        probes = keys.probes[idx]
         eid = np.full(idx.size, -1, dtype=np.int64)
         collided = np.zeros(idx.size, dtype=bool)
-        if not self.sp.size:
-            return eid, collided
-        probes = keys.probes[idx]
-        # sorted needles search ~8x faster than random ones at this size
-        o = np.argsort(probes)
-        pos = np.empty(idx.size, dtype=np.int64)
-        pos[o] = np.minimum(np.searchsorted(self.sp, probes[o]), self.sp.size - 1)
-        cand = np.flatnonzero(self.sp[pos] == probes)
-        e, i = self.sid[pos[cand]], idx[cand]
+        if not self.live:
+            return eid, collided, None, 0
+        home = self._home(probes)
+
+        def stop(i, s, free):
+            match = (s >= 0) & (self.probe[np.maximum(s, 0)] == probes[i, None])
+            return match | (s < 0 if free else s == _EMPTY)
+
+        end = self._walk(home, lambda i, s: stop(i, s, store))
+        at = end
+        if store:
+            # a walk that met a deleted bucket goes on to the line or an
+            # empty bucket; the deleted one is where the line would go
+            on = np.flatnonzero(self.index[end & (self.index.size - 1)]
+                                == _DELETED)
+            if on.size:
+                end = end.copy()
+                end[on] = self._walk(
+                    end[on] + 1, lambda i, s: stop(on[i], s, False))
+        s = self.index[end & (self.index.size - 1)]
+        cand = np.flatnonzero(s >= 0)
+        e, i = s[cand].astype(np.int64), idx[cand]
         ok = (self.lengths[e] == keys.lengths[i]) & (
             self.words[e] == keys.words_of(i, self.k)
         ).all(axis=1)
         eid[cand[ok]] = e[ok]
         collided[cand[~ok]] = True
-        return eid, collided
+        return eid, collided, at, int((end - home).sum())
 
-    def resize(self, cap: int) -> None:
+    def _place(self, at: np.ndarray, slots: np.ndarray, free=False) -> int:
+        """Put ``slots`` (in probe order, their probes not in the index)
+        in the first free bucket from ``at`` on (``free``: the buckets
+        ``at`` are free), one slot a bucket a round, the first in probe
+        order: the others walk on from the bucket past it. Returns the
+        buckets read past ``at``."""
+        mask = self.index.size - 1
+        end = at if free else self._walk(at, lambda i, s: s < 0)
+        placed = np.empty(slots.size, dtype=np.int64)
+        open_ = np.arange(slots.size)
+        while True:
+            pos = end & mask
+            if (pos[1:] >= pos[:-1]).all():
+                # walks from buckets in order end in order (the first free
+                # bucket on is monotone), so a bucket's walks are neighbours
+                first = np.flatnonzero(np.r_[True, pos[1:] != pos[:-1]])
+            else:  # a walk went on past the last bucket
+                first = np.unique(pos, return_index=True)[1]
+            won, pos = slots[open_[first]], pos[first]
+            self.deleted -= int((self.index[pos] == _DELETED).sum())
+            self.index[pos] = won
+            self.bucket[won] = pos
+            placed[open_[first]] = end[first]
+            if first.size == open_.size:
+                return int((placed - at).sum())
+            lost = np.ones(open_.size, dtype=bool)
+            lost[first] = False
+            open_ = open_[lost]
+            end = self._walk(end[lost] + 1, lambda i, s: s < 0)
+
+    def _rebuild(self, size: int) -> None:
+        """A fresh index of ``size`` buckets over the live entries, with
+        no deleted buckets. In order of their homes, linear probing puts
+        each entry at its home or just past the one before, so one
+        running maximum places them all; the few past the last bucket go
+        on at the first. The old index lists the entries nearly in that
+        order already, so a merge sort finds it in about one pass."""
+        self.layout += 1
+        self.deleted = 0
+        slots = self.index[self.index >= 0].astype(np.int64)
+        self.index = np.full(size, _EMPTY, dtype=np.int32)
+        if not slots.size:
+            return
+        home = self._home(self.probe[slots])
+        order = np.argsort(home, kind="stable")
+        slots, home = slots[order], home[order]
+        r = np.arange(slots.size)
+        pos = np.maximum.accumulate(home - r) + r
+        fits = pos < size
+        self.index[pos[fits]] = slots[fits]
+        self.bucket[slots[fits]] = pos[fits]
+        if not fits.all():
+            self._place(np.full(int((~fits).sum()), size), slots[~fits])
+
+    def resize(self, cap: int, tight: bool = False) -> None:
         """Grow or shrink the arrays to ``cap`` slots in place: a realloc,
         which does not copy the entries kept. ``ndarray.resize`` refuses
         an array that anything else references, so each is resized
-        through its attribute."""
+        through its attribute. The index is rebuilt where ``cap``
+        outgrows it, or shrinks to an eighth of it (between two rebuilds
+        the capacity has doubled or halved), or (``tight``) to the least
+        that holds it."""
         self.words.resize((cap, self.k))
         self.lengths.resize(cap)
         self.packed.resize((cap, self.packed.shape[1]))
         self.stamp.resize(cap)
+        self.probe.resize(cap)
+        self.bucket.resize(cap)
+        size = self.index.size
+        if 2 * cap > size or 8 * cap <= size or tight and _buckets(cap) < size:
+            self._rebuild(_buckets(cap))
 
     def drop(self, slots: np.ndarray) -> None:
-        """Evict the entries in ``slots``: out of the index, their slots
-        free for reuse."""
-        gone = np.zeros(self.n, dtype=bool)
-        gone[slots] = True
-        keep = ~gone[self.sid]
-        self.sp, self.sid = self.sp[keep], self.sid[keep]
+        """Evict the entries in ``slots``: their buckets deleted, their
+        slots free for reuse."""
+        self.index[self.bucket[slots]] = _DELETED
+        self.deleted += slots.size
         self.stamp[slots] = _FREE
         self.free = np.concatenate([self.free, slots])
 
     def copy(self, src: np.ndarray, dst: np.ndarray) -> None:
         """Copy the entries in slots ``src`` to slots ``dst``."""
-        for name in ("words", "lengths", "packed", "stamp"):
+        for name in ("words", "lengths", "packed", "stamp", "probe", "bucket"):
             arr = getattr(self, name)
             arr[dst] = arr[src]
 
-    def compact(self) -> None:
+    def compact(self, tight: bool = False) -> None:
         """Close up the free slots, down to a capacity of ``live``: the
-        live entries past it move into the free slots below it."""
+        live entries past it move into the free slots below it, and
+        their buckets point at them there (``tight``: see :meth:`resize`)."""
         self.epoch += 1
         live = self.live
         holes = self.free[self.free < live]
         movers = live + np.flatnonzero(self.stamp[live : self.n] != _FREE)
+        self.index[self.bucket[movers]] = holes
         self.copy(movers, holes)
-        to = np.arange(self.n)
-        to[movers] = holes
-        self.sid = to[self.sid]
         self.n, self.free = live, self.free[:0]
-        self.resize(live)
+        self.resize(live, tight)
 
-    def append(self, keys: LineKeys, idx: np.ndarray, packed, gen: int) -> None:
+    def append(self, keys: LineKeys, idx: np.ndarray, packed, gen: int,
+               at, layout: int) -> int:
         """Store keys ``idx`` (in probe order, none of them present),
-        free slots first."""
+        free slots first, each in the index from ``at`` on: the bucket
+        its :meth:`find` walk found free under index ``layout``, or its
+        home where the index has been rebuilt since. The deleted buckets
+        are cleared first where they would fill more than half the index,
+        or, in a table so full that they must, more than half its live
+        entries: a rebuild then follows at least that many evictions.
+        Returns the buckets read past ``at``."""
+        fill = self.live + idx.size
+        if self.deleted > max(self.index.size // 2 - fill, fill // 2):
+            self._rebuild(self.index.size)
+        probes = keys.probes[idx]
+        free = at is not None and self.layout == layout
+        if not free:
+            at = self._home(probes)
         f = min(idx.size, self.free.size)
         slots = np.concatenate(
             [self.free[:f], np.arange(self.n, self.n + idx.size - f)]
@@ -367,15 +528,8 @@ class _Table:
         self.lengths[slots] = keys.lengths[idx]
         self.packed[slots] = packed
         self.stamp[slots] = gen
-        # merge into the index: the new probes are sorted already
-        probes = keys.probes[idx]
-        at = np.searchsorted(self.sp, probes) + np.arange(idx.size)
-        old = np.ones(self.sp.size + idx.size, dtype=bool)
-        old[at] = False
-        for name, new in (("sp", probes), ("sid", slots)):
-            merged = np.empty(old.size, dtype=new.dtype)
-            merged[at], merged[old] = new, getattr(self, name)
-            setattr(self, name, merged)
+        self.probe[slots] = probes
+        return self._place(at, slots, free)
 
 
 class CachedRows(NamedTuple):
@@ -394,12 +548,13 @@ class LineCache:
 
     One :class:`_Table` a word class holds each cached line's content
     words, length, packed bit row (``np.packbits``: a 108-column bank
-    costs 14 bytes a line) and recency stamp, indexed by the sorted
-    probes. Lookup and populate are array operations under one lock
+    costs 14 bytes a line), recency stamp and probe, in a hashed index of
+    the probes. Lookup and populate are array operations under one lock
     acquisition a call; a hit needs equal words and length, so a row is
     only ever served to the bytes it was computed for. ``budget_bytes``
-    bounds the arrays' bytes at their capacity: past it the oldest
-    entries are evicted, never those the current call touched."""
+    bounds the arrays' bytes at their capacity, the index's included:
+    past it the oldest entries are evicted, never those the current call
+    touched."""
 
     def __init__(self, n_columns: int, budget_bytes: int):
         self.lock = threading.Lock()
@@ -414,6 +569,7 @@ class LineCache:
         self.evictions = 0
         self.epoch_flushes = 0
         self.probe_collisions = 0
+        self.index_probes = 0
 
     def _set_columns(self, n_columns: int) -> None:
         self.n_columns = int(n_columns)
@@ -421,7 +577,7 @@ class LineCache:
         self._tables: dict[int, _Table] = {}
 
     def _resident(self) -> int:
-        return sum(t.cap * t.entry_bytes for t in self._tables.values())
+        return sum(t.nbytes for t in self._tables.values())
 
     # ------------------------------------------------------------- data path
 
@@ -439,8 +595,9 @@ class LineCache:
                 table = self._tables.get(k)
                 if table is None:
                     continue
-                eid, collided = table.find(keys, idx)
+                eid, collided, _, reads = table.find(keys, idx)
                 self.probe_collisions += int(collided.sum())
+                self.index_probes += reads
                 e = eid[eid >= 0]
                 table.stamp[e] = self._gen
                 touched.append((table, table.epoch, e))
@@ -476,9 +633,10 @@ class LineCache:
         sel = np.flatnonzero(keys.storable)
         if sel.size < keys.storable.size:
             keys, packed = keys.take(sel), packed[sel]
-        # outside the lock: each class's keys in probe order (the index
-        # merge wants it), where a later key of this call under the same
-        # probe (the same line, or a collision) sits right after the first
+        # outside the lock: each class's keys in probe order (the index's
+        # walks then read it in order), where a later key of this call
+        # under the same probe (the same line, or a collision) sits right
+        # after the first
         classes = []
         for k, idx in _classes(keys):
             idx = idx[np.argsort(keys.probes[idx])]
@@ -504,36 +662,61 @@ class LineCache:
                 table = self._tables.get(k) or self._tables.setdefault(
                     k, _Table(k, self._row_bytes)
                 )
-                eid, coll = table.find(keys, idx)
+                layout = table.layout
+                eid, coll, at, reads = table.find(keys, idx, store=True)
                 table.stamp[eid[eid >= 0]] = self._gen
                 self.probe_collisions += int((collided | coll).sum())
-                new = idx[(eid < 0) & ~coll & ~later]
-                self._insert(table, keys, new, packed[new])
+                self.index_probes += reads
+                new = (eid < 0) & ~coll & ~later
+                self._insert(table, keys, idx[new], packed[idx[new]],
+                             None if at is None else at[new], layout)
             for k in [k for k, t in self._tables.items() if not t.live]:
                 del self._tables[k]
 
-    def _insert(self, table: _Table, keys: LineKeys, idx, packed) -> None:
-        """Store in ``table``: in its free slots, then in capacity grown
-        within the budget. Where the budget has no room, the oldest
-        entries of every class make way, at least a sixteenth of the
-        budget at a time: an eviction costs a pass over every entry, so
-        calls far smaller than the budget share one. What still does not
-        fit is not stored."""
-        eb = table.entry_bytes
+    def _insert(self, table: _Table, keys: LineKeys, idx, packed, at,
+                layout: int) -> None:
+        """Store in ``table`` (``at``, ``layout``: where :meth:`_Table.find`
+        left each key): in its free slots, then in capacity grown within
+        the budget. Where the budget has no room, the oldest entries of
+        every class make way, at least a sixteenth of the budget at a
+        time: an eviction costs a pass over every entry's stamp, so calls
+        far smaller than the budget share one. What still does not fit
+        is not stored."""
         short = idx.size - table.free.size - (table.cap - table.n)
-        room = max(0, self.budget_bytes - self._resident()) // eb
-        if short > room:
-            self._evict(max((short - room) * eb, self.budget_bytes // 16),
-                        table)
+        grow = self._room(table, short)
+        if grow < short:
+            over = (self._resident() - table.nbytes
+                    + table.bytes_at(table.cap + short) - self.budget_bytes)
+            self._evict(max(over, self.budget_bytes // 16), table)
             short = idx.size - table.free.size - (table.cap - table.n)
-            room = max(0, self.budget_bytes - self._resident()) // eb
-        grow = max(0, min(short, room))
+            grow = self._room(table, short)
         if grow:
             table.resize(table.cap + grow)
         if short > grow:
             idx, packed = idx[: grow - short], packed[: grow - short]
+            at = None if at is None else at[: grow - short]
         if idx.size:
-            table.append(keys, idx, packed, self._gen)
+            self.index_probes += table.append(
+                keys, idx, packed, self._gen, at, layout)
+
+    def _room(self, table: _Table, want: int) -> int:
+        """The most slots, up to ``want``, that ``table`` can grow by
+        within the budget: at each index size from its own up to the one
+        ``want`` needs, the slots that size holds and the budget pays
+        for besides it. Buckets and slots are int32, so an index stops
+        at 2^31 buckets."""
+        if want <= 0:
+            return 0
+        spare = self.budget_bytes - self._resident() + table.nbytes
+        cap, eb = table.cap, table.entry_bytes
+        size = max(table.index.size, _buckets(cap + 1))
+        best = 0
+        while True:
+            fits = (spare - size * table.index.itemsize) // eb
+            best = max(best, min(cap + want, size // 2, fits) - cap)
+            if 2 * (cap + want) <= size or size >= _MAX_BUCKETS:
+                return best
+            size *= 2
 
     def _evict(self, need: int, keep: _Table | None = None) -> None:
         """Evict the oldest entries of every class until they free
@@ -557,7 +740,12 @@ class LineCache:
         sel = sel[np.argsort(stamps[sel], kind="stable")]
         which = np.searchsorted(ends, sel, side="right")
         n = int(np.searchsorted(np.cumsum(eb[which]), need)) + 1
-        sel, which = sel[:n], which[:n]
+        self._drop(tables, ends, sel[:n], which[:n], keep)
+
+    def _drop(self, tables, ends, sel, which, keep=None) -> None:
+        """Evict entries ``sel``, numbered over the ``tables``' slots in
+        turn (table ``which``, ending at ``ends``): every table that loses
+        any but ``keep`` is compacted."""
         for j, t in enumerate(tables):
             slots = sel[which == j] - (ends[j] - t.n)
             if slots.size:
@@ -569,14 +757,36 @@ class LineCache:
     def set_budget(self, budget_bytes: int) -> None:
         """Re-arbitrate the byte budget live (fleet/budget.py pushes
         shares through ``POST /admin/budget``): a shrink evicts the oldest
-        entries down to the new budget at once."""
+        entries down to the new budget at once. An index shrinks by
+        halves, so the bytes an eviction frees are not its entries'
+        alone: a bisection finds the fewest oldest entries whose eviction,
+        each table's index shrunk to the least that holds what stays,
+        fits the budget."""
         with self.lock:
-            self.budget_bytes = max(0, int(budget_bytes))
-            if self._resident() > self.budget_bytes:
-                self._gen += 1
-                for t in self._tables.values():
-                    t.compact()
-                self._evict(self._resident() - self.budget_bytes)
+            self.budget_bytes = budget = max(0, int(budget_bytes))
+            if self._resident() <= budget:
+                return
+            tables = list(self._tables.values())
+            for t in tables:
+                t.compact(tight=True)
+            stamps = np.concatenate([t.stamp[: t.n] for t in tables])
+            ends = np.cumsum([t.n for t in tables])
+            age = np.argsort(stamps, kind="stable")
+            which = np.searchsorted(ends, age, side="right")
+            live = np.array([t.n for t in tables])
+
+            def fits(m: int) -> bool:
+                left = live - np.bincount(which[:m], minlength=len(tables))
+                return sum(t.bytes_at(c, tight=True)
+                           for t, c in zip(tables, left.tolist())) <= budget
+
+            lo, hi = 0, age.size  # with every entry evicted, nothing is left
+            while lo < hi:
+                mid = (lo + hi) // 2
+                lo, hi = (lo, mid) if fits(mid) else (mid + 1, hi)
+            self._drop(tables, ends, age[:lo], which[:lo])
+            for t in tables:
+                t.compact(tight=True)
 
     def note_residual(self, rows: int, fanout: int) -> None:
         """Account one residual dispatch: ``rows`` unique device rows
@@ -612,6 +822,7 @@ class LineCache:
                 "evictions": self.evictions,
                 "epochFlushes": self.epoch_flushes,
                 "probeCollisions": self.probe_collisions,
+                "indexProbes": self.index_probes,
             }
 
 
@@ -624,6 +835,7 @@ CACHE_METRIC_SAMPLES = (
     ("evictions", "logparser_line_cache_evictions_total", {}),
     ("residentBytes", "logparser_line_cache_resident_bytes", {}),
     ("probeCollisions", "logparser_line_cache_probe_collisions_total", {}),
+    ("indexProbes", "logparser_line_cache_index_probes_total", {}),
 )
 
 

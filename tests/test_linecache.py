@@ -336,7 +336,7 @@ class TestInvalidation:
         oom_col = int(engine.bank.primary_columns[oom_pat])
         with cache.lock:
             table = cache._tables[int(word_class(keys.lengths)[0])]
-            (eid,), _ = table.find(keys, np.arange(1))
+            (eid,), *_ = table.find(keys, np.arange(1))
             row = np.unpackbits(table.packed[eid], count=cache.n_columns)
             row[oom_col] = 0
             table.packed[eid] = np.packbits(row)
@@ -525,6 +525,22 @@ def _bits_of(line: bytes, n_columns: int = 24) -> np.ndarray:
     return rng.random(n_columns) < 0.3
 
 
+def _bytes(k: int, cap: int) -> int:
+    """A ``k``-word table's bytes at ``cap`` slots of a 24-column bank,
+    with the least index that holds them, as the table counts them."""
+    return lc._Table(k, 3).bytes_at(cap, tight=True)
+
+
+def _array_bytes(cache: LineCache) -> int:
+    """The bytes every table's arrays really hold, the index's included."""
+    return sum(
+        a.nbytes
+        for t in cache._tables.values()
+        for a in (t.words, t.lengths, t.packed, t.stamp, t.probe, t.bucket,
+                  t.index)
+    )
+
+
 def _dict_loop(corpus):
     """The scalar reference: slots by first appearance, keyed by bytes."""
     slot_of: dict[bytes, int] = {}
@@ -641,7 +657,7 @@ class TestContentKeys:
 
     def test_eviction_keeps_parity_and_the_current_call(self):
         # a budget of 100 entries against 60 unique lines a call
-        cache, ref = LineCache(24, 100 * (2 * 8 + 4 + 3 + 8 + 16)), {}
+        cache, ref = LineCache(24, _bytes(2, 100)), {}
         for r in range(4):
             lines = [f"round {r} line {i}" for i in range(60)]
             found, keys = self._serve(cache, ref, lines + lines[:30])
@@ -658,8 +674,7 @@ class TestContentKeys:
     def test_eviction_never_drops_what_the_call_touched(self):
         # room for four entries; the second call touches L1 and brings
         # four new lines: L2-L4 go, L1 stays, and three new lines fit
-        eb = 2 * 8 + 4 + 3 + 8 + 16
-        cache = LineCache(24, 4 * eb)
+        cache = LineCache(24, _bytes(2, 4))
         old = [b"line %d" % i for i in range(1, 5)]
         cache.populate(line_keys(old), np.stack([_bits_of(b) for b in old]))
         new = [b"new %d" % i for i in range(4)]
@@ -668,7 +683,7 @@ class TestContentKeys:
         assert _rows(cache, old)[1:] == [None] * 3
         np.testing.assert_array_equal(_rows(cache, old[:1])[0], _bits_of(old[0]))
         s = cache.stats()
-        assert s["entries"] == 4 and s["residentBytes"] <= 4 * eb
+        assert s["entries"] == 4 and s["residentBytes"] <= _bytes(2, 4)
 
     def test_eviction_cost_follows_entries_not_calls(self):
         # a server that ran ~1e9 calls before its first eviction: evicting
@@ -676,8 +691,7 @@ class TestContentKeys:
         # entries, not by the calls since the oldest stamp
         import tracemalloc
 
-        eb = 2 * 8 + 4 + 3 + 8 + 16
-        cache = LineCache(24, 50 * eb)
+        cache = LineCache(24, _bytes(2, 50))
         old = [b"old line %d" % i for i in range(40)]
         new = [b"new line %d" % i for i in range(30)]
 
@@ -690,21 +704,135 @@ class TestContentKeys:
             cache._gen = 10**9
             store(new)
             cache._gen = 2 * 10**9
-            cache.set_budget(30 * eb)
+            cache.set_budget(_bytes(2, 30))
             peak = tracemalloc.get_traced_memory()[1]
         finally:
             tracemalloc.stop()
         assert peak < 1 << 20
-        assert cache.stats()["residentBytes"] <= 30 * eb
+        assert cache.stats()["residentBytes"] <= _bytes(2, 30)
         for line, row in zip(new, _rows(cache, new)):
             np.testing.assert_array_equal(row, _bits_of(line))
         assert all(r is None for r in _rows(cache, old))
+
+    def test_upkeep_follows_the_call_not_the_resident_entries(self):
+        # 200k resident entries: a 100-key populate or lookup allocates
+        # under 1 MB, and an eviction beyond its partition of the stamps
+        # (two arrays of the resident entries) by the entries it evicts:
+        # nothing merges, filters or rebuilds an index of every entry
+        import tracemalloc
+
+        n = 200_000
+        cache = LineCache(24, _bytes(2, n))
+        fill = [b"fill %06d" % i for i in range(n)]
+        cache.populate(line_keys(fill), np.zeros((n, 24), dtype=bool))
+        assert cache.stats()["entries"] == n
+
+        def traced(fn):
+            tracemalloc.start()
+            try:
+                fn()
+                return tracemalloc.get_traced_memory()[1]
+            finally:
+                tracemalloc.stop()
+
+        def call(tag):
+            lines = [b"%s %06d" % (tag, i) for i in range(100)]
+            return lines, line_keys(lines), np.stack([_bits_of(b) for b in lines])
+
+        # the first call past the budget evicts a sixteenth of it
+        _, keys, rows = call(b"next")
+        peak = traced(lambda: cache.populate(keys, rows))
+        evicted = cache.stats()["evictions"]
+        assert 0 < evicted < n // 8
+        assert peak - 2 * 8 * n < 1 << 20
+        # the next calls store into the slots it freed
+        lines, keys, rows = call(b"more")
+        assert traced(lambda: cache.populate(keys, rows)) < 1 << 20
+        assert traced(lambda: cache.lookup(keys)) < 1 << 20
+        assert cache.stats()["evictions"] == evicted
+        for line, row in zip(lines, _rows(cache, lines)):
+            np.testing.assert_array_equal(row, _bits_of(line))
+
+    @pytest.mark.parametrize("one_run", [False, True], ids=["hashed", "one_run"])
+    def test_churn_keeps_the_index_equal_to_a_dict(self, monkeypatch, one_run):
+        """One table churned through inserts, evictions, re-inserts of
+        evicted lines, compactions (budget shrinks) and rebuilds: after
+        each call every line the table holds hits with the dict model's
+        row, every other line misses, and no probe collides. ``one_run``
+        keeps each probe's low half only, so every key's home is bucket
+        0 and the index is one linear run."""
+        import random
+
+        if one_run:
+            real = lc.probe64
+            monkeypatch.setattr(
+                lc, "probe64",
+                lambda words, lengths: real(words, lengths) >> np.uint64(32))
+        rebuilds = []
+        rebuild = lc._Table._rebuild
+
+        def counted(table, size):
+            rebuilds.append((table.index.size, size))
+            rebuild(table, size)
+
+        monkeypatch.setattr(lc._Table, "_rebuild", counted)
+        rng = random.Random(28)
+        pool = [b"churn line %05d" % i for i in range(600 if one_run else 3000)]
+        model = {b: _bits_of(b) for b in pool}
+        pool_keys = line_keys(pool)
+        cache = LineCache(24, _bytes(2, 400))
+        stored, reinserted, epochs = set(), 0, set()
+        for c in range(40 if one_run else 120):
+            if c % 30 == 29:  # a shrink compacts; the next calls grow back
+                cache.set_budget(_bytes(2, rng.randrange(60, 200)))
+            elif c % 30 == 0:
+                cache.set_budget(_bytes(2, 400))
+            lines = rng.sample(pool, rng.randrange(1, 60 if one_run else 300))
+            keys = line_keys(lines)
+            found = cache.lookup(keys)
+            got = cache.unpack(found.packed)
+            for j, r in enumerate(found.row.tolist()):
+                if r >= 0:
+                    np.testing.assert_array_equal(got[r], model[lines[j]])
+            miss = np.flatnonzero(found.row < 0)
+            reinserted += sum(lines[j] in stored for j in miss)
+            stored.update(lines[j] for j in miss)
+            cache.populate(
+                keys.take(miss),
+                np.stack([model[lines[j]] for j in miss]) if miss.size
+                else np.zeros((0, 24), dtype=bool),
+                found,
+            )
+            table = cache._tables[2]
+            epochs.add(table.epoch)
+            live = np.flatnonzero(table.stamp[: table.n] != lc._FREE)
+            held = {table.words[e].view(np.uint8)[: table.lengths[e]].tobytes()
+                    for e in live.tolist()}
+            eid = table.find(pool_keys, np.arange(len(pool)))[0]
+            assert (eid >= 0).tolist() == [b in held for b in pool]
+            rows = cache.unpack(table.packed[eid[eid >= 0]])
+            want = [model[b] for b in pool if b in held]
+            np.testing.assert_array_equal(rows, np.array(want).reshape(rows.shape))
+            s = cache.stats()
+            assert s["entries"] == len(held)
+            assert s["residentBytes"] == _array_bytes(cache) <= cache.budget_bytes
+        s = cache.stats()
+        assert s["probeCollisions"] == 0 and s["evictions"] > 0
+        assert reinserted > 0 and len(epochs) > 1
+        # the index grew and shrank; hashed, it was cleared of deleted
+        # buckets too (one run's inserts take them back at its head)
+        assert sum(new > old for old, new in rebuilds) >= 2
+        assert any(new < old for old, new in rebuilds)
+        if one_run:
+            assert s["indexProbes"] > 100 * len(pool)
+        else:
+            assert sum(new == old for old, new in rebuilds) >= 2
 
     def test_eviction_takes_the_oldest_entries_of_every_class(self):
         # calls of one- and two-word lines under a full budget: each call
         # evicts the oldest calls' lines, whatever their class, and every
         # table closed up or grown keeps its rows and its index
-        cache = LineCache(24, 200 * (2 * 8 + 4 + 3 + 8 + 16))
+        cache = LineCache(24, _bytes(2, 200))
         calls = []
         for r in range(12):
             lines = [b"tick %03d %04d" % (r, i) for i in range(40 + 7 * (r % 3))]
@@ -714,8 +842,7 @@ class TestContentKeys:
             calls.append(lines)
             s = cache.stats()
             assert s["residentBytes"] <= cache.budget_bytes
-            assert s["residentBytes"] == sum(
-                t.cap * t.entry_bytes for t in cache._tables.values())
+            assert s["residentBytes"] == _array_bytes(cache)
         kept = [[r is not None for r in _rows(cache, lines)] for lines in calls]
         # whole calls survive from the newest back, the oldest not at all
         whole = [all(k) for k in kept]
@@ -727,8 +854,8 @@ class TestContentKeys:
                 np.testing.assert_array_equal(row, _bits_of(line))
 
     def test_small_calls_share_one_eviction_of_a_sixteenth(self):
-        eb = 2 * 8 + 4 + 3 + 8 + 16
-        cache = LineCache(24, 160 * eb)
+        budget = _bytes(2, 160)
+        cache = LineCache(24, budget)
 
         def store(lines):
             cache.populate(line_keys(lines), np.stack([_bits_of(b) for b in lines]))
@@ -736,22 +863,25 @@ class TestContentKeys:
         for r in range(4):
             store([b"fill %d %03d" % (r, i) for i in range(40)])
         assert cache.stats()["evictions"] == 0
-        # two lines need 94 bytes; the first such call evicts 470 (ten
-        # entries) and the next four calls store into what it freed
+        assert cache.stats()["residentBytes"] == budget
+        # two lines need 86 bytes; the first such call evicts a sixteenth
+        # of the budget, 558 bytes (13 entries), and the next five calls
+        # store into what it freed
+        sixteenth = -(-(budget // 16) // lc._Table(2, 3).entry_bytes)
+        assert sixteenth == 13
         store([b"small 0 %d" % i for i in range(2)])
-        assert cache.stats()["evictions"] == 10
-        for r in range(1, 5):
+        assert cache.stats()["evictions"] == sixteenth
+        for r in range(1, 6):
             store([b"small %d %d" % (r, i) for i in range(2)])
         s = cache.stats()
-        assert s["evictions"] == 10 and s["entries"] == 160
+        assert s["evictions"] == sixteenth and s["entries"] == 159
         gone = [sum(r is None for r in _rows(cache, [b"fill %d %03d" % (r, i)
                                                     for i in range(40)]))
                 for r in range(4)]
-        assert gone == [10, 0, 0, 0]  # of the oldest call
+        assert gone == [sixteenth, 0, 0, 0]  # of the oldest call
 
     def test_a_new_class_takes_the_other_classes_oldest_bytes(self):
-        eb2 = 2 * 8 + 4 + 3 + 8 + 16
-        cache = LineCache(24, 200 * eb2)
+        cache = LineCache(24, _bytes(2, 200))
         calls = [[b"tick %02d %04d" % (r, i) for i in range(n)]
                  for r, n in enumerate((100, 60, 40))]
         for lines in calls:
@@ -773,7 +903,7 @@ class TestContentKeys:
         # stores five lines before request 2 stores its one miss: F is in
         # use until request 2 is done, so the next five rows evict the
         # other request's lines before F.
-        cache = LineCache(24, 10 * (8 + 4 + 3 + 8 + 16))
+        cache = LineCache(24, _bytes(1, 10))
 
         def store(lines, found=None):
             cache.populate(line_keys(lines),
@@ -815,7 +945,7 @@ class TestContentKeys:
         that line's bytes, and the budget holds."""
         import sys
 
-        cache = LineCache(24, 40 * (4 * 8 + 4 + 3 + 8 + 16))
+        cache = LineCache(24, _bytes(4, 40))
         lines = [b"shared line %d" % i for i in range(30)]
         bad: list = []
 
@@ -851,6 +981,26 @@ class TestContentKeys:
         s = cache.stats()
         assert s["residentBytes"] <= cache.budget_bytes
         assert s["evictions"] > 0 and s["hits"] > 0
+
+    def test_a_corrupt_index_raises_rather_than_spins(self):
+        cache = LineCache(24, 1 << 20)
+        cache.populate(line_keys([b"x"]), np.ones((1, 24), dtype=bool))
+        cache._tables[1].index[:] = 0  # no empty bucket ends a walk
+        with pytest.raises(RuntimeError):
+            cache.lookup(line_keys([b"y"]))
+        assert not cache.lock.locked()
+
+    def test_cache_counters_are_exported_in_metrics(self):
+        engine = _cached_engine()
+        data = _pod("\n".join(REPEAT_TEMPLATES))
+        engine.analyze_pipelined(data)
+        engine.analyze_pipelined(data)
+        s = engine.line_cache.stats()
+        reg = engine.obs.registry
+        for key, metric, _ in lc.CACHE_METRIC_SAMPLES:
+            assert reg.collected_value(metric, tenant="default") == s[key]
+        assert 'logparser_line_cache_index_probes_total{tenant="default"}' in (
+            reg.render())
 
     def test_engine_cache_path_counts_no_collisions(self):
         engine = _cached_engine()
