@@ -200,6 +200,16 @@ class Obs:
             "logparser_stage_seconds", ("tenant", "stage"),
             buckets=PHASE_BUCKETS, max_series=256,
         )
+        self.batch_flush_seconds = reg.histogram(
+            "logparser_batch_flush_seconds", ("tenant", "part"),
+            buckets=PHASE_BUCKETS,
+        )
+        self.batch_residual_rows = reg.counter(
+            "logparser_batch_residual_rows_total", ("tenant",)
+        )
+        self.batch_residual_slots = reg.counter(
+            "logparser_batch_residual_slots_total", ("tenant",)
+        )
         self.request_cpu = reg.counter(
             "logparser_request_cpu_seconds_total", ("tenant", "route"),
             max_series=256,
@@ -283,12 +293,16 @@ class Obs:
         request_id = getattr(trace, "request_id", None) or self.new_request_id()
         total_ms = (self.clock() - start) * 1e3
         phases = trace.as_dict()
+        # a batch flush's shared step counts once, in note_flush
+        shared = trace.shared
         observe = self.phase_seconds.observe
         for phase, seconds in phases.items():
-            observe(seconds, tenant=tenant, phase=phase, route=route)
+            if phase not in shared:
+                observe(seconds, tenant=tenant, phase=phase, route=route)
         for phase, seconds in trace.cpu_dict().items():
             self.phase_cpu.inc(seconds, tenant=tenant, phase=phase, route=route)
-        self.note_stages(trace.stage_dict(), tenant)
+        self.note_stages({k: v for k, v in trace.stage_dict().items()
+                          if k not in shared}, tenant)
         entry = {
             "requestId": request_id,
             "tenant": tenant,
@@ -366,6 +380,29 @@ class Obs:
         observe = self.stage_seconds.observe
         for stage, seconds in stages.items():
             observe(seconds, tenant=tenant, stage=stage)
+
+    def note_flush(self, tenant: str, device_s: float,
+                   stages: dict[str, float]) -> None:
+        """One micro-batch flush's device step (runtime/batcher.py): the
+        ``device`` phase and its stages, counted once for the flush and
+        not once per member (each member's trace shows them all the same,
+        ``PhaseTrace.add_shared``), and the flush's ``device`` part."""
+        self.phase_seconds.observe(device_s, tenant=tenant, phase="device",
+                                   route="batched")
+        self.note_stages(stages, tenant)
+        self.batch_flush_seconds.observe(device_s, tenant=tenant,
+                                         part="device")
+
+    def note_flush_demux(self, tenant: str, seconds: float) -> None:
+        """One flush's serial demux: the verify and finish of every member
+        in enqueue order, on the scheduler thread."""
+        self.batch_flush_seconds.observe(seconds, tenant=tenant, part="demux")
+
+    def note_batch_residual(self, tenant: str, rows: int, slots: int) -> None:
+        """One flush's line-cache residual: its unique miss lines and the
+        padded rows its dispatches ran."""
+        self.batch_residual_rows.inc(rows, tenant=tenant)
+        self.batch_residual_slots.inc(slots, tenant=tenant)
 
     def note_request_cpu(self, seconds: float, tenant: str,
                          route: str) -> None:

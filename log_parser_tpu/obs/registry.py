@@ -130,6 +130,17 @@ METRICS = {
         "counter", "Requests that rode a micro-batch."),
     "logparser_batches_flushed_total": (
         "counter", "Micro-batches flushed to the device."),
+    "logparser_batch_flush_seconds": (
+        "histogram",
+        "Micro-batch flush wall by part, once per flush: device (the "
+        "shared device step) and demux (every member's verify and finish), "
+        "by tenant/part."),
+    "logparser_batch_residual_rows_total": (
+        "counter", "Unique miss lines of the micro-batch flushes' line-cache "
+        "residual dispatches."),
+    "logparser_batch_residual_slots_total": (
+        "counter", "Padded rows the micro-batch flushes' line-cache residual "
+        "dispatches ran."),
     # -------------------------------------------------------- miner
     "logparser_miner_tapped_total": (
         "counter", "Miss lines tapped into the template miner."),

@@ -91,6 +91,11 @@ if TYPE_CHECKING:  # import cycle: engine imports nothing from here at boot
     from log_parser_tpu.models.analysis import AnalysisResult
     from log_parser_tpu.models.pod import PodFailureData
 
+# A line-cache flush of several requests runs its residual in dispatches
+# of at most this many rows (a 2,000-line request's rung), and every row
+# rung up to it compiles on the first such flush at a line width.
+FLUSH_CHUNK_ROWS = 2048
+
 
 def _next_pow2(n: int) -> int:
     p = 1
@@ -159,6 +164,10 @@ class MicroBatcher:
         # the same thread, so their dispatch spans land on the same
         # flush trace)
         self._active_flush: str | None = None
+        # line widths whose residual rung ladder the bank ``_ladder_for``
+        # has compiled (scheduler-thread only; ``_warm_ladder``)
+        self._ladders: set[int] = set()
+        self._ladder_for = None
 
     # ---------------------------------------------------------------- API
 
@@ -371,8 +380,8 @@ class MicroBatcher:
                 item.trace.request_id, "enqueue", wait_s,
                 attrs={"flush": flush_id, "reason": reason},
             )
-        # the flush's device stages, attributed to every member request
-        # as the shared device phase is
+        # the flush's device stages, shown in every member's trace as the
+        # shared device phase is, and counted once (Obs.note_flush)
         stages = PhaseTrace()
         t0 = time.perf_counter()
         self._active_flush = flush_id
@@ -391,8 +400,8 @@ class MicroBatcher:
         dt = time.perf_counter() - t0
         flush_stages = stages.stage_dict()
         for item in items:
-            item.trace.add("device", dt)
-            item.trace.add_stages(flush_stages)
+            item.trace.add_shared("device", dt, flush_stages)
+        engine.obs.note_flush(engine.obs_tenant, dt, flush_stages)
         demux_t0 = time.perf_counter()
         demux_errs = 0
         # demux in enqueue order: the frequency evolution equals a serial
@@ -450,8 +459,10 @@ class MicroBatcher:
                 item.error = exc
             finally:
                 item.done.set()
+        demux_s = time.perf_counter() - demux_t0
+        engine.obs.note_flush_demux(engine.obs_tenant, demux_s)
         spans.annotate(
-            flush_id, "demux", time.perf_counter() - demux_t0,
+            flush_id, "demux", demux_s,
             attrs={"requests": len(items), "errors": demux_errs,
                    "fallbacks": fallbacks},
         )
@@ -599,25 +610,38 @@ class MicroBatcher:
         if miss_slots.size:
             u = miss_slots.size
             T = int(widths.max())
-            pad = _pad_rows(u, engine._corpus_min_rows())
+            chunks = self._residual_chunks(u, len(items) == 1)
+            # chunk c's lines sit at the head of its own padded block
+            starts = np.cumsum([0] + [p for _, p in chunks])
+            dest = np.concatenate([
+                starts[c] + np.arange(n) for c, (n, _) in enumerate(chunks)
+            ])
+            pad = int(starts[-1])
             res_u8 = np.zeros((pad, T), dtype=np.uint8)
             res_len = np.zeros(pad, dtype=np.int32)
             contributed = np.unique(m_item).tolist()
             for r in contributed:
                 j = np.flatnonzero(m_item == r)
                 enc = items[r].corpus.encoded
-                res_u8[j, : enc.u8.shape[1]] = enc.u8[m_line[j]]
-                res_len[j] = enc.lengths[m_line[j]]
+                res_u8[dest[j], : enc.u8.shape[1]] = enc.u8[m_line[j]]
+                res_len[dest[j]] = enc.lengths[m_line[j]]
 
             def _device_step():
                 for r in contributed:
                     faults.fire("quarantine", key=items[r].data.logs or "")  # conlint: contained-by-caller (watchdog.run)
                 faults.fire("device")  # conlint: contained-by-caller (watchdog.run)
-                return engine._run_cube(res_u8, res_len, u, trace=stages)
+                self._warm_ladder(T)
+                return np.concatenate([
+                    engine._run_cube(
+                        res_u8[s : s + p], res_len[s : s + p], n,
+                        trace=stages,
+                    )[:n]
+                    for (n, p), s in zip(chunks, starts.tolist())
+                ])
 
             t0 = time.perf_counter()
             try:
-                fresh = engine.watchdog.run(_device_step)[:u]
+                fresh = engine.watchdog.run(_device_step)
             except Exception as exc:
                 self._dispatch_span(time.perf_counter() - t0, {
                     "rows": pad, "width": T, "lines": u,
@@ -626,8 +650,10 @@ class MicroBatcher:
                 return None
             self._dispatch_span(time.perf_counter() - t0, {
                 "rows": pad, "width": T, "lines": u, "residual": True,
+                "dispatches": len(chunks),
                 "wasteRatio": round((pad - u) / pad, 4) if pad else 0.0,
             })
+            engine.obs.note_batch_residual(engine.obs_tenant, u, pad)
             cache.note_residual(u, int(counts[miss_slots].sum()) - u)
             with stages.stage("cache.populate"):
                 cache.populate(keys.take(miss_slots), fresh, found)
@@ -643,6 +669,45 @@ class MicroBatcher:
             engine.obs.note_extract_hits(line.size, engine.obs_tenant)
         engine._k_hint = max(r.n_matches for r in out)
         return out
+
+    def _residual_chunks(self, u: int, lone: bool) -> list[tuple[int, int]]:
+        """(lines, padded rows) of each dispatch of a ``u``-line residual.
+        A lone request's residual runs at its own row rung, as the
+        unbatched path runs it. A flush of several requests coalesces
+        their misses, so its residual can reach rungs that no lone
+        request reaches: it runs in dispatches of ``FLUSH_CHUNK_ROWS``
+        rows and one at the rung of the rest, every one of them on the
+        width's ladder (``_warm_ladder``). Rows are independent lines, so
+        the split changes no bit of the result."""
+        own = _pad_rows(u, self.engine._corpus_min_rows())
+        if lone or u <= FLUSH_CHUNK_ROWS:
+            return [(u, own)]
+        chunks = [(FLUSH_CHUNK_ROWS, FLUSH_CHUNK_ROWS)] * (
+            (u - 1) // FLUSH_CHUNK_ROWS
+        )
+        rest = u - FLUSH_CHUNK_ROWS * len(chunks)
+        chunks.append((rest, _pad_rows(rest, self.engine._corpus_min_rows())))
+        return chunks
+
+    def _warm_ladder(self, width: int) -> None:
+        """Compile the residual cube program at every row rung up to
+        ``FLUSH_CHUNK_ROWS`` for ``width``, once per width and bank, on
+        the first cached flush that reaches it: no later flush at that
+        width compiles, whatever sizes came before it."""
+        fused = self.engine.fused
+        if self._ladder_for is not fused:
+            # a new bank has its programs still to compile
+            self._ladder_for = fused
+            self._ladders = set()
+        if width in self._ladders:
+            return
+        min_rows = self.engine._corpus_min_rows()
+        rows = _pad_rows(1, min_rows)
+        while rows <= FLUSH_CHUNK_ROWS:
+            fused.cube_rows(np.zeros((rows, width), dtype=np.uint8),
+                            np.zeros(rows, dtype=np.int32), 0)
+            rows = _pad_rows(rows + 1, min_rows)
+        self._ladders.add(width)
 
     def _device_batch(self, items: list[_Pending], stages: PhaseTrace):
         """Stack the bucket into one padded [R, B, T] batch, run the

@@ -73,6 +73,9 @@ class PhaseTrace:
         # back, so no lock is needed.
         self.links: list = []
         self.span_attrs: dict = {}
+        # phases and stages whose time a coalesced step shares with the
+        # request's batchmates (:meth:`add_shared`)
+        self.shared: set[str] = set()
 
     @contextlib.contextmanager
     def phase(self, name: str):
@@ -89,9 +92,9 @@ class PhaseTrace:
 
     def add(self, name: str, seconds: float, cpu: float | None = None) -> None:
         """Accumulate ``seconds`` into ``name`` (for callers that measured
-        a span themselves — e.g. one shared device step attributed to every
-        request of a coalesced batch). Such a phase ran on no thread of
-        its own, so it records no CPU unless ``cpu`` is given."""
+        a span themselves — e.g. the time a request waited in a batch
+        queue). Such a phase ran on no thread of its own, so it records
+        no CPU unless ``cpu`` is given."""
         with self._lock:
             self.phases[name] = self.phases.get(name, 0.0) + seconds
             if cpu is not None:
@@ -112,12 +115,19 @@ class PhaseTrace:
         with self._lock:
             self.stages[name] = self.stages.get(name, 0.0) + seconds
 
-    def add_stages(self, stages: dict[str, float]) -> None:
-        """Accumulate another trace's stages (a batch flush's device
-        stages, attributed to every request it served)."""
+    def add_shared(self, phase: str, seconds: float,
+                   stages: dict[str, float]) -> None:
+        """Add the time of a step this request shared with its batchmates
+        (a micro-batch flush's device step and its stages). The request's
+        own phases, stages and span show it; the phase and stage totals
+        leave it out, since the step counts there once, for the flush
+        (``Obs.note_flush``)."""
+        self.add(phase, seconds)
         with self._lock:
-            for name, seconds in stages.items():
-                self.stages[name] = self.stages.get(name, 0.0) + seconds
+            for name, secs in stages.items():
+                self.stages[name] = self.stages.get(name, 0.0) + secs
+            self.shared.add(phase)
+            self.shared.update(stages)
 
     @property
     def total(self) -> float:
